@@ -18,7 +18,10 @@ death) once an adversarial state condition holds:
   the victim, so the snapshot carries a purged subtree and the journal a
   ``quarantined`` terminal.
 
-The parent then restores **in-process** from the child's artifacts
+The parent runs every child first and touches JAX only after the last
+one has died: a device belongs to one process at a time, so a parent that
+already held it would leave the child without one.  It then restores
+**in-process** from the child's artifacts
 (snapshot warm start with fsck, journal-suffix adoption, recompute
 resubmission of in-flight requests) and drives the workload to drain.
 
@@ -354,11 +357,15 @@ def main(argv=None) -> float:
     if args.smoke:
         args.n_req, args.max_new, args.fresh = 4, 6, 2
 
-    cfg, params = _setup()
     failures: List[str] = []
     cases: Dict[str, Dict] = {}
     shutil.rmtree(args.artifacts, ignore_errors=True)
+    # every child dies before this process first touches the device
+    child_rc = {case: spawn_child(case, os.path.join(args.artifacts, case),
+                                  args.seed, args.n_req, args.max_new)
+                for case in KILL_CASES}
 
+    cfg, params = _setup()
     for case in KILL_CASES:
         adir = os.path.join(args.artifacts, case)
         arrivals = case_workload(case, cfg.vocab_size, args.seed,
@@ -369,7 +376,7 @@ def main(argv=None) -> float:
         ref = streams_of(drive(build_fleet(factory, case), arrivals,
                                args.max_new))
 
-        rc = spawn_child(case, adir, args.seed, args.n_req, args.max_new)
+        rc = child_rc[case]
         if rc != -signal.SIGKILL:
             failures.append(f"{case}: child exited {rc}, expected SIGKILL")
             cases[case] = {"child_rc": rc}

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 from repro.core.numerics import LN_2, NEG_INF
 
 
@@ -195,7 +193,7 @@ def flash_attention_bwd(
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -217,7 +215,7 @@ def flash_attention_bwd(
         out_specs=pl.BlockSpec((1, block_q, D), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Sqp, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -8,10 +8,13 @@ Softermax recurrence is order-free (every rescale is an exact exponent add),
 blocks can be streamed in table order with no pre-pass over the scores, which
 is exactly what makes the paged layout free for this kernel.
 
-The block table is a scalar-prefetch operand (``PrefetchScalarGridSpec``):
-its entries are available *before* the kernel body runs, so the KV BlockSpec
-index maps perform the gather — each grid step DMAs physical blocks from the
-pool directly into VMEM.
+The block table and the per-row lengths are scalar-prefetch operands
+(``PrefetchScalarGridSpec``, SMEM): their entries are available *before*
+the kernel body runs, so the KV BlockSpec index maps perform the gather —
+each grid step DMAs physical blocks from the pool directly into VMEM — and
+the body reads its row's length as a scalar. (A ``(1, 1)`` VMEM block over
+a ``(B, 1)`` lengths array is refused by the TPU compiler for B > 1: a
+block's last two dims must be (8, 128)-divisible or span the array.)
 
 Three grid-level restructurings over the naive per-head walk (all three are
 pure reorganizations of the same recurrence — outputs are unchanged):
@@ -63,8 +66,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 from repro.core.numerics import NEG_INF
 from repro.core.softermax import softermax_finalize, softermax_merge
 from repro.kernels.flash_decode_paged.ref import split_layout
@@ -81,7 +82,8 @@ def concat_tiles(refs, axis: int = 0):
 
 
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, intmax: bool,
-                         block_size: int, tile_blocks: int, quantized: bool):
+                         block_size: int, tile_blocks: int, quantized: bool,
+                         kv_heads: int):
     T = tile_blocks
     k_refs, v_refs = rest[:T], rest[T:2 * T]
     n = 2 * T
@@ -98,7 +100,7 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, intmax: bool,
         d_scr[...] = jnp.zeros_like(d_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kv_len = len_ref[0, 0]
+    kv_len = len_ref[pl.program_id(0) // kv_heads]
     jj = pl.program_id(1) * spl + j           # global kv tile index
     k_start = jj * (T * block_size)
 
@@ -176,22 +178,21 @@ def flash_decode_paged(
     bt = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, Wp - W)))
 
     qf = q.reshape(B * Hkv, G, D)
-    lens = lengths.astype(jnp.int32).reshape(B, 1)
+    lens = lengths.astype(jnp.int32)
 
     def kv_map(t):
         # one gather map per tile slot; values and scales share it
-        def _map(bh, s, j, bt_ref):
+        def _map(bh, s, j, bt_ref, len_ref):
             jj = s * spl + j
             return (bt_ref[bh // Hkv, jj * T + t], bh % Hkv, 0, 0)
         return _map
 
     in_specs = [
-        pl.BlockSpec((1, 1), lambda bh, s, j, bt_ref: (bh // Hkv, 0)),
-        pl.BlockSpec((1, G, D), lambda bh, s, j, bt_ref: (bh, 0, 0)),
+        pl.BlockSpec((1, G, D), lambda bh, s, j, bt_ref, len_ref: (bh, 0, 0)),
     ]
     in_specs += [pl.BlockSpec((1, 1, BS, D), kv_map(t)) for t in range(T)]
     in_specs += [pl.BlockSpec((1, 1, BS, D), kv_map(t)) for t in range(T)]
-    inputs = [lens, qf] + [k_pool] * T + [v_pool] * T
+    inputs = [qf] + [k_pool] * T + [v_pool] * T
     if quantized:
         # scales ride the same scalar-prefetch block-table gather as the
         # values; the trailing unit axis keeps in-kernel reads 2-D
@@ -203,14 +204,16 @@ def flash_decode_paged(
                      for t in range(T)]
         inputs += [ksr] * T + [vsr] * T
 
-    part = pl.BlockSpec((1, 1, G, 1), lambda bh, s, j, bt_ref: (bh, s, 0, 0))
+    def out_map(bh, s, j, bt_ref, len_ref):
+        return (bh, s, 0, 0)
+
+    part = pl.BlockSpec((1, 1, G, 1), out_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B * Hkv, S, spl),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, G, D),
-                         lambda bh, s, j, bt_ref: (bh, s, 0, 0)),
+            pl.BlockSpec((1, 1, G, D), out_map),
             part, part,
         ],
         scratch_shapes=[
@@ -222,18 +225,19 @@ def flash_decode_paged(
 
     acc, m, d = pl.pallas_call(
         functools.partial(_paged_decode_kernel, intmax=intmax,
-                          block_size=BS, tile_blocks=T, quantized=quantized),
+                          block_size=BS, tile_blocks=T, quantized=quantized,
+                          kv_heads=Hkv),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B * Hkv, S, G, D), jnp.float32),
             jax.ShapeDtypeStruct((B * Hkv, S, G, 1), jnp.float32),
             jax.ShapeDtypeStruct((B * Hkv, S, G, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(bt, *inputs)
+    )(bt, lens, *inputs)
 
     # second stage: associative Softermax merge of the split partials under
     # the joint (Int)Max, then the one deferred normalize. With split_k=1
@@ -250,7 +254,7 @@ def flash_decode_paged(
 
 def _paged_decode_kernel_single(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
                                 intmax: bool, block_size: int,
-                                quantized: bool):
+                                quantized: bool, n_q_heads: int):
     if quantized:
         ksc_ref, vsc_ref, o_ref, acc_scr, m_scr, d_scr = rest
     else:
@@ -264,7 +268,7 @@ def _paged_decode_kernel_single(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         d_scr[...] = jnp.zeros_like(d_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kv_len = len_ref[0, 0]
+    kv_len = len_ref[pl.program_id(0) // n_q_heads]
     k_start = j * block_size
 
     @pl.when(k_start < kv_len)
@@ -323,19 +327,21 @@ def flash_decode_paged_single(
     quantized = k_scale is not None
 
     qf = q.reshape(B * Hq, 1, D)
-    lens = lengths.astype(jnp.int32).reshape(B, 1)
+    lens = lengths.astype(jnp.int32)
     bt = block_tables.astype(jnp.int32)
 
-    def kv_map(bh, j, bt_ref):
+    def kv_map(bh, j, bt_ref, len_ref):
         return (bt_ref[bh // Hq, j], (bh % Hq) // group, 0, 0)
 
+    def q_map(bh, j, bt_ref, len_ref):
+        return (bh, 0, 0)
+
     in_specs = [
-        pl.BlockSpec((1, 1), lambda bh, j, bt_ref: (bh // Hq, 0)),
-        pl.BlockSpec((1, 1, D), lambda bh, j, bt_ref: (bh, 0, 0)),
+        pl.BlockSpec((1, 1, D), q_map),
         pl.BlockSpec((1, 1, BS, D), kv_map),
         pl.BlockSpec((1, 1, BS, D), kv_map),
     ]
-    inputs = [lens, qf, k_pool, v_pool]
+    inputs = [qf, k_pool, v_pool]
     if quantized:
         in_specs += [pl.BlockSpec((1, 1, 1, BS), kv_map),
                      pl.BlockSpec((1, 1, 1, BS), kv_map)]
@@ -343,10 +349,10 @@ def flash_decode_paged_single(
                    v_scale.astype(jnp.float32).reshape(N, Hkv, 1, BS)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B * Hq, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, D), lambda bh, j, bt_ref: (bh, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((1, D), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
@@ -356,13 +362,14 @@ def flash_decode_paged_single(
 
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel_single, intmax=intmax,
-                          block_size=BS, quantized=quantized),
+                          block_size=BS, quantized=quantized,
+                          n_q_heads=Hq),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, 1, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(bt, *inputs)
+    )(bt, lens, *inputs)
 
     return out.reshape(B, Hq, D)

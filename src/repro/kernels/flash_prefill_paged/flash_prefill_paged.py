@@ -17,8 +17,9 @@ Layout (same conventions as the decode kernel):
 * KV is the pool ``(N, Hkv, BS, D)``; ``block_tables`` holds each
   sequence's physical block ids in *logical* order, so the key at logical
   position ``p`` lives at ``pool[table[p // BS], :, p % BS]``.
-* The table is a scalar-prefetch operand: the KV BlockSpec index maps do
-  the gather.
+* The table and the per-row ``q_pos0`` are scalar-prefetch operands
+  (SMEM): the KV BlockSpec index maps do the gather, and the body reads
+  its row's first query position as a scalar.
 * **GQA grouping** — grid axis 0 is ``B*Hkv``: one lane owns a whole GQA
   group with a ``(group, BQ, D)`` query tile (flattened to
   ``(group*BQ, D)`` for the dots), so the block-table gather runs once per
@@ -59,8 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 from repro.core.numerics import NEG_INF
 from repro.core.softermax import softermax_finalize
 from repro.kernels.flash_decode_paged.flash_decode_paged import concat_tiles
@@ -69,7 +68,7 @@ from repro.kernels.flash_decode_paged.ref import split_layout
 
 def _paged_prefill_kernel(bt_ref, pos_ref, q_ref, *rest, intmax: bool,
                           block_q: int, block_size: int, tile_blocks: int,
-                          group: int, quantized: bool):
+                          group: int, kv_heads: int, quantized: bool):
     T = tile_blocks
     k_refs, v_refs = rest[:T], rest[T:2 * T]
     n = 2 * T
@@ -86,8 +85,9 @@ def _paged_prefill_kernel(bt_ref, pos_ref, q_ref, *rest, intmax: bool,
         d_scr[...] = jnp.zeros_like(d_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_start = pos_ref[0, 0] + i * block_q     # absolute pos of q row 0
-    k_start = j * (T * block_size)            # logical pos of kv tile row 0
+    # absolute pos of q row 0; logical pos of kv tile row 0
+    q_start = pos_ref[pl.program_id(0) // kv_heads] + i * block_q
+    k_start = j * (T * block_size)
 
     @pl.when(k_start <= q_start + block_q - 1)
     def _body():
@@ -166,22 +166,21 @@ def flash_prefill_paged(
     nq = Sqp // block_q
 
     qf = qp.reshape(B, Hkv, G, Sqp, D).reshape(B * Hkv, G, Sqp, D)
-    pos = q_pos0.astype(jnp.int32).reshape(B, 1)
+    pos = q_pos0.astype(jnp.int32)
 
     def kv_map(t):
         # one gather map per tile slot; values and scales share it
-        def _map(bh, i, j, bt_ref):
+        def _map(bh, i, j, bt_ref, pos_ref):
             return (bt_ref[bh // Hkv, j * T + t], bh % Hkv, 0, 0)
         return _map
 
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda bh, i, j, bt_ref: (bh // Hkv, 0)),
-        pl.BlockSpec((1, G, block_q, D),
-                     lambda bh, i, j, bt_ref: (bh, 0, i, 0)),
-    ]
+    def q_map(bh, i, j, bt_ref, pos_ref):
+        return (bh, 0, i, 0)
+
+    in_specs = [pl.BlockSpec((1, G, block_q, D), q_map)]
     in_specs += [pl.BlockSpec((1, 1, BS, D), kv_map(t)) for t in range(T)]
     in_specs += [pl.BlockSpec((1, 1, BS, D), kv_map(t)) for t in range(T)]
-    inputs = [pos, qf] + [k_pool] * T + [v_pool] * T
+    inputs = [qf] + [k_pool] * T + [v_pool] * T
     if quantized:
         # scales ride the same scalar-prefetch gather as the values; the
         # trailing unit axis keeps in-kernel reads 2-D (TPU-friendly)
@@ -194,11 +193,10 @@ def flash_prefill_paged(
         inputs += [ksr] * T + [vsr] * T
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B * Hkv, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, block_q, D),
-                               lambda bh, i, j, bt_ref: (bh, 0, i, 0)),
+        out_specs=pl.BlockSpec((1, G, block_q, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((G * block_q, D), jnp.float32),
             pltpu.VMEM((G * block_q, 1), jnp.float32),
@@ -209,14 +207,14 @@ def flash_prefill_paged(
     out = pl.pallas_call(
         functools.partial(_paged_prefill_kernel, intmax=intmax,
                           block_q=block_q, block_size=BS, tile_blocks=T,
-                          group=G, quantized=quantized),
+                          group=G, kv_heads=Hkv, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, Sqp, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(bt, *inputs)
+    )(bt, pos, *inputs)
 
     out = out.reshape(B, Hkv, G, Sqp, D).reshape(B, Hq, Sqp, D)
     return out[:, :, :Sq, :]

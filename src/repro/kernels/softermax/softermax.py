@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 from repro.core.numerics import NEG_INF
 
 _SAFE_NEG = NEG_INF  # finite mask value; (-inf)-(-inf) NaNs are avoided
@@ -118,7 +116,7 @@ def softermax_rows(
             pltpu.VMEM((block_rows, 1), jnp.float32),
             pltpu.VMEM((block_rows, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -135,7 +133,7 @@ def softermax_rows(
         ],
         out_specs=pl.BlockSpec((block_rows, block_v), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, Vp), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -18,6 +18,8 @@ import time
 import jax
 import numpy as np
 
+from repro.configs.base import ModelConfig
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.registry import (GRID_ARCHS, get_config, model_fns,
                                    reduce_config)
@@ -28,13 +30,24 @@ from repro.utils.logging import get_logger
 log = get_logger("launch.serve")
 
 
+def serving_params(cfg: ModelConfig, seed: int = 0):
+    """Seeded random weights as serving holds them: every leaf is drawn
+    directly in bf16, so no f32 copy of the model is ever on the device
+    (qwen3-4b: 16.4 GiB in f32 against a 16 GB chip, 8.2 GiB in bf16).
+    Returns the bf16-parameter config and its params. The draw is one
+    jitted program: drawn leaf by leaf, every shape compiled on its own
+    (100 s of a cold start on a v5e at qwen3-4b widths)."""
+    cfg = cfg.replace(param_dtype="bfloat16")
+    return cfg, jax.jit(model_fns(cfg).init)(jax.random.PRNGKey(seed))
+
+
 def _serve_fleet(args, cfg, params, prompts, t0):
     """Serve the workload through a FleetSupervisor over N replicas:
     prefix-affinity (or round-robin) placement, step-watchdog
     supervision, journaled failover, fleet-aggregated metrics."""
     from repro.serve import (EngineGuard, FaultInjector, FaultPlan,
                              FleetSupervisor, Journal, Router, Telemetry,
-                             canned_fleet_plan)
+                             canned_fleet_plan, replica_device)
     want_tel = bool(args.telemetry or args.metrics_out)
 
     def engine_factory():
@@ -81,7 +94,10 @@ def _serve_fleet(args, cfg, params, prompts, t0):
                      if t.result is not None),
                  int(sup.tracker.c_tail_lost.value))
     else:
-        engines = [engine_factory() for _ in range(args.replicas)]
+        engines = []
+        for i in range(args.replicas):
+            with jax.default_device(replica_device(i)):
+                engines.append(engine_factory())
         sup = FleetSupervisor(engines, router=Router(args.router),
                               journal=journal, faults=faults,
                               step_parallel=True,
@@ -299,6 +315,7 @@ def main() -> None:
                          "resubmit via the [prompt ‖ emitted] recompute "
                          "contract), then serve the new workload")
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -306,11 +323,13 @@ def main() -> None:
     if args.optimized:
         cfg = cfg.with_opts(True)
 
+    # a paged engine, and each fleet replica, lives on one device (its
+    # weights are committed there); only the static engine spreads its
+    # batch over a host mesh
     mesh = (make_production_mesh() if args.production_mesh
-            else make_host_mesh())
+            else make_host_mesh() if args.engine == "static" else None)
     with sharding_context(mesh, SERVE_RULES):
-        fns = model_fns(cfg)
-        params = fns.init(jax.random.PRNGKey(0))
+        cfg, params = serving_params(cfg)
         rng = np.random.default_rng(0)
         prompts = rng.integers(1, cfg.vocab_size,
                                (args.batch, args.prompt_len)).astype(np.int32)

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs.base import TrainConfig
 from repro.data import SyntheticLMData
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.registry import (GRID_ARCHS, get_config, model_fns,
                                    reduce_config)
@@ -45,6 +46,7 @@ def main() -> None:
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

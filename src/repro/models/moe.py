@@ -31,7 +31,6 @@ from repro.core.softermax import attention_softmax
 from repro.models.layers import _activate, mlp, mlp_schema
 from repro.models.schema import ParamSpec
 from repro.parallel.sharding import current_mesh, shard_act
-from repro.parallel.compat import shard_map
 
 
 def moe_schema(cfg: ModelConfig):
@@ -246,7 +245,7 @@ def moe_apply_shard_map(params, x: jax.Array, cfg: ModelConfig, mesh
         return y.reshape(x_l.shape), aux
 
     x_spec = P(batch_axes if B % n_data == 0 else None, "model", None)
-    out = shard_map(
+    out = jax.shard_map(
         _inner, mesh=mesh,
         in_specs=(x_spec,
                   P(None, None),                    # router replicated

@@ -53,22 +53,26 @@ def logical_specs(schema: Schema):
 
 
 def _init_leaf(key, ps: ParamSpec, dtype) -> jax.Array:
+    """Draws directly in ``dtype``: a bf16 leaf never passes through an
+    f32 copy on the device (f32 draws are unchanged)."""
     if ps.init == "zeros":
         return jnp.zeros(ps.shape, dtype)
     if ps.init == "ones":
         return jnp.ones(ps.shape, dtype)
     if ps.init == "embed":
         std = ps.std if ps.std is not None else 1.0
-        return (jax.random.normal(key, ps.shape) * std).astype(dtype)
-    if ps.init == "normal":
+    elif ps.init == "normal":
         if ps.std is not None:
             std = ps.std
         else:
             # fan-in = second-to-last dim (or last for 1-D)
             fan_in = ps.shape[-2] if len(ps.shape) >= 2 else ps.shape[-1]
             std = 1.0 / np.sqrt(max(fan_in, 1))
-        return (jax.random.normal(key, ps.shape) * std).astype(dtype)
-    raise ValueError(f"unknown init {ps.init}")
+    else:
+        raise ValueError(f"unknown init {ps.init}")
+    # a Python float keeps the product in ``dtype`` (a NumPy float64 would
+    # promote a bf16 draw to f32)
+    return jax.random.normal(key, ps.shape, dtype) * float(std)
 
 
 def init_params(key: jax.Array, schema: Schema, dtype=jnp.float32):

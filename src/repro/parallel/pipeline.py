@@ -23,7 +23,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from repro.parallel.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -87,7 +86,7 @@ def pipeline_apply(
 
     inner = functools.partial(_pipeline_inner, stage_fn=stage_fn,
                               axis_name=axis_name, n_stages=n)
-    out = shard_map(
+    out = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),

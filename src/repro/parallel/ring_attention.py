@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.numerics import NEG_INF
-from repro.parallel.compat import shard_map
 
 
 def _ring_inner(q, k, v, *, axis_name: str, n_ranks: int, causal: bool,
@@ -98,7 +97,7 @@ def ring_attention(
         None, axis_name, None)
     inner = functools.partial(_ring_inner, axis_name=axis_name, n_ranks=n,
                               causal=causal, intmax=intmax)
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,

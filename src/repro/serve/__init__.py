@@ -34,7 +34,7 @@ from repro.serve.snapshot import (Snapshot, SnapshotCorrupt, apply_snapshot,
                                   restore_engine, snapshot_state,
                                   write_snapshot)
 from repro.serve.supervisor import (FleetSupervisor, ReplicaHandle,
-                                    snapshot_path)
+                                    replica_device, snapshot_path)
 from repro.serve.telemetry import (ManualClock, RequestTrace, StepTimeline,
                                    Telemetry)
 
@@ -63,7 +63,7 @@ __all__ = ["ContinuousEngine", "EngineMetrics", "GenerateResult",
            "RequestResult", "RequestTracker", "TrackedRequest",
            "Journal", "JournalCorrupt", "ReplayState", "ReplayedRequest",
            "replay", "ROUTING_POLICIES", "PlacementDecision", "Router",
-           "FleetSupervisor", "ReplicaHandle",
+           "FleetSupervisor", "ReplicaHandle", "replica_device",
            # durability layer (PR 10)
            "FSYNC_POLICIES", "state_digest", "Snapshot", "SnapshotCorrupt",
            "apply_snapshot", "engine_fingerprint", "requeue_inflight",

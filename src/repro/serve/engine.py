@@ -75,6 +75,15 @@ from repro.serve.scheduler import (FINISH_DEADLINE, FINISH_QUARANTINED,
 from repro.serve.telemetry import Telemetry
 
 
+def _current_device():
+    """The device new arrays land on: ``jax.default_device`` if one is
+    in force, else the default backend's first device."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.devices()[0]
+    return jax.devices(dev)[0] if isinstance(dev, str) else dev
+
+
 def sample_tokens(lg: jax.Array, key, temperature: float,
                   cfg: ModelConfig) -> jax.Array:
     """Greedy or temperature sampling over the softermax distribution."""
@@ -216,7 +225,10 @@ class ContinuousEngine:
         if cfg.opt_bf16_params:
             from repro.models.lm import maybe_cast_params
             params = maybe_cast_params(params, cfg)
-        self.params = params
+        # An engine lives on the device current while it is built (a fleet
+        # builds each replica under ``jax.default_device``): committing the
+        # weights there pins every jitted step, and so the pool, to it.
+        self.params = jax.device_put(params, _current_device())
         self.block_size = block_size
         self.max_batch = max_batch
         self.max_len = max_len

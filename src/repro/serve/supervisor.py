@@ -50,6 +50,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.serve.faults import FaultInjector
@@ -61,11 +62,23 @@ from repro.serve.journal import Journal, state_digest
 from repro.serve.router import Router
 from repro.serve.scheduler import (FINISH_DEADLINE, FINISH_FAILOVER,
                                    FINISH_LENGTH, CapacityExceededError)
+from repro.utils.logging import get_logger
+
+log = get_logger("serve.supervisor")
 
 
 def snapshot_path(snapshot_dir: str, replica_idx: int) -> str:
     """Canonical per-replica snapshot file name inside a snapshot dir."""
     return os.path.join(snapshot_dir, f"replica{replica_idx}.snap")
+
+
+def replica_device(replica_idx: int):
+    """The device replica ``replica_idx`` lives on: one replica per
+    device, wrapping round when there are more replicas than devices.
+    Build the replica's engine under ``jax.default_device`` of it."""
+    devices = jax.devices()
+    return devices[replica_idx % len(devices)]
+
 
 # replica lifecycle (ReplicaHandle.state)
 SERVING, HUNG, DEAD = "serving", "hung", "dead"
@@ -85,6 +98,7 @@ class ReplicaHandle:
     revoked: List[int] = dataclasses.field(default_factory=list)
     last_beat_tick: int = -1
     last_beat_t: float = 0.0
+    error: Optional[BaseException] = None   # what killed a crashed replica
 
     @property
     def name(self) -> str:
@@ -335,6 +349,11 @@ class FleetSupervisor:
             errs = [self._step_one(r) for r in stepping]
         for r, err in zip(stepping, errs):
             if err is not None:
+                # the fleet keeps serving, so the cause must not vanish:
+                # a replica that cannot compile or run is a crash too
+                r.error = err
+                log.error("replica %s crashed at tick %d", r.name, t,
+                          exc_info=err)
                 self._fail(r, "crash")
         beat_t = self.clock()
         for r in active:
@@ -458,7 +477,8 @@ class FleetSupervisor:
         for i in range(n_replicas):
             spath = (snapshot_path(snapshot_dir, i)
                      if snapshot_dir else None)
-            engine, _specs, info = restore_engine(engine_factory, spath)
+            with jax.default_device(replica_device(i)):
+                engine, _specs, info = restore_engine(engine_factory, spath)
             if info["mode"] == "warm":
                 # journal is authoritative for request state: drop the
                 # snapshot's queues (publishing their generated KV into

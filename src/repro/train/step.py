@@ -23,7 +23,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.optim import adamw
-from repro.parallel.compat import shard_map
 
 
 def make_loss_and_grad(loss_fn, tc: TrainConfig):
@@ -105,7 +104,7 @@ def make_ddp_train_step(loss_fn: Callable, tc: TrainConfig, mesh,
     pspec_params = P()           # replicated
     pspec_batch = P(data_axis)   # batch-sharded
 
-    return shard_map(
+    return jax.shard_map(
         _step, mesh=mesh,
         in_specs=(pspec_params, pspec_params, pspec_params, pspec_batch),
         out_specs=(pspec_params, pspec_params, pspec_params, pspec_params),

@@ -109,7 +109,8 @@ class TestMoEShardMap:
             x = jnp.asarray(np.random.default_rng(0).normal(
                 size=(4, 16, cfg.d_model)), jnp.float32)
             y_g, _ = moe_mod._moe_apply_global(params, x, cfg)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             with sharding_context(mesh, DEFAULT_RULES):
                 y_s, _ = jax.jit(lambda p, xx: moe_mod.moe_apply_shard_map(
                     p, xx, cfg, mesh))(params, x)
@@ -124,7 +125,8 @@ class TestMoEShardMap:
         out = run_sub("""
             import jax
             import repro.launch.dryrun as dr
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             dr.make_production_mesh = lambda multi_pod=False: mesh
             from repro.models.registry import get_config, reduce_config
             cfg = reduce_config(get_config("moonshot-v1-16b-a3b")).replace(
@@ -141,7 +143,8 @@ class TestCacheSeqShard:
         out = run_sub("""
             import jax
             import repro.launch.dryrun as dr
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             dr.make_production_mesh = lambda multi_pod=False: mesh
             from repro.models.registry import get_config, reduce_config
             cfg = reduce_config(get_config("qwen3-4b")).replace(

@@ -28,11 +28,10 @@ def run_sub(code: str) -> str:
 
 class TestShardingRules:
     def setup_method(self):
-        # AbstractMesh avoids touching real devices (via the version shim:
-        # its constructor signature changed across jax releases)
-        from repro.parallel.compat import abstract_mesh
-        self.mesh = abstract_mesh((16, 16), ("data", "model"))
-        self.mp = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+        # AbstractMesh avoids touching real devices
+        from jax.sharding import AbstractMesh
+        self.mesh = AbstractMesh((16, 16), ("data", "model"))
+        self.mp = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
     def test_divisible_dims_shard(self):
         spec = logical_to_physical(("embed", "mlp"), (4096, 12800),
@@ -78,16 +77,17 @@ class TestMultiDevice:
         out = run_sub("""
             import jax, jax.numpy as jnp, numpy as np
             from repro.parallel.collectives import compressed_psum
-            from repro.parallel.compat import shard_map
-            mesh = jax.make_mesh((8,), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((8,), ("data",))
             x = jnp.arange(8 * 32, dtype=jnp.float32).reshape(8, 32) / 77.0
             def f(xs):
                 mean, resid = compressed_psum(xs, "data")
                 return mean, resid
-            y, r = jax.jit(shard_map(f, mesh=mesh,
+            y, r = jax.jit(jax.shard_map(f, mesh=mesh,
                 in_specs=jax.sharding.PartitionSpec("data"),
                 out_specs=(jax.sharding.PartitionSpec(),
-                           jax.sharding.PartitionSpec("data"))))(x)
+                           jax.sharding.PartitionSpec("data")),
+                check_vma=False))(x)
             exact = jnp.mean(x.reshape(8, 1, 32), 0)
             err = float(jnp.abs(y[0] - exact).max())
             amax = float(jnp.abs(x).max())
@@ -108,7 +108,8 @@ class TestMultiDevice:
             params = fns.init(jax.random.PRNGKey(0))
             opt = adamw.init_state(params)
             errors = jax.tree_util.tree_map(jnp.zeros_like, params)
-            mesh = jax.make_mesh((8,), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((8,), ("data",))
             tc = TrainConfig(grad_compression=True, learning_rate=1e-3)
             step = jax.jit(make_ddp_train_step(fns.loss, tc, mesh))
             batch = {"tokens": jnp.ones((8, 32), jnp.int32),
@@ -141,7 +142,8 @@ class TestMultiDevice:
             # single device
             _, _, m1 = jax.jit(make_train_step(fns.loss, tc))(params, opt, batch)
             # sharded
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             with sharding_context(mesh, DEFAULT_RULES):
                 sh = jax.tree_util.tree_map(
                     lambda spec, a: NamedSharding(mesh, logical_to_physical(
@@ -163,7 +165,8 @@ class TestMultiDevice:
             import jax
             from repro.launch.dryrun import lower_cell
             from repro.models.registry import get_config, reduce_config
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             import repro.launch.dryrun as dr
             import repro.launch.mesh as lm
             lm_orig = lm.make_production_mesh

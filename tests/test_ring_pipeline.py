@@ -24,7 +24,8 @@ class TestRingAttention:
             from repro.parallel.ring_attention import ring_attention
             from repro.models.attention import chunked_attention
             rng = np.random.default_rng(0)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             B, Hq, Hkv, S, D = 2, 4, 2, 64, 16
             q = jnp.asarray(rng.normal(size=(B,Hq,S,D)), jnp.float32) / 4
             k = jnp.asarray(rng.normal(size=(B,Hkv,S,D)), jnp.float32)
@@ -50,7 +51,8 @@ class TestRingAttention:
             import jax, jax.numpy as jnp, numpy as np
             from repro.parallel.ring_attention import ring_attention
             from repro.models.attention import chunked_attention
-            mesh = jax.make_mesh((1, 8), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((1, 8), ("data", "model"))
             rng = np.random.default_rng(1)
             q = jnp.asarray(rng.normal(size=(1,2,64,16)) * 8, jnp.float32)
             k = jnp.asarray(rng.normal(size=(1,2,64,16)) * 8, jnp.float32)
@@ -72,7 +74,8 @@ class TestPipeline:
             from repro.models.registry import get_config, reduce_config
             from repro.models import lm as lm_mod
             from repro.models.schema import init_params
-            mesh = jax.make_mesh((4, 2), ("pod", "data"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("pod", "data"))
             cfg = reduce_config(get_config("llama3.2-3b")).replace(
                 n_layers=8, remat="none")
             params = init_params(jax.random.PRNGKey(0), lm_mod.lm_schema(cfg))
@@ -107,7 +110,8 @@ class TestPipeline:
         out = run_sub("""
             import jax, jax.numpy as jnp, numpy as np
             from repro.parallel.pipeline import pipeline_apply
-            mesh = jax.make_mesh((4, 2), ("pod", "data"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("pod", "data"))
             # toy stage: affine per layer
             L, d = 8, 16
             rng = np.random.default_rng(0)

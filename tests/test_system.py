@@ -118,7 +118,8 @@ class TestCheckpointing:
         """Checkpoints restore onto a different device layout (elastic)."""
         cfg, fns, params = small_setup
         from jax.sharding import NamedSharding, PartitionSpec
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1), ("data", "model"))
         sh = jax.tree_util.tree_map(
             lambda a: NamedSharding(mesh, PartitionSpec()), params)
         with tempfile.TemporaryDirectory() as d:
