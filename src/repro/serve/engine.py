@@ -72,7 +72,7 @@ from repro.serve.paged_step import (check_paged_support, paged_decode_step,
 from repro.serve.radix_cache import RadixCache
 from repro.serve.scheduler import (FINISH_DEADLINE, FINISH_QUARANTINED,
                                    PREFILL, Request, Scheduler)
-from repro.serve.telemetry import Telemetry
+from repro.serve.telemetry import Telemetry, span
 
 
 def _current_device():
@@ -176,6 +176,15 @@ class EngineMetrics:
     faults_injected: int = 0     # injector firings (mirror of the log)
     transient_retries: int = 0   # TransientFaults absorbed by retry
     readback_audits: int = 0     # scatter-readback integrity audits run
+    # where the step's time goes (cumulative, like the counters above)
+    forced_syncs: int = 0        # device waits the engine made on its own:
+    #                              a drain on a finishing step or before
+    #                              host sampling (a caller's drain() is not
+    #                              counted)
+    decode_rows: int = 0         # occupied rows of every decode step, summed
+    admit_blocked_steps: int = 0  # steps whose admission stopped at the
+    #                               queue's head for want of pool blocks
+    #                               while a batch row was free
 
     @property
     def tok_per_s(self) -> float:
@@ -583,6 +592,7 @@ class ContinuousEngine:
             self.guard.reset()
         self.sched.finished.clear()
         self.sched.n_preemptions = 0
+        self.sched.n_admit_blocked = 0
         self.sched.tokens_discarded = 0
         self.metrics = self._fresh_metrics()
         if self.prefix_cache is not None:
@@ -606,6 +616,10 @@ class ContinuousEngine:
         the finishing request's generated tokens are published to the
         radix tree, which needs their values — so drained greedy tokens
         land in that step's events."""
+        with span("serve.step", step_num=self.metrics.steps):
+            return self._step()
+
+    def _step(self) -> Dict[int, List[int]]:
         tel = self.telemetry
         inj = self.faults
         t0 = self._clock()
@@ -614,30 +628,30 @@ class ContinuousEngine:
         if inj is not None:
             inj.begin_step(self.metrics.steps, telemetry=tel)
             self._apply_fault_front(inj, tel)
-        self._enforce_deadlines()
-        self._sync_rows()
-
-        max_admit: Optional[int] = self.max_admit_per_step
-        budget = self.prefill_budget
-        if self.guard is not None:
-            max_admit = self.guard.effective_max_admit(
-                max_admit if max_admit is not None else self.max_batch)
-            budget = self.guard.effective_prefill_budget(budget)
-        admitted = self.sched.admit(max_admit)
-        if tel is not None:
-            for req in admitted:
-                tel.on_admit(req)
-        if self.prefill_chunk:
-            # admitted requests stay PREFILL; prefilling requests advance
-            # one chunk each, oldest first, until the per-step prefill
-            # token budget (if any) is spent — decodes keep their share of
-            # every step even under a herd of long prompts
-            for req in self.sched.chunk_schedule(self.prefill_chunk,
-                                                 budget):
-                self._do_prefill_chunk(req, events)
-        else:
-            for req in admitted:
-                self._do_prefill(req, events)
+        with span("serve.admit"):
+            self._enforce_deadlines()
+            self._sync_rows()
+            max_admit: Optional[int] = self.max_admit_per_step
+            budget = self.prefill_budget
+            if self.guard is not None:
+                max_admit = self.guard.effective_max_admit(
+                    max_admit if max_admit is not None else self.max_batch)
+                budget = self.guard.effective_prefill_budget(budget)
+            admitted = self.sched.admit(max_admit)
+            if tel is not None:
+                for req in admitted:
+                    tel.on_admit(req)
+            # chunked: admitted requests stay PREFILL; prefilling
+            # requests advance one chunk each, oldest first, until the
+            # per-step prefill token budget (if any) is spent — decodes
+            # keep their share of every step even under a herd of long
+            # prompts
+            todo = (self.sched.chunk_schedule(self.prefill_chunk, budget)
+                    if self.prefill_chunk else admitted)
+        prefill = (self._do_prefill_chunk if self.prefill_chunk
+                   else self._do_prefill)
+        for req in todo:
+            prefill(req, events)
         self._drain_if_finishing(events)
         self._evict_finished(tel)                # max_new == 1 requests
 
@@ -663,6 +677,7 @@ class ContinuousEngine:
         self.metrics.peak_blocks = self.pool.stats.peak_in_use
         self.metrics.shared_blocks_peak = self.pool.stats.peak_shared
         self.metrics.cow_copies = self.pool.stats.cow_copies
+        self.metrics.admit_blocked_steps = self.sched.n_admit_blocked
         if self.prefix_cache is not None:
             self.metrics.cache_evictions = self.prefix_cache.stats.evictions
         if self.guard is not None:
@@ -672,9 +687,10 @@ class ContinuousEngine:
         return events
 
     def _evict_finished(self, tel: Optional[Telemetry]) -> None:
-        for req in self.sched.evict_finished():
-            if tel is not None:
-                tel.on_finish(req)
+        with span("serve.evict"):
+            for req in self.sched.evict_finished():
+                if tel is not None:
+                    tel.on_finish(req)
 
     def _sync_rows(self) -> None:
         """Vacate rows whose request left the running set (finished or
@@ -866,22 +882,32 @@ class ContinuousEngine:
         if g.should_quarantine(err):
             self._quarantine(req, err)
 
-    def drain(self) -> Dict[int, List[int]]:
+    def drain(self, cause: str = "caller") -> Dict[int, List[int]]:
         """Materialize every in-flight sampled-token vector into its
-        request's ``tokens`` list. Returns {req_id: fresh tokens}."""
-        tel = self.telemetry
-        n = len(self._pending)
-        t = self._clock() if (tel is not None and n) else 0.0
+        request's ``tokens`` list. Returns {req_id: fresh tokens}.
+        ``cause`` names who waits: ``"caller"``, or the engine itself —
+        ``"finish"`` (a step on which a request finishes) or ``"sample"``
+        (host sampling needs the tokens); the engine's own waits count in
+        ``metrics.forced_syncs``."""
         events: Dict[int, List[int]] = {}
-        for vec, rows in self._pending:
-            arr = np.asarray(vec)                # host↔device sync point
+        n = len(self._pending)
+        if not n:
+            return events
+        if cause != "caller":
+            self.metrics.forced_syncs += 1
+        tel = self.telemetry
+        t = self._clock() if tel is not None else 0.0
+        with span("serve.sync", cause=cause):
+            # host↔device sync point
+            arrs = [np.asarray(vec) for vec, _ in self._pending]
+        for arr, (_, rows) in zip(arrs, self._pending):
             for req, epoch, row in rows:
                 if req.epoch == epoch:           # not preempted since
                     tok = int(arr[row])
                     req.tokens.append(tok)
                     events.setdefault(req.req_id, []).append(tok)
         self._pending.clear()
-        if tel is not None and n:
+        if tel is not None:
             tel.on_drain(t, self._clock() - t, n)
         return events
 
@@ -893,7 +919,7 @@ class ContinuousEngine:
         if self.prefix_cache is None or not self._pending:
             return
         if any(r.done for r in self.sched.running):
-            for rid, toks in self.drain().items():
+            for rid, toks in self.drain("finish").items():
                 events.setdefault(rid, []).extend(toks)
 
     def run(self, on_token: Optional[Callable[[int, List[int]], None]] = None
@@ -1006,28 +1032,29 @@ class ContinuousEngine:
         t = self._clock() if tel is not None else 0.0
         plen = req.prompt_len
         m = req.n_prefix_hit
-        if m > 0:
-            greedy, lg = self._prefill_from_offset(req, m)
-        else:
-            greedy, lg = self._prefill_full(req)
-        req.n_prefilled = plen
-        self.metrics.prefill_tokens += plen - m
-        self.metrics.prefix_hit_tokens += m
-        if tel is not None:
-            tel.on_prefill(req, "prefill-suffix" if m > 0 else "prefill",
-                           plen - m,
-                           self._pow2_bucket(-(-plen // self.block_size)),
-                           t, self._clock() - t)
-        if self.faults is not None and self.faults.take_kv_corrupt():
-            self._corrupt_request_blocks(req)      # bad scatter, post hoc
-        self._join_decode(req, greedy, lg, events)
-        if tel is not None:
-            probe = tel.maybe_numerics_probe(self, req)
-            if probe:
-                self._step_logit_err = max(
-                    self._step_logit_err,
-                    float(probe.get("logit_error", 0.0)))
-        self._audit_and_quarantine(req, lg)
+        width = self._pow2_bucket(-(-plen // self.block_size))
+        with span("serve.prefill", req=req.req_id, tokens=plen - m,
+                  width=width):
+            if m > 0:
+                greedy, lg = self._prefill_from_offset(req, m)
+            else:
+                greedy, lg = self._prefill_full(req)
+            req.n_prefilled = plen
+            self.metrics.prefill_tokens += plen - m
+            self.metrics.prefix_hit_tokens += m
+            if tel is not None:
+                tel.on_prefill(req, "prefill-suffix" if m > 0 else "prefill",
+                               plen - m, width, t, self._clock() - t)
+            if self.faults is not None and self.faults.take_kv_corrupt():
+                self._corrupt_request_blocks(req)      # bad scatter, post hoc
+            self._join_decode(req, greedy, lg, events)
+            if tel is not None:
+                probe = tel.maybe_numerics_probe(self, req)
+                if probe:
+                    self._step_logit_err = max(
+                        self._step_logit_err,
+                        float(probe.get("logit_error", 0.0)))
+            self._audit_and_quarantine(req, lg)
 
     def _do_prefill_chunk(self, req: Request,
                           events: Dict[int, List[int]]) -> None:
@@ -1043,61 +1070,62 @@ class ContinuousEngine:
         m, sl = self.sched.next_chunk(req, C)
         if m == req.n_prefix_hit:        # first chunk of this admission
             self.metrics.prefix_hit_tokens += m
-        tokens = np.zeros((1, C), np.int32)
-        tokens[0, :sl] = req.prompt[m:m + sl]
-        table = np.asarray(self.pool.blocks_of(req.req_id), np.int32)
         cover = -(-(m + sl) // bs)       # blocks holding positions < m+sl
         # chunk tables bucket to multiples of the chunk's own block count
         # (not pow2) — see table_width_bucket for why that bound is also
         # the paged_prefill_chunked table contract
-        cq = C // bs
-        w = table_width_bucket(cover, chunk_blocks=cq)
-        pt = np.zeros((1, w), np.int32)
-        pt[0, :cover] = table[:cover]
-        pos = m + np.arange(C)
-        blk = np.zeros((C,), np.int32)   # pad rows -> garbage block 0
-        off = np.zeros((C,), np.int32)
-        blk[:sl] = table[pos[:sl] // bs]
-        off[:sl] = pos[:sl] % bs
-        greedy, lg, *pools = self._prefill_chunk_fn(
-            self.params, jnp.asarray(tokens), jnp.asarray(m, jnp.int32),
-            jnp.asarray([sl - 1], jnp.int32), jnp.asarray(pt),
-            jnp.asarray(blk), jnp.asarray(off), *self._pools())
-        self._set_pools(pools)
-        req.n_prefilled = m + sl
-        self.metrics.prefill_tokens += sl
-        self.metrics.prefill_chunks += 1
-        if tel is not None:
-            # modeled cost of the chunk's paged-prefill kernel launch
-            # (per layer); pos0 = m, one row, real table cover = cover
-            cost = prefill_launch_cost(
-                C, [m], [cover], w, n_q_heads=self.cfg.n_heads,
-                n_kv_heads=self.cfg.n_kv_heads,
-                head_dim=self.cfg.head_dim, block_size=self.block_size,
-                kv_tile_blocks=self.kv_tile_blocks,
-                kv_dtype=self.pool.kv_dtype)
-            tel.on_prefill(req, "prefill-chunk", sl, w, t,
-                           self._clock() - t, cost=cost,
-                           launches=self.cfg.n_layers)
-        if req.n_prefilled == req.prompt_len:
-            if self.faults is not None and self.faults.take_kv_corrupt():
-                self._corrupt_request_blocks(req)  # bad scatter, post hoc
-            self._join_decode(req, greedy, lg, events)
+        w = table_width_bucket(cover, chunk_blocks=C // bs)
+        with span("serve.prefill_chunk", req=req.req_id, tokens=sl,
+                  width=w):
+            tokens = np.zeros((1, C), np.int32)
+            tokens[0, :sl] = req.prompt[m:m + sl]
+            table = np.asarray(self.pool.blocks_of(req.req_id), np.int32)
+            pt = np.zeros((1, w), np.int32)
+            pt[0, :cover] = table[:cover]
+            pos = m + np.arange(C)
+            blk = np.zeros((C,), np.int32)   # pad rows -> garbage block 0
+            off = np.zeros((C,), np.int32)
+            blk[:sl] = table[pos[:sl] // bs]
+            off[:sl] = pos[:sl] % bs
+            greedy, lg, *pools = self._prefill_chunk_fn(
+                self.params, jnp.asarray(tokens), jnp.asarray(m, jnp.int32),
+                jnp.asarray([sl - 1], jnp.int32), jnp.asarray(pt),
+                jnp.asarray(blk), jnp.asarray(off), *self._pools())
+            self._set_pools(pools)
+            req.n_prefilled = m + sl
+            self.metrics.prefill_tokens += sl
+            self.metrics.prefill_chunks += 1
             if tel is not None:
-                probe = tel.maybe_numerics_probe(self, req)
-                if probe:
-                    self._step_logit_err = max(
-                        self._step_logit_err,
-                        float(probe.get("logit_error", 0.0)))
-            self._audit_and_quarantine(req, lg)
-        elif self.prefix_cache is not None:
-            # publish completed chunks as they land — including a partial
-            # tail block (its leaf is promoted in place by insert() once
-            # later chunks fill the block, so no stale double-owner
-            # survives) — so a request admitted while this long prompt is
-            # still mid-prefill gets the maximal possible hit
-            self.prefix_cache.insert(req.req_id,
-                                     req.prompt[:req.n_prefilled])
+                # modeled cost of the chunk's paged-prefill kernel launch
+                # (per layer); pos0 = m, one row, real table cover = cover
+                cost = prefill_launch_cost(
+                    C, [m], [cover], w, n_q_heads=self.cfg.n_heads,
+                    n_kv_heads=self.cfg.n_kv_heads,
+                    head_dim=self.cfg.head_dim, block_size=self.block_size,
+                    kv_tile_blocks=self.kv_tile_blocks,
+                    kv_dtype=self.pool.kv_dtype)
+                tel.on_prefill(req, "prefill-chunk", sl, w, t,
+                               self._clock() - t, cost=cost,
+                               launches=self.cfg.n_layers)
+            if req.n_prefilled == req.prompt_len:
+                if self.faults is not None and self.faults.take_kv_corrupt():
+                    self._corrupt_request_blocks(req)  # bad scatter, post hoc
+                self._join_decode(req, greedy, lg, events)
+                if tel is not None:
+                    probe = tel.maybe_numerics_probe(self, req)
+                    if probe:
+                        self._step_logit_err = max(
+                            self._step_logit_err,
+                            float(probe.get("logit_error", 0.0)))
+                self._audit_and_quarantine(req, lg)
+            elif self.prefix_cache is not None:
+                # publish completed chunks as they land — including a partial
+                # tail block (its leaf is promoted in place by insert() once
+                # later chunks fill the block, so no stale double-owner
+                # survives) — so a request admitted while this long prompt is
+                # still mid-prefill gets the maximal possible hit
+                self.prefix_cache.insert(req.req_id,
+                                         req.prompt[:req.n_prefilled])
 
     def _join_decode(self, req: Request, greedy, lg,
                      events: Dict[int, List[int]]) -> None:
@@ -1159,32 +1187,33 @@ class ContinuousEngine:
         if greedy_only:
             tokens1 = self._vec          # previous step's vector, on device
         else:
-            for rid, toks in self.drain().items():
+            for rid, toks in self.drain("sample").items():
                 events.setdefault(rid, []).extend(toks)
             t1 = np.zeros((B,), np.int32)
             for i, req in occ:
                 t1[i] = req.tokens[-1]
             tokens1 = jnp.asarray(t1)
 
-        lengths = np.zeros((B,), np.int32)
-        for i, req in occ:
-            lengths[i] = req.n_cached
         w = self._table_width(occ)
-        bt = np.zeros((B, w), np.int32)
-        bt[[i for i, _ in occ]] = self.pool.table_array(
-            [r.req_id for _, r in occ], w)
+        with span("serve.decode", rows=len(occ), width=w):
+            lengths = np.zeros((B,), np.int32)
+            for i, req in occ:
+                lengths[i] = req.n_cached
+            bt = np.zeros((B, w), np.int32)
+            bt[[i for i, _ in occ]] = self.pool.table_array(
+                [r.req_id for _, r in occ], w)
 
-        # the kernel attends lengths+1 on every row (zombies included,
-        # masked) — plan and account against what it actually does
-        tile, split = self.kv_tile_blocks, self.decode_split_k
-        plan = None
-        if self.planner is not None and self.autotune == "per-step":
-            plan = self.planner.plan_decode(lengths + 1, w)
-            tile, split = plan.kv_tile_blocks, plan.split_k
-        greedy, lg, *pools = self._decode(
-            self.params, tokens1, jnp.asarray(bt), jnp.asarray(lengths),
-            *self._pools(), tile=tile, split=split)
-        self._set_pools(pools)
+            # the kernel attends lengths+1 on every row (zombies included,
+            # masked) — plan and account against what it actually does
+            tile, split = self.kv_tile_blocks, self.decode_split_k
+            plan = None
+            if self.planner is not None and self.autotune == "per-step":
+                plan = self.planner.plan_decode(lengths + 1, w)
+                tile, split = plan.kv_tile_blocks, plan.split_k
+            greedy, lg, *pools = self._decode(
+                self.params, tokens1, jnp.asarray(bt), jnp.asarray(lengths),
+                *self._pools(), tile=tile, split=split)
+            self._set_pools(pools)
 
         if greedy_only:
             # async: token values stay on device until drained; bookkeeping
@@ -1207,6 +1236,7 @@ class ContinuousEngine:
                 events.setdefault(req.req_id, []).append(tok)
             self._vec = jnp.asarray(toks)
         self.metrics.decode_steps += 1
+        self.metrics.decode_rows += len(occ)
         self.metrics.tokens_out += len(occ)
         if tel is not None:
             now = self._clock()

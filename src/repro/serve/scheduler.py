@@ -164,6 +164,9 @@ class Scheduler:
         self._next_id = 0
         self._reserved: Dict[int, int] = {}   # future growth blocks held
         self.n_preemptions = 0
+        self.n_admit_blocked = 0      # admit() calls that stopped at the
+        #                               queue's head for want of pool
+        #                               blocks while a batch row was free
         self.tokens_discarded = 0     # generated tokens thrown away by
         #                               preemption (recomputed on readmit)
 
@@ -257,6 +260,7 @@ class Scheduler:
             else:
                 cplan, fresh, budget = None, total, self.pool.num_free
             if budget - self._outstanding() < fresh:
+                self.n_admit_blocked += 1
                 break        # strict FIFO: don't let short requests overtake
             self.waiting.popleft()
             hit = 0

@@ -349,6 +349,7 @@ def snapshot_state(engine) -> Snapshot:
         "reserved": {str(rid): int(n) for rid, n in sched._reserved.items()},
         "next_id": int(sched._next_id),
         "n_preemptions": int(sched.n_preemptions),
+        "n_admit_blocked": int(sched.n_admit_blocked),
         "tokens_discarded": int(sched.tokens_discarded),
     }
 
@@ -480,6 +481,7 @@ def apply_snapshot(engine, snap: Snapshot, fsck: bool = True) -> None:
     sched._reserved = {int(rid): int(n) for rid, n in sc["reserved"].items()}
     sched._next_id = int(sc["next_id"])
     sched.n_preemptions = int(sc["n_preemptions"])
+    sched.n_admit_blocked = int(sc.get("n_admit_blocked", 0))
     sched.tokens_discarded = int(sc["tokens_discarded"])
 
     # --- engine ------------------------------------------------------------

@@ -30,6 +30,14 @@ instants land on tid = req_id so Perfetto shows one lane per request.
 **Clock injection**: all timestamps come from ``Telemetry.clock`` (default
 ``time.monotonic``); ``ManualClock`` makes tests fully deterministic.
 
+**Profiler spans** (``span``): independent of any ``Telemetry``, the engine
+wraps its phases in ``jax.profiler`` annotations (``serve.step``,
+``serve.admit``, ``serve.prefill_chunk``/``serve.prefill``,
+``serve.decode``, ``serve.sync``, ``serve.evict``). A running
+``jax.profiler`` trace records them on the host line, on the same clock as
+the device's operations; with no trace running each costs about a
+microsecond.
+
 **Online numerics monitors** (``numerics_every > 0`` on an int8 engine):
 every Nth completed prefill re-runs that request's prompt prefix through
 ``serve/paged_step.paged_prefill_audit`` — a lockstep full-precision vs
@@ -46,9 +54,20 @@ import json
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from repro.serve.metrics import MetricRegistry
 
 Clock = Callable[[], float]
+
+
+def span(name: str, **args):
+    """A host span on the profiler's clock, as a context manager; ``args``
+    are recorded as its stats. With ``step_num`` it is a step marker, so
+    the profiler's step view groups by engine step."""
+    if "step_num" in args:
+        return StepTraceAnnotation(name, **args)
+    return TraceAnnotation(name, **args)
 
 
 class ManualClock:
@@ -216,7 +235,9 @@ class Telemetry:
                         "request (dispatch-time convention)")
         self.h_e2e = h("serve_e2e_seconds", "submit to finish")
         self.h_queue = h("serve_queue_wait_seconds", "submit to admission")
-        self.h_step = h("serve_step_seconds", "one engine step() call")
+        self.h_step = h("serve_step_seconds",
+                        "host time of one engine step() call, including "
+                        "the device waits it made (not device step time)")
         c = reg.counter
         self.c_submitted = c("serve_requests_submitted_total",
                              "requests enqueued")
@@ -355,9 +376,6 @@ class Telemetry:
                 tr.t_first_token = req.t_first_token
             tr.n_tokens = req.n_generated
         self._mark(req, "first_token", req.t_first_token)
-
-    def on_decode_token(self, req, now: float) -> None:
-        self.on_decode_tokens((req,), now)
 
     def on_decode_tokens(self, reqs, now: float) -> None:
         """Per-token accounting for one decode step, batched: the engine
@@ -514,7 +532,10 @@ class Telemetry:
         ("serve_prefill_savings", "prefill_savings"),
         ("serve_wall_seconds", "wall_s"),
         ("serve_kv_pool_bytes", "kv_pool_bytes"),
-        ("serve_pool_token_capacity", "pool_token_capacity"))
+        ("serve_pool_token_capacity", "pool_token_capacity"),
+        ("serve_forced_syncs", "forced_syncs"),
+        ("serve_decode_rows", "decode_rows"),
+        ("serve_admit_blocked_steps", "admit_blocked_steps"))
     _POOL_GAUGES = (
         ("pool_blocks_in_use", "blocks_in_use"),
         ("pool_blocks_peak", "peak_in_use"),
