@@ -12,12 +12,12 @@ physical pool through the same tables):
   group's shared KV block (group× redundant operand movement), one
   ``block_size``-row block per kv grid step, the whole walk serialized on
   one lane per head.
-* **grouped**  — this PR (``flash_decode_paged``): grid
-  ``(B*Hkv, split_k, W/(T*split_k))`` — one gather feeds the whole query
-  group (``(group, D)`` MXU tiles instead of ``(1, D)`` vector dots), each
-  step streams a ``kv_tile_blocks``-block KV tile, compute skips tiles past
-  a row's length, and the split partials merge through the associative
-  Softermax combine.
+* **grouped**  — ``flash_decode_paged``: grid
+  ``(B, split_k, W/(T*split_k))`` — one whole-block gather (every KV head)
+  feeds every query head (``(Hkv, group, D)`` batched MXU tiles instead of
+  ``(1, D)`` vector dots), each step streams a ``kv_tile_blocks``-block KV
+  tile, tiles past a row's length neither compute nor copy, and the split
+  partials merge through the associative Softermax combine.
 
 Two measurements:
 
@@ -117,14 +117,15 @@ def _time_kernels(args, rng):
         jax.block_until_ready(run_grouped())
         grouped_s.append(time.time() - t0)
 
-    # modeled gather traffic per decoded token, per layer (README math):
-    # the per-head kernel walks W blocks once per *query* head, the
-    # grouped kernel once per *KV* head over the (tile-padded) table —
-    # padded exactly as the kernel wrapper pads it (shared split_layout)
-    _, _, _, Wp = split_layout(W, args.tile_blocks, args.split_k)
+    # modeled gather traffic per decoded token, per layer (README math)
+    # for a row that fills the table: the per-head kernel walks W blocks
+    # once per *query* head; the grouped kernel copies each live block
+    # once, every KV head in one copy, and then each slot of the last
+    # tile once more (shared split_layout), its dead steps copying nothing
+    T, _, _, Wp = split_layout(W, args.tile_blocks, args.split_k)
     itm = np.dtype(np.float32).itemsize
     bytes_single = 2 * Hq * W * BS * D * itm
-    bytes_grouped = 2 * Hkv * Wp * BS * D * itm
+    bytes_grouped = 2 * Hkv * (W - 1 + min(T, Wp - W + 1)) * BS * D * itm
     return (float(min(single_s)), float(min(grouped_s)),
             {"mode": "compiled-tpu" if not interpret else "pallas-interpret",
              "table_width": int(W), "padded_width": int(Wp),

@@ -182,11 +182,12 @@ def kernel_parity(cfg, seed: int, interpret: bool = False):
     for name, (k, v, sc) in pools.items():
         with jax.default_matmul_precision("highest"):
             want = paged_decode_ref(q, k, v, bt, lens, **sc)
-        for tile, split in ((1, 1), (8, 2)):
+        for tile, split in ((1, 1), (None, 1), (8, 2)):
             got = flash_decode_paged(q, k, v, bt, lens, kv_tile_blocks=tile,
                                      split_k=split, interpret=interpret,
                                      **sc)
-            errors[f"decode/{name}/T{tile}/S{split}"] = rel(got, want)
+            errors[f"decode/{name}/T{tile or 'derived'}/S{split}"] = \
+                rel(got, want)
 
     batch = 2
     qp = normal(keys[3], (batch, hq, PREFILL_CHUNK, d), d ** -0.5)

@@ -28,9 +28,10 @@ def main():
     ap.add_argument("--num-blocks", type=int, default=64)
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="disable radix-tree prompt-prefix reuse")
-    ap.add_argument("--kv-tile-blocks", type=int, default=1,
+    ap.add_argument("--kv-tile-blocks", type=int, default=None,
                     help="pool blocks per kernel kv grid step (TPU knob; "
-                         "layout-only, identical outputs)")
+                         "layout-only, identical outputs; unset: derived "
+                         "from the block shape for decode, 1 for prefill)")
     ap.add_argument("--decode-split-k", type=int, default=1,
                     help="parallel KV partitions per decode lane (TPU "
                          "knob; same attention up to fp summation order "

@@ -8,7 +8,8 @@ Softermax recurrence is order-free (every rescale is an exact exponent add),
 blocks can be streamed in table order with no pre-pass over the scores, which
 is exactly what makes the paged layout free for this kernel.
 
-The block table and the per-row lengths are scalar-prefetch operands
+The block ids each grid column reads (the block table, clamped as below)
+and the per-row lengths are scalar-prefetch operands
 (``PrefetchScalarGridSpec``, SMEM): their entries are available *before*
 the kernel body runs, so the KV BlockSpec index maps perform the gather —
 each grid step DMAs physical blocks from the pool directly into VMEM — and
@@ -19,20 +20,26 @@ block's last two dims must be (8, 128)-divisible or span the array.)
 Three grid-level restructurings over the naive per-head walk (all three are
 pure reorganizations of the same recurrence — outputs are unchanged):
 
-* **GQA grouping.** Grid axis 0 is ``B*Hkv``, not ``B*Hq``: one lane owns a
-  whole GQA group, its query tile is ``(group, D)``, and the block-table
-  gather that used to run once per *query* head now runs once per *KV*
-  head — a ``group``× cut in gather DMA — while the QK/AV dots grow from
-  ``(1, D)`` vector products into real ``(group, ·)`` MXU matmuls.
+* **Whole-block gathers, KV heads folded in.** The grid is ``(B, S,
+  spl)``: one step owns a row's every query head, its query tile is
+  ``(Hkv, G, D)``, and each gather operand takes one whole pool block
+  ``(1, Hkv, BS, D)`` — every KV head of the block in one contiguous DMA
+  (32 KB at 8 heads of 128 in bf16) where a per-head grid moved it in
+  ``Hkv`` copies from ``Hkv`` steps. The QK and AV dots run batched over
+  the head axis. On a TPU v5e each grid step and gather operand costs
+  fixed scalar work, so the grid's size, not the bytes, set the time of
+  the per-head walk.
 * **Multi-block KV tiles.** Each kv grid step gathers ``kv_tile_blocks``
-  (T) pool blocks — T block-granular DMAs the pipeline overlaps within one
-  step — and processes them as a single ``(T*BS, D)`` VMEM tile, so with
-  ``T*BS >= 128`` the dots are MXU-shaped and the per-step mask/rescale
-  overhead amortizes over T blocks. Table entries past the real table width
-  are clamped to the pool's reserved garbage block 0 (the wrapper pads the
-  table), and ``@pl.when`` skips compute on tiles that start past the
-  sequence length, so short requests stop paying for the batch-max table
-  width.
+  (T) whole blocks — T DMAs the pipeline overlaps within one step — and
+  processes them as one ``(Hkv, T*BS, D)`` VMEM tile. Without a tile from
+  the caller, T comes from the block shape (``ref.decode_tile_blocks``:
+  ``T*BS >= 128`` rows, a K tile of at most 512 KiB, so many KV heads or
+  wide heads get a smaller T), clamped to the table width. The block each
+  slot reads is looked up once per launch (``ref.live_block_column``): a
+  slot past its row's live blocks reads the row's last live block, so
+  dead slots and the steps past a row's length repeat a block index, and
+  the pipeline copies nothing for a repeated index; ``@pl.when`` skips
+  their compute.
 * **Split-K.** The KV walk is partitioned across a *parallel* grid axis of
   ``split_k`` lanes; each lane emits its partial ``(m, d, acc)`` state and
   a small jnp second stage merges them with the associative Softermax
@@ -47,19 +54,20 @@ DMA moves half the bytes, and dequantization is fused *after* the matmuls
 instead of widening the tiles — ``S = q·Kᵀ`` against the raw int8 codes
 then ``S *= k_scale`` per column (exact: the scale is a per-row constant of
 K), and ``p *= v_scale`` before ``p·V`` (same identity on the V side). Both
-rescales touch the (group, T*BS) score tile, not the (T*BS, D) value tile,
-so the dequant cost stays O(tile-row) while the accumulate stays fp32 — the
+rescales touch the (Hkv, group, T*BS) score tile, not the value tile, so
+the dequant cost stays O(tile-row) while the accumulate stays fp32 — the
 paper's int-storage / wide-accumulate split applied to the KV side. TPU
 tiling note: int8 VMEM tiles are (32, 128)-granular (vs (16, 128) for
 bf16), so int8 pools waste no sublane padding when ``block_size >= 32``.
 
 Table entries past a sequence's length may be garbage (the pool's reserved
-block 0): the length mask zeroes their contribution and the gather of block
-0 is a wasted-but-harmless DMA.
+block 0): no slot reads them, and the length mask zeroes the columns of the
+last live block past the length.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -68,22 +76,27 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.numerics import NEG_INF
 from repro.core.softermax import softermax_finalize, softermax_merge
-from repro.kernels.flash_decode_paged.ref import split_layout
+from repro.kernels.flash_decode_paged.ref import (decode_tile_blocks,
+                                                  live_block_column,
+                                                  split_layout)
 
 
-def concat_tiles(refs, axis: int = 0):
+def concat_tiles(refs, axis: int = 0, lead: int = 2):
     """Assemble one VMEM tile from the T per-slot gather operands (each
-    ref holds one pool block, leading (1, 1) block axes stripped). Shared
-    by the decode and prefill kernel bodies — values concat along rows
-    (axis 0), the (1, BS) scale rows along columns (axis 1)."""
+    ref holds one pool block; its ``lead`` leading unit block axes are
+    stripped). Shared by the decode and prefill kernel bodies: the
+    prefill kernel's ``(1, 1, BS, D)`` blocks concat along rows (axis 0)
+    and its ``(1, BS)`` scale rows along columns (axis 1); the decode
+    kernel's whole-block ``(1, Hkv, BS, D)`` operands (``lead=1``) concat
+    along rows (axis 1) and its ``(Hkv, 1, BS)`` scales along axis 2."""
+    idx = (0,) * lead
     if len(refs) == 1:
-        return refs[0][0, 0]
-    return jnp.concatenate([r[0, 0] for r in refs], axis=axis)
+        return refs[0][idx]
+    return jnp.concatenate([r[idx] for r in refs], axis=axis)
 
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, intmax: bool,
-                         block_size: int, tile_blocks: int, quantized: bool,
-                         kv_heads: int):
+def _paged_decode_kernel(ids_ref, len_ref, q_ref, *rest, intmax: bool,
+                         block_size: int, tile_blocks: int, quantized: bool):
     T = tile_blocks
     k_refs, v_refs = rest[:T], rest[T:2 * T]
     n = 2 * T
@@ -100,41 +113,41 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, intmax: bool,
         d_scr[...] = jnp.zeros_like(d_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kv_len = len_ref[pl.program_id(0) // kv_heads]
+    kv_len = len_ref[pl.program_id(0)]
     jj = pl.program_id(1) * spl + j           # global kv tile index
     k_start = jj * (T * block_size)
 
     @pl.when(k_start < kv_len)
     def _body():
-        q = q_ref[0].astype(jnp.float32)      # (G, D)
-        # T block-granular gathers assembled into one (T*BS, D) VMEM tile
-        k = concat_tiles(k_refs)
-        v = concat_tiles(v_refs)
+        q = q_ref[0].astype(jnp.float32)      # (Hkv, G, D)
+        # T whole-block gathers (every KV head) as one (Hkv, T*BS, D) tile
+        k = concat_tiles(k_refs, axis=1, lead=1)
+        v = concat_tiles(v_refs, axis=1, lead=1)
         s = jax.lax.dot_general(
-            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)   # (G, T*BS)
+            q, k.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)   # (Hkv, G, T*BS)
         if quantized:
             # dequant fused post-dot: k_scale is constant per K row, so
             # scaling the (G, T*BS) score columns equals scaling the
             # (T*BS, D) tile — for a fraction of the flops
-            s = s * concat_tiles(ksc_refs, axis=1)   # (1, T*BS) broadcast
-        kj = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = s * concat_tiles(ksc_refs, axis=2, lead=1)  # (Hkv, 1, T*BS)
+        kj = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(kj < kv_len, s, NEG_INF)
         m_prev = m_scr[...]
         # IntMax via ceil-after-reduce (ceil is monotone, so this equals
-        # max(ceil(s)) with a (G, 1) ceil instead of a (G, T*BS) pass)
-        sm = jnp.max(s, axis=1, keepdims=True)
+        # max(ceil(s)) with a (Hkv, G, 1) ceil instead of a full-tile pass)
+        sm = jnp.max(s, axis=2, keepdims=True)
         m_new = jnp.maximum(m_prev, jnp.ceil(sm) if intmax else sm)
         alpha = jnp.exp2(m_prev - m_new)      # exact power-of-two
         p = jnp.exp2(s - m_new)
         if quantized:
-            pv = p * concat_tiles(vsc_refs, axis=1)  # fold v_scale into p
+            pv = p * concat_tiles(vsc_refs, axis=2, lead=1)  # fold v_scale
         else:
             pv = p
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            pv, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        d_scr[...] = d_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            pv, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)   # (Hkv, G, D)
+        d_scr[...] = d_scr[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
         m_scr[...] = m_new
 
     @pl.when(j == spl - 1)
@@ -159,7 +172,9 @@ def flash_decode_paged(
     k_scale: jax.Array = None,   # (N, Hkv, BS) f32: int8 pools' row scales
     v_scale: jax.Array = None,
     intmax: bool = True,
-    kv_tile_blocks: int = 1,  # pool blocks gathered per kv grid step (T)
+    kv_tile_blocks: Optional[int] = None,  # pool blocks a kv grid step
+    #                                        gathers (T); None derives it
+    #                                        from the block shape
     split_k: int = 1,         # parallel partitions of the KV walk
     interpret: bool = False,
 ) -> jax.Array:
@@ -169,81 +184,85 @@ def flash_decode_paged(
     G = Hq // Hkv
     quantized = k_scale is not None
 
+    if kv_tile_blocks is None:
+        kv_tile_blocks = decode_tile_blocks(BS, Hkv, D,
+                                            k_pool.dtype.itemsize)
     # clamp the tiling to the table (shared geometry — ref.split_layout):
-    # T-block tiles, S split lanes of spl tiles each; the table pads to
-    # the S*spl*T cover with garbage block 0 (padded entries sit past
-    # every length — masked, and their repeated block-0 gather is a
-    # harmless DMA)
+    # T-block tiles, S split lanes of spl tiles each, covering Wp >= W
+    # table columns. The block each (grid column, row) gathers is looked
+    # up once here, not per grid step: its live table entry, else the
+    # row's last live one (``live_block_column``), so dead slots and dead
+    # steps repeat a block index and the pipeline copies nothing for them
     T, S, spl, Wp = split_layout(W, kv_tile_blocks, split_k)
-    bt = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, Wp - W)))
-
-    qf = q.reshape(B * Hkv, G, D)
     lens = lengths.astype(jnp.int32)
+    cols = live_block_column(jnp.arange(Wp, dtype=jnp.int32)[None, :],
+                             lens[:, None], BS, W)
+    ids = jnp.take_along_axis(block_tables.astype(jnp.int32), cols, axis=1)
+    qf = q.reshape(B, Hkv, G, D)
 
     def kv_map(t):
         # one gather map per tile slot; values and scales share it
-        def _map(bh, s, j, bt_ref, len_ref):
-            jj = s * spl + j
-            return (bt_ref[bh // Hkv, jj * T + t], bh % Hkv, 0, 0)
+        def _map(b, s, j, ids_ref, len_ref):
+            return (ids_ref[b, (s * spl + j) * T + t], 0, 0, 0)
         return _map
 
     in_specs = [
-        pl.BlockSpec((1, G, D), lambda bh, s, j, bt_ref, len_ref: (bh, 0, 0)),
+        pl.BlockSpec((1, Hkv, G, D),
+                     lambda b, s, j, ids_ref, len_ref: (b, 0, 0, 0)),
     ]
-    in_specs += [pl.BlockSpec((1, 1, BS, D), kv_map(t)) for t in range(T)]
-    in_specs += [pl.BlockSpec((1, 1, BS, D), kv_map(t)) for t in range(T)]
+    in_specs += [pl.BlockSpec((1, Hkv, BS, D), kv_map(t)) for t in range(T)]
+    in_specs += [pl.BlockSpec((1, Hkv, BS, D), kv_map(t)) for t in range(T)]
     inputs = [qf] + [k_pool] * T + [v_pool] * T
     if quantized:
         # scales ride the same scalar-prefetch block-table gather as the
-        # values; the trailing unit axis keeps in-kernel reads 2-D
+        # values; the unit axis keeps in-kernel reads (Hkv, 1, BS)
         ksr = k_scale.astype(jnp.float32).reshape(N, Hkv, 1, BS)
         vsr = v_scale.astype(jnp.float32).reshape(N, Hkv, 1, BS)
-        in_specs += [pl.BlockSpec((1, 1, 1, BS), kv_map(t))
+        in_specs += [pl.BlockSpec((1, Hkv, 1, BS), kv_map(t))
                      for t in range(T)]
-        in_specs += [pl.BlockSpec((1, 1, 1, BS), kv_map(t))
+        in_specs += [pl.BlockSpec((1, Hkv, 1, BS), kv_map(t))
                      for t in range(T)]
         inputs += [ksr] * T + [vsr] * T
 
-    def out_map(bh, s, j, bt_ref, len_ref):
-        return (bh, s, 0, 0)
+    def out_map(b, s, j, ids_ref, len_ref):
+        return (b, s, 0, 0, 0)
 
-    part = pl.BlockSpec((1, 1, G, 1), out_map)
+    part = pl.BlockSpec((1, 1, Hkv, G, 1), out_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * Hkv, S, spl),
+        grid=(B, S, spl),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, G, D), out_map),
+            pl.BlockSpec((1, 1, Hkv, G, D), out_map),
             part, part,
         ],
         scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, D), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
         ],
     )
 
     acc, m, d = pl.pallas_call(
         functools.partial(_paged_decode_kernel, intmax=intmax,
-                          block_size=BS, tile_blocks=T, quantized=quantized,
-                          kv_heads=Hkv),
+                          block_size=BS, tile_blocks=T, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B * Hkv, S, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hkv, S, G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hkv, S, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, S, Hkv, G, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, S, Hkv, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, S, Hkv, G, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(bt, lens, *inputs)
+    )(ids, lens, *inputs)
 
     # second stage: associative Softermax merge of the split partials under
     # the joint (Int)Max, then the one deferred normalize. With split_k=1
     # this is exactly the old in-kernel epilogue (scale = 2^0 = 1).
     _, d2, acc2 = softermax_merge(m, d, acc, axis=1)
-    o = softermax_finalize(acc2, d2)          # (B*Hkv, G, D)
+    o = softermax_finalize(acc2, d2)          # (B, Hkv, G, D)
     return o.reshape(B, Hq, D).astype(q.dtype)
 
 

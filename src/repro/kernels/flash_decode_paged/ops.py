@@ -3,9 +3,11 @@
 Single dispatcher for every caller (the serving engine's fused decode step
 routes here too):
 
-* TPU backend          — the compiled Pallas kernel: GQA-grouped lanes,
-  ``kv_tile_blocks``-block KV tiles, ``split_k`` parallel partitions merged
-  by the associative Softermax combine.
+* TPU backend          — the compiled Pallas kernel: one grid step per
+  (row, split lane, tile of ``kv_tile_blocks`` whole pool blocks, every KV
+  head), ``split_k`` parallel partitions merged by the associative
+  Softermax combine; with no tile given, T comes from the block shape
+  (``ref.decode_tile_blocks``).
 * ``interpret=True``   — the same kernel under the Pallas interpreter (CPU
   CI exercises the exact grid/tile/split dataflow this way).
 * anywhere else        — pure JAX: the gather oracle ``paged_decode_ref``.
@@ -16,6 +18,8 @@ routes here too):
   testing the partition structure itself.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 
@@ -31,7 +35,7 @@ from repro.kernels.flash_decode_paged.ref import (decode_gather_oracle,
 def flash_decode_paged_op(q, k_pool, v_pool, block_tables, lengths, *,
                           k_scale=None, v_scale=None,
                           intmax: bool = True,
-                          kv_tile_blocks: int = 1,
+                          kv_tile_blocks: Optional[int] = None,
                           split_k: int = 1,
                           interpret: bool = False) -> jax.Array:
     if interpret:

@@ -25,8 +25,11 @@ that sit entirely past a sequence's length.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.numerics import NEG_INF
 from repro.core.softermax import softermax_finalize, softermax_merge
@@ -44,6 +47,38 @@ def split_layout(W: int, kv_tile_blocks: int, split_k: int):
     S = max(1, min(split_k, tiles))
     spl = -(-tiles // S)
     return T, S, spl, S * spl * T
+
+
+# the derived decode tile: enough pool blocks that one grid step's dots
+# reach ``DECODE_TILE_ROWS`` token rows, but never a K tile (every KV head
+# of T blocks) past ``DECODE_TILE_BYTES`` of VMEM
+DECODE_TILE_ROWS = 128
+DECODE_TILE_BYTES = 512 * 1024
+
+
+def decode_tile_blocks(block_size: int, kv_heads: int, head_dim: int,
+                       itemsize: int) -> int:
+    """THE decode tile T when the caller names none, from the pool's block
+    shape ``(Hkv, BS, D)`` and element size: the fewest blocks giving
+    ``T*BS >= DECODE_TILE_ROWS`` rows, cut to what ``DECODE_TILE_BYTES``
+    holds of K, at least 1. ``split_layout`` then clamps it to the table
+    width. A model with many KV heads or wide heads gets a smaller T."""
+    rows = -(-DECODE_TILE_ROWS // block_size)
+    fit = DECODE_TILE_BYTES // (kv_heads * block_size * head_dim * itemsize)
+    return max(1, min(rows, fit))
+
+
+def live_block_column(col, kv_len, block_size: int, width: int):
+    """The table column a decode gather slot reads for grid column
+    ``col``: the column itself while it holds a live block, else the
+    row's last live column (column 0 for an empty row), never past the
+    table. Dead slots and dead steps so repeat the block they read last,
+    and the pipeline issues no copy for a block index that repeats. The
+    kernel wrapper and the gather oracle both look up their block ids
+    through it."""
+    last = jnp.clip((kv_len + block_size - 1) // block_size - 1, 0,
+                    width - 1)
+    return jnp.minimum(col, last)
 
 
 def gather_kv(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
@@ -82,41 +117,53 @@ def decode_gather_oracle(
     block_tables: jax.Array,  # (B, W) int32
     lengths,                  # (B,) kv lengths the kernel attends
     *,
-    kv_tile_blocks: int = 1,
+    kv_tile_blocks: Optional[int] = None,
     split_k: int = 1,
     k_scale: jax.Array = None,   # (N, Hkv, BS) f32 when the pools are int8
     v_scale: jax.Array = None,
 ):
-    """MEASURE (not model) one decode launch's gather traffic: pad the
-    table exactly as the kernel wrapper does (``split_layout``), run the
-    ref layer's actual gathers on it, and count bytes off the gathered
-    array shapes. The analytic model in ``serve/kernel_costs.py`` must
-    reproduce these numbers exactly — that agreement is the cross-check
-    against the grouped-gather contract pinned in this module's docstring.
+    """MEASURE (not model) one decode launch's gather traffic: walk the
+    kernel's grid ``(B, S, spl)`` with its T gather slots, look up the
+    block each slot reads at each step (``live_block_column`` over the
+    table, as the wrapper does), count a copy whenever a slot's block
+    differs from the one it read the step before (each row's first step
+    copies every slot), take those blocks from the pools and count bytes
+    off the taken arrays' shapes, plus the int32 table the scalar
+    prefetch moves. The analytic model in ``serve/kernel_costs.py`` must
+    reproduce these numbers exactly on tables whose rows hold distinct
+    blocks, as the pool's do.
 
     Returns ``{"gather_bytes", "useful_bytes", "waste_bytes",
-    "grid_steps", "padded_width"}``; waste counts table entries at or past
-    each row's real block cover ``ceil(len/BS)`` (pow2 bucketing, tile
-    padding, dead tail blocks alike), including int8 scale siblings.
+    "grid_steps", "padded_width"}``; useful bytes are each row's live
+    blocks (``ceil(len/BS)``, at most W) and their table entries, waste
+    the rest: repeat copies into slots past a row's last live block and
+    the table's dead entries, int8 scale siblings included.
     """
-    B, W = block_tables.shape
-    _, Hkv, BS, _ = k_pool.shape
+    bt = jnp.asarray(block_tables, jnp.int32)
+    B, W = bt.shape
+    _, Hkv, BS, D = k_pool.shape
+    if kv_tile_blocks is None:
+        kv_tile_blocks = decode_tile_blocks(BS, Hkv, D,
+                                            k_pool.dtype.itemsize)
     T, S, spl, Wp = split_layout(W, kv_tile_blocks, split_k)
-    bt = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, Wp - W)))
+    lens = jnp.asarray(lengths, jnp.int32)
+    cols = live_block_column(jnp.arange(Wp, dtype=jnp.int32)[None, :],
+                             lens[:, None], BS, W)
+    ids = np.asarray(jnp.take_along_axis(bt, cols, axis=1)).reshape(
+        B, S * spl, T)                            # (row, step, slot)
+    copied = np.ones_like(ids, bool)
+    copied[:, 1:] = ids[:, 1:] != ids[:, :-1]
+    moved = jnp.asarray(ids[copied])              # block id of every copy
 
-    gk = gather_kv(k_pool, bt)                    # the real takes — bytes
-    gv = gather_kv(v_pool, bt)                    # come off their shapes
-    gather = int(gk.nbytes) + int(gv.nbytes)
-    per_block = gk.dtype.itemsize * BS * k_pool.shape[-1] * 2
-    if k_scale is not None:
-        gks = gather_scales(k_scale, bt)
-        gvs = gather_scales(v_scale, bt)
-        gather += int(gks.nbytes) + int(gvs.nbytes)
-        per_block += gks.dtype.itemsize * BS * 2
-    useful_blocks = sum(min(-(-int(ln) // BS), Wp) for ln in list(lengths))
-    useful = useful_blocks * Hkv * per_block
+    taken = [k_pool[moved], v_pool[moved]]        # the real takes — bytes
+    if k_scale is not None:                       # come off their shapes
+        taken += [k_scale[moved], v_scale[moved]]
+    per_block = sum(int(a[0].nbytes) for a in taken)
+    gather = sum(int(a.nbytes) for a in taken) + int(bt.nbytes)
+    live = sum(min(-(-int(ln) // BS), W) for ln in list(lengths))
+    useful = live * (per_block + bt.dtype.itemsize)
     return {"gather_bytes": gather, "useful_bytes": useful,
-            "waste_bytes": gather - useful, "grid_steps": B * Hkv * S * spl,
+            "waste_bytes": gather - useful, "grid_steps": B * S * spl,
             "padded_width": Wp}
 
 

@@ -183,12 +183,12 @@ def main() -> None:
                          "prefilling request always advances), so many "
                          "concurrent long prompts can't starve decodes; "
                          "0 = one chunk per prefilling request per step")
-    ap.add_argument("--kv-tile-blocks", type=int, default=1,
+    ap.add_argument("--kv-tile-blocks", type=int, default=None,
                     help="paged engine: pool blocks gathered per kv grid "
-                         "step of the paged Pallas kernels (raise until "
-                         "kv_tile_blocks * block_size >= 128 so decode "
-                         "streams MXU-shaped KV tiles; layout-only — same "
-                         "attention, same visit order, identical outputs)")
+                         "step of the paged Pallas kernels (layout-only — "
+                         "same attention, same visit order, identical "
+                         "outputs); unset, decode derives it from the "
+                         "pool's block shape and chunked prefill takes 1")
     ap.add_argument("--decode-split-k", type=int, default=1,
                     help="paged engine: partition each decode lane's KV "
                          "walk across this many parallel grid lanes, "

@@ -55,6 +55,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.flash_decode_paged.ref import (decode_tile_blocks,
+                                                  split_layout)
 from repro.core.softermax import softmax_base2
 from repro.models.registry import model_fns
 from repro.serve.autotune import (AUTOTUNE_MODES, GridPlanner,
@@ -210,7 +212,8 @@ class ContinuousEngine:
                  prefix_cache: bool = True, evict_policy: str = "lru",
                  prefill_chunk: int = 0, prefill_budget: int = 0,
                  kv_dtype: Optional[str] = None,
-                 kv_tile_blocks: int = 1, decode_split_k: int = 1,
+                 kv_tile_blocks: Optional[int] = None,
+                 decode_split_k: int = 1,
                  autotune: str = "off",
                  autotune_candidates=None,
                  telemetry: Optional[Telemetry] = None,
@@ -266,18 +269,19 @@ class ContinuousEngine:
         # same attention; tiling preserves the visit order exactly, split-K
         # reassociates the partition sums within fp rounding, the rescales
         # staying exact power-of-two shifts): ``kv_tile_blocks`` pool
-        # blocks are gathered
-        # per kv grid step of both paged kernels (T*block_size >= 128 rows
-        # makes MXU-shaped tiles), and decode's KV walk is partitioned
-        # across ``decode_split_k`` parallel lanes merged by the
-        # associative Softermax combine. Both only reach the Pallas
-        # kernels (TPU / interpret_kernels); the CPU ref path ignores
-        # them. See serve/README.md "Kernel grid & tiling".
-        if kv_tile_blocks < 1 or decode_split_k < 1:
+        # blocks are gathered per kv grid step of both paged kernels, and
+        # decode's KV walk is partitioned across ``decode_split_k``
+        # parallel lanes merged by the associative Softermax combine.
+        # Without a tile, decode takes the one its pool's block shape
+        # gives (``decode_tile_blocks``, resolved below) and the chunk
+        # program keeps T = 1. Both only reach the Pallas kernels (TPU /
+        # interpret_kernels); the CPU ref path ignores them. See
+        # serve/README.md "Kernel grid & tiling".
+        if (kv_tile_blocks is not None and kv_tile_blocks < 1) \
+                or decode_split_k < 1:
             raise ValueError(
                 f"kv_tile_blocks and decode_split_k must be >= 1, got "
                 f"{kv_tile_blocks}/{decode_split_k}")
-        self.kv_tile_blocks = kv_tile_blocks
         self.decode_split_k = decode_split_k
         if autotune not in AUTOTUNE_MODES:
             raise ValueError(f"autotune must be one of {AUTOTUNE_MODES}, "
@@ -290,6 +294,10 @@ class ContinuousEngine:
         self.pool = PagedKVCache(cfg, num_blocks, block_size,
                                  kv_dtype=kv_dtype or "auto")
         self.quantized = self.pool.quantized
+        self.kv_tile_blocks = kv_tile_blocks or decode_tile_blocks(
+            block_size, cfg.n_kv_heads, cfg.head_dim_,
+            jnp.dtype(self.pool.kv_dtype).itemsize)
+        self._prefill_tile = kv_tile_blocks or 1
         self.prefix_cache = (RadixCache(self.pool, evict_policy)
                              if prefix_cache else None)
         self.sched = Scheduler(self.pool, max_batch, max_len,
@@ -326,7 +334,7 @@ class ContinuousEngine:
         if autotune != "off":
             self.planner = GridPlanner(
                 autotune_candidates
-                or default_candidates(kv_tile_blocks, decode_split_k),
+                or default_candidates(self.kv_tile_blocks, decode_split_k),
                 n_q_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
                 head_dim=cfg.head_dim, block_size=block_size,
                 kv_dtype=self.pool.kv_dtype,
@@ -335,7 +343,7 @@ class ContinuousEngine:
                 dec = self.planner.plan_decode(
                     np.full((max_batch,), max_len, np.int64),
                     table_width_bucket(self.nb_max, nb_max=self.nb_max))
-                self.kv_tile_blocks = dec.kv_tile_blocks
+                self.kv_tile_blocks = self._prefill_tile = dec.kv_tile_blocks
                 self.decode_split_k = dec.split_k
         # Telemetry-path decode LaunchCost memo. Exact: the kernel attends
         # lengths+1, and every cost term depends on a row only through
@@ -379,7 +387,7 @@ class ContinuousEngine:
         # so the per-step planner can swap grids without retracing tricks
         # — each (tile, split, table-width) lands in its own cache entry,
         # all of which warmup() pre-compiles when autotuning is on
-        def _decode_fn(p, t, bt, ln, *pools, tile=1, split=1):
+        def _decode_fn(p, t, bt, ln, *pools, tile=None, split=1):
             out = paged_decode_step(p, t, pools[0], pools[1], bt, ln, cfg,
                                     kv_tile_blocks=tile,
                                     decode_split_k=split,
@@ -395,7 +403,7 @@ class ContinuousEngine:
         def _prefill_chunk_fn(p, t, pos0, last_rel, pt, blk, off, *pools):
             out = paged_prefill_chunked(p, t, pos0, last_rel, pools[0],
                                         pools[1], pt, blk, off, cfg,
-                                        kv_tile_blocks=self.kv_tile_blocks,
+                                        kv_tile_blocks=self._prefill_tile,
                                         **_sc(pools))
             return (_amax(out[0]), out[0]) + tuple(out[1:])
 
@@ -1102,7 +1110,7 @@ class ContinuousEngine:
                     C, [m], [cover], w, n_q_heads=self.cfg.n_heads,
                     n_kv_heads=self.cfg.n_kv_heads,
                     head_dim=self.cfg.head_dim, block_size=self.block_size,
-                    kv_tile_blocks=self.kv_tile_blocks,
+                    kv_tile_blocks=self._prefill_tile,
                     kv_dtype=self.pool.kv_dtype)
                 tel.on_prefill(req, "prefill-chunk", sl, w, t,
                                self._clock() - t, cost=cost,
@@ -1195,21 +1203,25 @@ class ContinuousEngine:
             tokens1 = jnp.asarray(t1)
 
         w = self._table_width(occ)
-        with span("serve.decode", rows=len(occ), width=w):
-            lengths = np.zeros((B,), np.int32)
-            for i, req in occ:
-                lengths[i] = req.n_cached
+        lengths = np.zeros((B,), np.int32)
+        for i, req in occ:
+            lengths[i] = req.n_cached
+        # the kernel attends lengths+1 on every row (zombies included,
+        # masked) — plan and account against what it actually does
+        tile, split = self.kv_tile_blocks, self.decode_split_k
+        plan = None
+        if self.planner is not None and self.autotune == "per-step":
+            plan = self.planner.plan_decode(lengths + 1, w)
+            tile, split = plan.kv_tile_blocks, plan.split_k
+        # the kernel's grid: B rows x S*spl tiles of T blocks, of which
+        # each row's first ceil((len+1)/(T*BS)) do work
+        T, S, spl, _ = split_layout(w, tile, split)
+        live = -(-(lengths + 1) // (T * self.block_size))
+        with span("serve.decode", rows=len(occ), width=w, tile=T,
+                  live_tiles=int(live.sum()), grid_tiles=B * S * spl):
             bt = np.zeros((B, w), np.int32)
             bt[[i for i, _ in occ]] = self.pool.table_array(
                 [r.req_id for _, r in occ], w)
-
-            # the kernel attends lengths+1 on every row (zombies included,
-            # masked) — plan and account against what it actually does
-            tile, split = self.kv_tile_blocks, self.decode_split_k
-            plan = None
-            if self.planner is not None and self.autotune == "per-step":
-                plan = self.planner.plan_decode(lengths + 1, w)
-                tile, split = plan.kv_tile_blocks, plan.split_k
             greedy, lg, *pools = self._decode(
                 self.params, tokens1, jnp.asarray(bt), jnp.asarray(lengths),
                 *self._pools(), tile=tile, split=split)

@@ -15,21 +15,30 @@ gathered arrays and count bytes) by ``tests/test_kernel_costs.py``.
 
 What is counted, and why it is exact:
 
-* **Gather-DMA bytes.** The kernels' KV BlockSpec index maps gather one
-  pool block per (tile slot, grid step) — unconditionally; ``@pl.when``
-  skips *compute* on tiles past a row's length, not the DMA. The table is
-  padded to ``Wp = S * spl * T`` blocks (``split_layout``), so per layer a
-  decode launch moves exactly ``B * Hkv * Wp`` K-blocks + as many
-  V-blocks (the once-per-KV-head gather contract pinned in PR 5's ref
-  docstring), each ``BS * D * itemsize`` bytes, plus the int8 pools' scale
-  siblings (``BS * 4`` bytes per block, K and V). Prefill re-streams the
-  walk once per query tile (``nq`` of them).
-* **Clamped / block-0 waste bytes.** Table entries at or past a row's
-  real block count (``ceil(len / BS)``; the engine's pow2 bucketing, the
-  wrapper's tile padding, and dead preallocated tail blocks all produce
-  them) are gathered and then fully masked — pure DMA waste. Waste is 0
-  exactly when every row's blocks fill the padded table (no block-0
-  padding anywhere), which the property tests pin.
+* **Gather-DMA bytes, decode.** Grid ``(B, S, spl)``: each step's T
+  slots gather whole pool blocks, every KV head in one copy
+  (``Hkv * BS * D * itemsize`` bytes of K, as many of V, plus the int8
+  pools' ``Hkv * BS * 4``-byte scale rows each). A slot past a row's live
+  blocks reads the row's last live block (``ref.live_block_column``), and
+  the pipeline issues no copy for a block index that repeats from the
+  step before; so a row costs one copy per live column before its last,
+  then one per slot as it reaches the last (``last + min(T, Wp - last)``
+  for ``Wp = S * spl * T``), however wide the table. The model takes a
+  row's blocks as distinct, as the pool's are, and counts each row's
+  first step as copying all T slots. The ``B * W`` int32 block table,
+  copied to SMEM as the scalar-prefetch operand, is counted too.
+* **Gather-DMA bytes, prefill.** One pool block per (KV head, tile slot,
+  grid step), unconditionally; ``@pl.when`` skips *compute* on tiles
+  past the diagonal, not the DMA. The table is padded to ``Wp`` blocks
+  (``split_layout``), and the walk re-streams once per query tile
+  (``nq`` of them).
+* **Waste bytes.** What a launch moves for table entries at or past a
+  row's real block count (``ceil(len / BS)``; the engine's pow2
+  bucketing, tile padding and dead preallocated tail blocks all produce
+  them): decode's repeat copies into slots past the last live block and
+  its dead table entries, prefill's masked block-0 gathers. Waste is 0
+  exactly when every row's blocks fill the padded table, which the
+  property tests pin.
 * **MXU FLOPs.** Per *computed* kv tile (``k_start < kv_len``, resp. the
   prefill diagonal check) the QK and AV dots each run their full tile
   shape regardless of masking: ``2 * rows * D * (T * BS)`` FLOPs apiece.
@@ -48,15 +57,17 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Sequence
+from typing import Dict, Optional, Sequence
 
-from repro.kernels.flash_decode_paged.ref import split_layout
+from repro.kernels.flash_decode_paged.ref import (decode_tile_blocks,
+                                                  split_layout)
 
 # storage itemsizes by resolved pool dtype name (np.dtype("bfloat16")
 # does not exist, so a mapping instead of np.dtype().itemsize)
 KV_ITEMSIZE: Dict[str, int] = {"float32": 4, "bfloat16": 2,
                                "float16": 2, "int8": 1}
 SCALE_BYTES = 4          # f32 per-row scale siblings of an int8 pool
+TABLE_ENTRY_BYTES = 4    # int32 block-table entries (scalar prefetch)
 ACC_BYTES = 4            # kernels accumulate in f32
 
 
@@ -79,11 +90,12 @@ class LaunchCost:
 
     kind: str                # "decode" | "prefill"
     grid_steps: int          # total grid iterations of the launch
-    lanes: int               # parallel grid extent (B*Hkv*S / B*Hkv*nq)
+    lanes: int               # parallel grid extent (B*S / B*Hkv*nq)
     steps_per_lane: int      # sequential kv iterations per lane (spl / nk)
-    gather_bytes: int        # KV (+scale) HBM->VMEM bytes the gather moves
-    waste_bytes: int         # subset of gather_bytes that is masked junk
-    #                          (clamped block-0 / pad / dead tail entries)
+    gather_bytes: int        # KV (+scale) HBM->VMEM bytes the gather
+    #                          moves (decode: and its SMEM block table)
+    waste_bytes: int         # subset of gather_bytes spent on table
+    #                          entries past a row's live blocks
     io_bytes: int            # non-gather operand traffic (q in, out/partials)
     flops: int               # MXU matmul FLOPs actually executed (QK + AV)
     merge_flops: int         # second-stage softermax_merge work (split-K)
@@ -126,26 +138,32 @@ def decode_launch_cost(
     n_kv_heads: int,
     head_dim: int,
     block_size: int,
-    kv_tile_blocks: int = 1,
+    kv_tile_blocks: Optional[int] = None,   # None: the kernel's derived T
     split_k: int = 1,
     kv_dtype: str = "float32",
 ) -> LaunchCost:
     """Cost of one ``flash_decode_paged`` launch (one layer).
 
-    Mirrors the kernel wrapper exactly: ``split_layout`` clamps/pads the
-    grid, every (lane, kv step) DMAs its T blocks unconditionally, and
-    compute runs on tiles with ``k_start < kv_len`` only.
+    Mirrors the kernel wrapper exactly: the same derived T when none is
+    given, ``split_layout``'s clamp, a grid ``(B, S, spl)`` of whole-block
+    gathers whose dead steps repeat a block and copy nothing, and compute
+    on tiles with ``k_start < kv_len`` only.
     """
     B = len(lengths)
     W, BS, D = table_width, block_size, head_dim
     Hq, Hkv = n_q_heads, n_kv_heads
     G = Hq // Hkv
+    if kv_tile_blocks is None:
+        kv_tile_blocks = decode_tile_blocks(BS, Hkv, D, _itemsize(kv_dtype))
     T, S, spl, Wp = split_layout(W, kv_tile_blocks, split_k)
-    bb = _block_bytes(BS, D, kv_dtype)
+    bb = Hkv * _block_bytes(BS, D, kv_dtype)          # one whole block
 
-    gather = B * Hkv * Wp * bb
-    useful_blocks = sum(min(-(-int(ln) // BS), Wp) for ln in lengths)
-    waste = (B * Wp - useful_blocks) * Hkv * bb
+    live = [min(-(-int(ln) // BS), W) for ln in lengths]
+    lasts = [max(n - 1, 0) for n in live]
+    copies = sum(c + min(T, Wp - c) for c in lasts)
+    gather = copies * bb + B * W * TABLE_ENTRY_BYTES
+    waste = ((copies - sum(live)) * bb
+             + (B * W - sum(live)) * TABLE_ENTRY_BYTES)
 
     # computed kv tiles per row: tile jj runs iff jj*T*BS < len
     tiles = sum(min(-(-int(ln) // (T * BS)), S * spl) for ln in lengths)
@@ -153,13 +171,13 @@ def decode_launch_cost(
     merge = B * Hq * D * 8 * S if S > 1 else 0        # jnp merge stage
 
     q_in = B * Hq * D * ACC_BYTES
-    part_out = B * Hkv * S * (G * D + 2 * G) * ACC_BYTES
-    vmem = (G * D * ACC_BYTES                         # q tile (f32 in-kernel)
+    part_out = B * S * Hkv * (G * D + 2 * G) * ACC_BYTES
+    vmem = (Hkv * G * D * ACC_BYTES                   # q tile (f32 in-kernel)
             + T * bb                                  # K+V (+scale) tiles
-            + (G * D + 2 * G) * ACC_BYTES             # acc/m/d scratch
-            + (G * D + 2 * G) * ACC_BYTES)            # partial outputs
-    return LaunchCost(kind="decode", grid_steps=B * Hkv * S * spl,
-                      lanes=B * Hkv * S, steps_per_lane=spl,
+            + Hkv * (G * D + 2 * G) * ACC_BYTES       # acc/m/d scratch
+            + Hkv * (G * D + 2 * G) * ACC_BYTES)      # partial outputs
+    return LaunchCost(kind="decode", grid_steps=B * S * spl,
+                      lanes=B * S, steps_per_lane=spl,
                       gather_bytes=gather, waste_bytes=waste,
                       io_bytes=q_in + part_out, flops=flops,
                       merge_flops=merge, tile_bytes=T * bb,

@@ -633,7 +633,7 @@ def paged_prefill_chunked(
 
 
 def _paged_attention(q, k_pool_l, v_pool_l, block_tables, new_len, cfg,
-                     intmax, ksc_l=None, vsc_l=None, kv_tile_blocks=1,
+                     intmax, ksc_l=None, vsc_l=None, kv_tile_blocks=None,
                      split_k=1):
     """Fused-batch decode attention through the one shared dispatcher
     (``kernels/flash_decode_paged/ops.py``): grouped/tiled/split Pallas
@@ -657,7 +657,8 @@ def paged_decode_step(
     cfg: ModelConfig,
     k_scale: jax.Array = None,   # (L, N, Hkv, BS) f32 scale pools (int8)
     v_scale: jax.Array = None,
-    kv_tile_blocks: int = 1,     # static: pool blocks per kernel kv step
+    kv_tile_blocks: Optional[int] = None,  # static: pool blocks per
+    #                              kernel kv step; None: from the block shape
     decode_split_k: int = 1,     # static: parallel KV partitions per lane
 ):
     """One continuous-batch decode step.

@@ -65,20 +65,30 @@ def _compiled_text(fn, *args, **kwargs) -> str:
     return jax.jit(fn).lower(*args, **kwargs).compile().as_text()
 
 
-@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
-                         ids=["bf16", "int8"])
-def test_flash_decode_paged_compiles_for_v5e(one_chip, kv_dtype):
+# (dtype, tile, split, table width, rows): an explicit grid, and the
+# derived tile (None) at the backlog cell's widest table (320 blocks) and
+# batch (32 rows)
+DECODE_GRIDS = [
+    pytest.param(jnp.bfloat16, 8, 2, TABLE_WIDTH, 8, id="bf16"),
+    pytest.param(jnp.int8, 8, 2, TABLE_WIDTH, 8, id="int8"),
+    pytest.param(jnp.bfloat16, None, 1, 320, 32, id="bf16-derived-w320"),
+    pytest.param(jnp.int8, None, 1, 320, 32, id="int8-derived-w320"),
+]
+
+
+@pytest.mark.parametrize("kv_dtype,tile,split,width,batch", DECODE_GRIDS)
+def test_flash_decode_paged_compiles_for_v5e(one_chip, kv_dtype, tile,
+                                             split, width, batch):
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     hq, hkv, d = _widths()
-    batch = 8
     pool, scales = _pool_args(sd, kv_dtype, hkv)
     text = _compiled_text(
         lambda q, k, v, bt, ln, **kw: flash_decode_paged(
-            q, k, v, bt, ln, kv_tile_blocks=8, split_k=2, **kw),
+            q, k, v, bt, ln, kv_tile_blocks=tile, split_k=split, **kw),
         sd((batch, hq, d), jnp.bfloat16), pool, pool,
-        sd((batch, TABLE_WIDTH), jnp.int32), sd((batch,), jnp.int32),
+        sd((batch, width), jnp.int32), sd((batch,), jnp.int32),
         **scales)
     assert "tpu_custom_call" in text
 

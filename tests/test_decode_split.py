@@ -13,7 +13,10 @@ from repro.core.softermax import softermax_finalize, softermax_merge
 from repro.kernels.flash_decode_paged import (flash_decode_paged,
                                               flash_decode_paged_single,
                                               paged_decode_ref,
-                                              paged_decode_split_ref)
+                                              paged_decode_split_ref,
+                                              split_layout)
+from repro.kernels.flash_decode_paged.ref import (decode_tile_blocks,
+                                                  live_block_column)
 from repro.models.attention import quantize_kv
 from repro.models.registry import get_config, model_fns, reduce_config
 from repro.serve import ContinuousEngine
@@ -232,22 +235,41 @@ def _random_paged_kv(B, Hkv, D, BS, W, quantized=False):
     return kq, vq, bt, ksc, vsc
 
 
+# (B, Hq, Hkv, D, BS, W) and lengths: a small grid, and qwen3-4b's head
+# geometry (8 KV heads, 4 query heads each, 128 wide, 16-token blocks)
+# with an empty row (length 0 attends 1), a row ending mid-tile, a row at
+# the full table width and a zombie row
+SMALL = ((4, 8, 2, 16, 8, 7), [1, 29, 56, 0])
+QWEN = ((4, 32, 8, 128, 16, 20), [1, 150, 320, 0])
+
+
+def _grid_cases():
+    """(geometry, T, S): the small grid across tiles and splits, and the
+    qwen3-4b geometry at the derived T and explicit T 1 and 8, split 1
+    and 2. T None is the tile the kernel derives from the block shape."""
+    cases = [pytest.param(SMALL, T, S, id=f"{S}-{T}")
+             for S in (1, 2, 3) for T in (1, 2, 4)]
+    cases += [pytest.param(QWEN, T, S, id=f"qwen-{S}-{T or 'derived'}")
+              for S in (1, 2) for T in (None, 1, 8)]
+    return cases
+
+
 class TestGroupedSplitDecodeKernel:
-    @pytest.mark.parametrize("T", [1, 2, 4])
-    @pytest.mark.parametrize("S", [1, 2, 3])
-    def test_matches_ref_across_tiles_and_splits(self, T, S):
-        """Odd lengths, mid-block tails, a zombie row, a one-token row —
-        every (tile, split) layout computes the identical attention."""
-        B, Hq, Hkv, D, BS, W = 4, 8, 2, 16, 8, 7
+    @pytest.mark.parametrize("geom,T,S", _grid_cases())
+    def test_matches_ref_across_tiles_and_splits(self, geom, T, S):
+        """Odd lengths, mid-block and mid-tile tails, a full-width row, a
+        zombie row, a one-token row — every (tile, split) layout computes
+        the identical attention."""
+        (B, Hq, Hkv, D, BS, W), lens = geom
         kp, vp, bt, _, _ = _random_paged_kv(B, Hkv, D, BS, W)
         q = jnp.asarray(_rng.normal(size=(B, Hq, D)),
                         jnp.float32) / np.sqrt(D)
-        lens = jnp.asarray([1, 29, 56, 0], jnp.int32)
+        lens = jnp.asarray(lens, jnp.int32)
         want = paged_decode_ref(q, kp, vp, bt, lens)
         got = flash_decode_paged(q, kp, vp, bt, lens, kv_tile_blocks=T,
                                  split_k=S, interpret=True)
         sref = paged_decode_split_ref(q, kp, vp, bt, lens,
-                                      kv_tile_blocks=T, split_k=S)
+                                      kv_tile_blocks=T or 1, split_k=S)
         # row with length 0 is a zombie: kernels/split-ref emit 0 (merge
         # identity), the closed-form oracle emits a uniform average — the
         # engine masks either; compare the live rows against the oracle
@@ -259,21 +281,26 @@ class TestGroupedSplitDecodeKernel:
         assert np.all(np.asarray(got)[3] == 0)
         assert np.all(np.asarray(sref)[3] == 0)
 
-    @pytest.mark.parametrize("T,S", [(1, 1), (2, 2), (4, 3)])
-    def test_int8_matches_ref(self, T, S):
-        B, Hq, Hkv, D, BS, W = 2, 4, 2, 16, 8, 6
+    @pytest.mark.parametrize("geom,T,S", [
+        pytest.param(((2, 4, 2, 16, 8, 6), [11, 41]), T, S, id=f"{T}-{S}")
+        for T, S in ((1, 1), (2, 2), (4, 3))] + [
+        pytest.param(((3, 32, 8, 128, 16, 20), [1, 150, 320]), T, S,
+                     id=f"qwen-{T or 'derived'}-{S}")
+        for T, S in ((None, 1), (8, 2))])
+    def test_int8_matches_ref(self, geom, T, S):
+        (B, Hq, Hkv, D, BS, W), lens = geom
         kp, vp, bt, ksc, vsc = _random_paged_kv(B, Hkv, D, BS, W,
                                                 quantized=True)
         q = jnp.asarray(_rng.normal(size=(B, Hq, D)),
                         jnp.float32) / np.sqrt(D)
-        lens = jnp.asarray([11, 41], jnp.int32)
+        lens = jnp.asarray(lens, jnp.int32)
         want = paged_decode_ref(q, kp, vp, bt, lens, k_scale=ksc,
                                 v_scale=vsc)
         got = flash_decode_paged(q, kp, vp, bt, lens, k_scale=ksc,
                                  v_scale=vsc, kv_tile_blocks=T, split_k=S,
                                  interpret=True)
         sref = paged_decode_split_ref(q, kp, vp, bt, lens, k_scale=ksc,
-                                      v_scale=vsc, kv_tile_blocks=T,
+                                      v_scale=vsc, kv_tile_blocks=T or 1,
                                       split_k=S)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5)
@@ -307,6 +334,33 @@ class TestGroupedSplitDecodeKernel:
                                  split_k=9, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5)
+
+    def test_tile_rule_from_block_shape(self):
+        """T from (BS, Hkv, D, itemsize): the fewest blocks of >= 128
+        rows, cut to a K tile of at most 512 KiB, at least 1; the table
+        width clamps it."""
+        # qwen3-4b: 8 blocks of 16 rows, 8 x 16 x 128 x 2 x 8 = 256 KiB
+        assert decode_tile_blocks(16, 8, 128, 2) == 8
+        assert decode_tile_blocks(16, 8, 128, 1) == 8      # int8 pool
+        assert decode_tile_blocks(32, 8, 128, 2) == 4      # larger blocks
+        assert decode_tile_blocks(128, 8, 128, 2) == 1
+        # many KV heads: 64 of 128 at bf16 is 256 KiB a block -> 2
+        assert decode_tile_blocks(16, 64, 128, 2) == 2
+        # a block past the budget on its own still gathers whole
+        assert decode_tile_blocks(16, 128, 256, 2) == 1
+        assert split_layout(3, decode_tile_blocks(16, 8, 128, 2), 1)[0] == 3
+
+    def test_dead_slots_repeat_the_last_live_block(self):
+        """Slots past a row's live blocks read its last live column (0
+        for an empty row), never past the table, so dead steps repeat a
+        block index and the pipeline copies nothing for them."""
+        cols = np.arange(8)
+        assert list(np.asarray(live_block_column(cols, 40, 16, 6))) == \
+            [0, 1, 2, 2, 2, 2, 2, 2]
+        assert list(np.asarray(live_block_column(cols, 0, 16, 6))) == \
+            [0] * 8
+        assert list(np.asarray(live_block_column(cols, 96, 16, 6))) == \
+            [0, 1, 2, 3, 4, 5, 5, 5]
 
     @pytest.mark.tpu
     def test_compiled_matches_interpret(self):

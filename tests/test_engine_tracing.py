@@ -204,6 +204,12 @@ class TestSpans:
         decodes = [s for s in spans if s[0] == "serve.decode"]
         assert all(1 <= s[3]["rows"] <= 4 and s[3]["width"] >= 1
                    for s in decodes)
+        # the kernel's grid: 4 rows x ceil(width / T) tiles, each row
+        # (empty ones attend 1) doing at least its first tile
+        for *_, a, _ in decodes:
+            assert a["tile"] == min(eng.kv_tile_blocks, a["width"])
+            assert a["grid_tiles"] == 4 * -(-a["width"] // a["tile"])
+            assert 4 <= a["live_tiles"] <= a["grid_tiles"]
         pre = [s for s in spans if s[0] == prefill]
         assert all(s[3]["tokens"] >= 1 for s in pre)
         assert {s[3]["req"] for s in pre} <= {0, 1, 2}

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_decode_paged.ref import (decode_gather_oracle,
+                                                  decode_tile_blocks,
                                                   split_layout)
 from repro.kernels.flash_prefill_paged.ref import prefill_gather_oracle
 from repro.models.registry import get_config, model_fns, reduce_config
@@ -34,11 +35,12 @@ def _pools(n_blocks, dtype):
 
 
 def _table(B, W, n_blocks, lengths):
-    """Exact-cover tables: row i holds ceil(len/BS) real entries, rest 0."""
+    """Exact-cover tables: row i holds ceil(len/BS) real entries, rest 0.
+    A row's blocks are distinct, as the pool's are."""
     bt = np.zeros((B, W), np.int32)
     for i, ln in enumerate(lengths):
         nb = min(-(-int(ln) // BS), W)
-        bt[i, :nb] = _rng.integers(1, n_blocks, (nb,))
+        bt[i, :nb] = _rng.choice(np.arange(1, n_blocks), nb, replace=False)
     return jnp.asarray(bt)
 
 
@@ -46,7 +48,8 @@ class TestDecodeModelMatchesOracle:
     @pytest.mark.parametrize("dtype", ["float32", "int8"])
     @pytest.mark.parametrize("tile,split", [(1, 1), (2, 1), (4, 1),
                                             (1, 2), (2, 2), (4, 3),
-                                            (16, 1), (1, 4)])
+                                            (16, 1), (1, 4), (None, 1),
+                                            (None, 2)])
     def test_gather_waste_steps_exact(self, dtype, tile, split):
         B, W, n_blocks = 5, 12, 64
         lengths = np.array([1, 17, 64, 190, 7], np.int64)
@@ -61,7 +64,8 @@ class TestDecodeModelMatchesOracle:
         assert model.waste_bytes == oracle["waste_bytes"]
         assert model.useful_bytes == oracle["useful_bytes"]
         assert model.grid_steps == oracle["grid_steps"]
-        _, _, _, Wp = split_layout(W, tile, split)
+        T = tile or decode_tile_blocks(BS, Hkv, D, k.dtype.itemsize)
+        _, _, _, Wp = split_layout(W, T, split)
         assert Wp == oracle["padded_width"]
 
     def test_random_geometry_sweep(self):
@@ -100,14 +104,32 @@ class TestDecodeModelMatchesOracle:
         assert decode_launch_cost(lengths, W, kv_tile_blocks=3, split_k=1,
                                   **SHAPE).waste_bytes > 0
 
+    def test_dead_steps_copy_nothing(self):
+        """Whole-block gathers on a (B, S, spl) grid: widening the table
+        past every row's last live tile adds grid steps and the table's
+        int32 entries, and no block copy."""
+        lengths = np.array([1, 17, 64, 190, 7], np.int64)
+        narrow = decode_launch_cost(lengths, 16, kv_tile_blocks=4, **SHAPE)
+        wide = decode_launch_cost(lengths, 64, kv_tile_blocks=4, **SHAPE)
+        assert wide.gather_bytes - narrow.gather_bytes == 5 * (64 - 16) * 4
+        assert (narrow.grid_steps, wide.grid_steps) == (5 * 4, 5 * 16)
+        assert narrow.lanes == 5
+        # one copy moves a block's every KV head, K and V
+        assert narrow.tile_bytes == 4 * Hkv * 2 * BS * D * 4
+
     def test_int8_scale_siblings_counted(self):
         lengths = np.array([40, 8], np.int64)
-        f32 = decode_launch_cost(lengths, 4, kv_dtype="float32", **SHAPE)
-        i8 = decode_launch_cost(lengths, 4, kv_dtype="int8", **SHAPE)
-        # int8 blocks: quarter the values + the f32 scale rows
-        _, _, _, Wp = split_layout(4, 1, 1)
-        blocks = 2 * Hkv * Wp
-        assert i8.gather_bytes == f32.gather_bytes // 4 + blocks * 2 * BS * 4
+        f32 = decode_launch_cost(lengths, 4, kv_tile_blocks=1,
+                                 kv_dtype="float32", **SHAPE)
+        i8 = decode_launch_cost(lengths, 4, kv_tile_blocks=1,
+                                kv_dtype="int8", **SHAPE)
+        # int8 blocks: quarter the values + the f32 scale rows; at T = 1
+        # each row copies its live blocks once (3 + 1 whole blocks of Hkv
+        # heads), and both move the same 2 x 4 int32 table
+        table = 2 * 4 * 4
+        heads = Hkv * (3 + 1)
+        assert i8.gather_bytes - table == \
+            (f32.gather_bytes - table) // 4 + heads * 2 * BS * 4
 
     def test_scaled_multiplies_extensive_fields_only(self):
         c = decode_launch_cost(np.array([33]), 4, **SHAPE)
