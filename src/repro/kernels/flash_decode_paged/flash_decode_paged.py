@@ -8,13 +8,16 @@ Softermax recurrence is order-free (every rescale is an exact exponent add),
 blocks can be streamed in table order with no pre-pass over the scores, which
 is exactly what makes the paged layout free for this kernel.
 
-The block ids each grid column reads (the block table, clamped as below)
-and the per-row lengths are scalar-prefetch operands
+The layer index, the block ids each grid column reads (the block table,
+clamped as below) and the per-row lengths are scalar-prefetch operands
 (``PrefetchScalarGridSpec``, SMEM): their entries are available *before*
 the kernel body runs, so the KV BlockSpec index maps perform the gather —
-each grid step DMAs physical blocks from the pool directly into VMEM — and
-the body reads its row's length as a scalar. (A ``(1, 1)`` VMEM block over
-a ``(B, 1)`` lengths array is refused by the TPU compiler for B > 1: a
+each grid step DMAs physical blocks of the one layer straight from the
+whole ``(L, N, Hkv, BS, D)`` pool into VMEM — and the body reads its
+row's length as a scalar. The layer scan of ``serve/paged_step.py``
+carries that whole pool and writes each layer's new rows into it in
+place, so no program copies the pool. (A ``(1, 1)`` VMEM block over a
+``(B, 1)`` lengths array is refused by the TPU compiler for B > 1: a
 block's last two dims must be (8, 128)-divisible or span the array.)
 
 Three grid-level restructurings over the naive per-head walk (all three are
@@ -81,22 +84,52 @@ from repro.kernels.flash_decode_paged.ref import (decode_tile_blocks,
                                                   split_layout)
 
 
-def concat_tiles(refs, axis: int = 0, lead: int = 2):
+def concat_tiles(refs, axis: int = 0, idx: tuple = (0, 0)):
     """Assemble one VMEM tile from the T per-slot gather operands (each
-    ref holds one pool block; its ``lead`` leading unit block axes are
-    stripped). Shared by the decode and prefill kernel bodies: the
-    prefill kernel's ``(1, 1, BS, D)`` blocks concat along rows (axis 0)
-    and its ``(1, BS)`` scale rows along columns (axis 1); the decode
-    kernel's whole-block ``(1, Hkv, BS, D)`` operands (``lead=1``) concat
-    along rows (axis 1) and its ``(Hkv, 1, BS)`` scales along axis 2."""
-    idx = (0,) * lead
+    ref holds one pool block of one layer; ``idx`` strips its leading
+    unit block axes). Shared by the decode and prefill kernel bodies: the
+    decode kernel's whole-block ``(1, 1, Hkv, BS, D)`` operands concat
+    along rows (axis 1) and its ``(1, Hkv, 1, BS)`` scales (``idx=(0,)``)
+    along axis 2; the prefill kernel's ``(1, 1, 1, BS, D)`` blocks
+    (``idx=(0, 0, 0)``) concat along rows (axis 0) and its ``(1, 1, 1,
+    BS)`` scale rows along columns (axis 1)."""
     if len(refs) == 1:
         return refs[0][idx]
     return jnp.concatenate([r[idx] for r in refs], axis=axis)
 
 
-def _paged_decode_kernel(ids_ref, len_ref, q_ref, *rest, intmax: bool,
-                         block_size: int, tile_blocks: int, quantized: bool):
+def layer_operands(k_pool, v_pool, k_scale, v_scale, layer):
+    """The operands both paged kernels take: the pools with their layer
+    axis, the layer index as a ``(1,)`` int32 scalar-prefetch operand,
+    and an int8 pool's scales of that one layer as f32 ``(N, Hkv, 1,
+    BS)``. A one-layer ``(N, Hkv, BS, D)`` pool (no ``layer``) gains a
+    unit layer axis, a free reshape, and is read at layer 0; a pool with
+    its layer axis needs its ``layer``.
+
+    The scales are the one operand read per layer rather than in place:
+    on a TPU an f32 ``(..., Hkv, BS)`` array is laid out with its block
+    axis minor (the TPU's default for a minor dim under 128 lanes), along
+    which no BlockSpec can gather, so one layer's scales are relaid here
+    — a copy of one layer of the scale pool, never of the pool."""
+    if (layer is None) != (k_pool.ndim == 4):
+        raise ValueError(f"a pool of rank {k_pool.ndim} with layer "
+                         f"{layer!r}: pass a layer index with an "
+                         f"(L, N, Hkv, BS, D) pool, and none with one "
+                         f"layer's (N, Hkv, BS, D)")
+    if layer is None:
+        k_pool, v_pool = k_pool[None], v_pool[None]
+    if k_scale is not None:
+        N, Hkv, BS = k_scale.shape[-3:]
+        k_scale, v_scale = (
+            (sc if layer is None else sc[layer]).astype(jnp.float32)
+            .reshape(N, Hkv, 1, BS) for sc in (k_scale, v_scale))
+    lay = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
+    return k_pool, v_pool, k_scale, v_scale, lay
+
+
+def _paged_decode_kernel(layer_ref, ids_ref, len_ref, q_ref, *rest,
+                         intmax: bool, block_size: int, tile_blocks: int,
+                         quantized: bool):
     T = tile_blocks
     k_refs, v_refs = rest[:T], rest[T:2 * T]
     n = 2 * T
@@ -121,8 +154,8 @@ def _paged_decode_kernel(ids_ref, len_ref, q_ref, *rest, intmax: bool,
     def _body():
         q = q_ref[0].astype(jnp.float32)      # (Hkv, G, D)
         # T whole-block gathers (every KV head) as one (Hkv, T*BS, D) tile
-        k = concat_tiles(k_refs, axis=1, lead=1)
-        v = concat_tiles(v_refs, axis=1, lead=1)
+        k = concat_tiles(k_refs, axis=1)
+        v = concat_tiles(v_refs, axis=1)
         s = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)   # (Hkv, G, T*BS)
@@ -130,7 +163,7 @@ def _paged_decode_kernel(ids_ref, len_ref, q_ref, *rest, intmax: bool,
             # dequant fused post-dot: k_scale is constant per K row, so
             # scaling the (G, T*BS) score columns equals scaling the
             # (T*BS, D) tile — for a fraction of the flops
-            s = s * concat_tiles(ksc_refs, axis=2, lead=1)  # (Hkv, 1, T*BS)
+            s = s * concat_tiles(ksc_refs, 2, (0,))  # (Hkv, 1, T*BS)
         kj = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(kj < kv_len, s, NEG_INF)
         m_prev = m_scr[...]
@@ -141,7 +174,7 @@ def _paged_decode_kernel(ids_ref, len_ref, q_ref, *rest, intmax: bool,
         alpha = jnp.exp2(m_prev - m_new)      # exact power-of-two
         p = jnp.exp2(s - m_new)
         if quantized:
-            pv = p * concat_tiles(vsc_refs, axis=2, lead=1)  # fold v_scale
+            pv = p * concat_tiles(vsc_refs, 2, (0,))  # fold v_scale
         else:
             pv = p
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
@@ -164,13 +197,17 @@ def _paged_decode_kernel(ids_ref, len_ref, q_ref, *rest, intmax: bool,
     static_argnames=("intmax", "kv_tile_blocks", "split_k", "interpret"))
 def flash_decode_paged(
     q: jax.Array,             # (B, Hq, D) — pre-scaled single-token queries
-    k_pool: jax.Array,        # (N, Hkv, BS, D) physical block pool
-    v_pool: jax.Array,        # (N, Hkv, BS, D)
+    k_pool: jax.Array,        # (L, N, Hkv, BS, D) physical block pool of
+    #                           every layer; (N, Hkv, BS, D) is one layer
+    v_pool: jax.Array,
     block_tables: jax.Array,  # (B, W) int32 physical block ids
     lengths: jax.Array,       # (B,) int32 valid cache lengths
     *,
-    k_scale: jax.Array = None,   # (N, Hkv, BS) f32: int8 pools' row scales
-    v_scale: jax.Array = None,
+    layer=None,               # () int32 layer the kernel reads; None with
+    #                           a one-layer pool
+    k_scale: jax.Array = None,   # (L, N, Hkv, BS) f32: int8 pools' row
+    v_scale: jax.Array = None,   # scales, laid out as the pool (no L with
+    #                              a one-layer pool)
     intmax: bool = True,
     kv_tile_blocks: Optional[int] = None,  # pool blocks a kv grid step
     #                                        gathers (T); None derives it
@@ -178,8 +215,10 @@ def flash_decode_paged(
     split_k: int = 1,         # parallel partitions of the KV walk
     interpret: bool = False,
 ) -> jax.Array:
+    k_pool, v_pool, k_scale, v_scale, lay = layer_operands(
+        k_pool, v_pool, k_scale, v_scale, layer)
     B, Hq, D = q.shape
-    N, Hkv, BS, _ = k_pool.shape
+    _, _, Hkv, BS, _ = k_pool.shape
     W = block_tables.shape[1]
     G = Hq // Hkv
     quantized = k_scale is not None
@@ -200,36 +239,34 @@ def flash_decode_paged(
     ids = jnp.take_along_axis(block_tables.astype(jnp.int32), cols, axis=1)
     qf = q.reshape(B, Hkv, G, D)
 
-    def kv_map(t):
-        # one gather map per tile slot; values and scales share it
-        def _map(b, s, j, ids_ref, len_ref):
-            return (ids_ref[b, (s * spl + j) * T + t], 0, 0, 0)
-        return _map
+    def kv_spec(t, layered, block):
+        # one gather per tile slot: (layer, block id, whole block) of the
+        # pools, the layer scalar-prefetched beside the ids; (block id,
+        # whole block) of one layer's scales
+        def _map(b, s, j, layer_ref, ids_ref, len_ref):
+            return ((layer_ref[0],) if layered else ()) + \
+                (ids_ref[b, (s * spl + j) * T + t],) + (0,) * len(block)
+        return pl.BlockSpec((1,) * (1 + layered) + block, _map)
 
     in_specs = [
-        pl.BlockSpec((1, Hkv, G, D),
-                     lambda b, s, j, ids_ref, len_ref: (b, 0, 0, 0)),
+        pl.BlockSpec((1, Hkv, G, D), lambda b, s, j, *_: (b, 0, 0, 0)),
     ]
-    in_specs += [pl.BlockSpec((1, Hkv, BS, D), kv_map(t)) for t in range(T)]
-    in_specs += [pl.BlockSpec((1, Hkv, BS, D), kv_map(t)) for t in range(T)]
+    in_specs += [kv_spec(t, True, (Hkv, BS, D))
+                 for _ in "kv" for t in range(T)]
     inputs = [qf] + [k_pool] * T + [v_pool] * T
     if quantized:
         # scales ride the same scalar-prefetch block-table gather as the
         # values; the unit axis keeps in-kernel reads (Hkv, 1, BS)
-        ksr = k_scale.astype(jnp.float32).reshape(N, Hkv, 1, BS)
-        vsr = v_scale.astype(jnp.float32).reshape(N, Hkv, 1, BS)
-        in_specs += [pl.BlockSpec((1, Hkv, 1, BS), kv_map(t))
-                     for t in range(T)]
-        in_specs += [pl.BlockSpec((1, Hkv, 1, BS), kv_map(t))
-                     for t in range(T)]
-        inputs += [ksr] * T + [vsr] * T
+        in_specs += [kv_spec(t, False, (Hkv, 1, BS))
+                     for _ in "kv" for t in range(T)]
+        inputs += [k_scale] * T + [v_scale] * T
 
-    def out_map(b, s, j, ids_ref, len_ref):
+    def out_map(b, s, j, *_):
         return (b, s, 0, 0, 0)
 
     part = pl.BlockSpec((1, 1, Hkv, G, 1), out_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, S, spl),
         in_specs=in_specs,
         out_specs=[
@@ -256,7 +293,7 @@ def flash_decode_paged(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(ids, lens, *inputs)
+    )(lay, ids, lens, *inputs)
 
     # second stage: associative Softermax merge of the split partials under
     # the joint (Int)Max, then the one deferred normalize. With split_k=1

@@ -33,25 +33,17 @@ from repro.kernels.flash_decode_paged.ref import (decode_gather_oracle,
 
 
 def flash_decode_paged_op(q, k_pool, v_pool, block_tables, lengths, *,
-                          k_scale=None, v_scale=None,
+                          layer=None, k_scale=None, v_scale=None,
                           intmax: bool = True,
                           kv_tile_blocks: Optional[int] = None,
                           split_k: int = 1,
                           interpret: bool = False) -> jax.Array:
-    if interpret:
+    kw = dict(layer=layer, k_scale=k_scale, v_scale=v_scale, intmax=intmax)
+    if interpret or jax.default_backend() == "tpu":
         return flash_decode_paged(q, k_pool, v_pool, block_tables, lengths,
-                                  k_scale=k_scale, v_scale=v_scale,
-                                  intmax=intmax,
                                   kv_tile_blocks=kv_tile_blocks,
-                                  split_k=split_k, interpret=True)
-    if jax.default_backend() == "tpu":
-        return flash_decode_paged(q, k_pool, v_pool, block_tables, lengths,
-                                  k_scale=k_scale, v_scale=v_scale,
-                                  intmax=intmax,
-                                  kv_tile_blocks=kv_tile_blocks,
-                                  split_k=split_k)
-    return paged_decode_ref(q, k_pool, v_pool, block_tables, lengths,
-                            k_scale=k_scale, v_scale=v_scale, intmax=intmax)
+                                  split_k=split_k, interpret=interpret, **kw)
+    return paged_decode_ref(q, k_pool, v_pool, block_tables, lengths, **kw)
 
 
 __all__ = ["flash_decode_paged_op", "paged_decode_ref",

@@ -81,32 +81,46 @@ def live_block_column(col, kv_len, block_size: int, width: int):
     return jnp.minimum(col, last)
 
 
-def gather_kv(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """(N, Hkv, BS, D) pool + (B, nb) table -> (B, Hkv, nb*BS, D) caches."""
+def _take_blocks(pool: jax.Array, block_tables: jax.Array, layer):
+    """``pool[block_tables]`` of a one-layer pool, or ``pool[layer,
+    block_tables]`` of a pool with its layer axis: only the tables'
+    blocks are taken, never the layer's whole slice."""
+    if layer is None:
+        return pool[block_tables]
+    return pool[layer, block_tables]
+
+
+def gather_kv(pool: jax.Array, block_tables: jax.Array,
+              layer=None) -> jax.Array:
+    """(N, Hkv, BS, D) pool — or (L, N, Hkv, BS, D) with a ``layer``
+    index — + (B, nb) table -> (B, Hkv, nb*BS, D) caches."""
     B, nb = block_tables.shape
-    _, Hkv, BS, D = pool.shape
-    g = pool[block_tables]                    # (B, nb, Hkv, BS, D)
+    Hkv, BS, D = pool.shape[-3:]
+    g = _take_blocks(pool, block_tables, layer)   # (B, nb, Hkv, BS, D)
     g = jnp.moveaxis(g, 2, 1)                 # (B, Hkv, nb, BS, D)
     return g.reshape(B, Hkv, nb * BS, D)
 
 
-def gather_scales(scales: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """(N, Hkv, BS) scale pool + (B, nb) table -> (B, Hkv, nb*BS)."""
+def gather_scales(scales: jax.Array, block_tables: jax.Array,
+                  layer=None) -> jax.Array:
+    """(N, Hkv, BS) scale pool — or (L, N, Hkv, BS) with a ``layer``
+    index — + (B, nb) table -> (B, Hkv, nb*BS)."""
     B, nb = block_tables.shape
-    _, Hkv, BS = scales.shape
-    g = scales[block_tables]                  # (B, nb, Hkv, BS)
+    Hkv, BS = scales.shape[-2:]
+    g = _take_blocks(scales, block_tables, layer)  # (B, nb, Hkv, BS)
     g = jnp.moveaxis(g, 2, 1)
     return g.reshape(B, Hkv, nb * BS)
 
 
 def gather_kv_dequant(pool: jax.Array, scales, block_tables: jax.Array,
-                      dtype=jnp.float32) -> jax.Array:
+                      dtype=jnp.float32, layer=None) -> jax.Array:
     """Gather + (optional) int8 dequant: the pure-JAX mirror of the
-    kernels' fused dequant-on-gather. ``scales=None`` is the plain path."""
-    g = gather_kv(pool, block_tables)
+    kernels' fused dequant-on-gather. ``scales=None`` is the plain path;
+    ``layer`` indexes a pool that keeps its layer axis."""
+    g = gather_kv(pool, block_tables, layer)
     if scales is None:
         return g
-    s = gather_scales(scales, block_tables)
+    s = gather_scales(scales, block_tables, layer)
     return (g.astype(jnp.float32) * s[..., None].astype(jnp.float32)
             ).astype(dtype)
 
@@ -169,27 +183,29 @@ def decode_gather_oracle(
 
 def paged_decode_ref(
     q: jax.Array,             # (B, Hq, D) pre-scaled
-    k_pool: jax.Array,        # (N, Hkv, BS, D)
+    k_pool: jax.Array,        # (N, Hkv, BS, D), or (L, N, ...) with layer
     v_pool: jax.Array,
     block_tables: jax.Array,  # (B, nb) int32
     lengths: jax.Array,       # (B,) int32
     *,
+    layer=None,               # () int32 layer of a pool with a layer axis
     k_scale: jax.Array = None,   # (N, Hkv, BS) f32 when the pools are int8
     v_scale: jax.Array = None,
     intmax: bool = True,
 ) -> jax.Array:
-    k = gather_kv_dequant(k_pool, k_scale, block_tables)
-    v = gather_kv_dequant(v_pool, v_scale, block_tables)
+    k = gather_kv_dequant(k_pool, k_scale, block_tables, layer=layer)
+    v = gather_kv_dequant(v_pool, v_scale, block_tables, layer=layer)
     return decode_ref(q, k, v, lengths, intmax=intmax)
 
 
 def paged_decode_split_ref(
     q: jax.Array,             # (B, Hq, D) pre-scaled
-    k_pool: jax.Array,        # (N, Hkv, BS, D)
+    k_pool: jax.Array,        # (N, Hkv, BS, D), or (L, N, ...) with layer
     v_pool: jax.Array,
     block_tables: jax.Array,  # (B, W) int32
     lengths: jax.Array,       # (B,) int32
     *,
+    layer=None,               # () int32 layer of a pool with a layer axis
     split_k: int = 1,
     kv_tile_blocks: int = 1,
     k_scale: jax.Array = None,   # (N, Hkv, BS) f32 when the pools are int8
@@ -204,15 +220,15 @@ def paged_decode_split_ref(
     reduction order (exactly equal where IntMax makes every rescale an
     integer exponent add and each partition's sums coincide)."""
     B, Hq, D = q.shape
-    _, Hkv, BS, _ = k_pool.shape
+    Hkv, BS, _ = k_pool.shape[-3:]
     W = block_tables.shape[1]
     G = Hq // Hkv
 
     _, S, _, Wp = split_layout(W, kv_tile_blocks, split_k)
     bt = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, Wp - W)))
 
-    k = gather_kv_dequant(k_pool, k_scale, bt)     # (B, Hkv, Wp*BS, D)
-    v = gather_kv_dequant(v_pool, v_scale, bt)
+    k = gather_kv_dequant(k_pool, k_scale, bt, layer=layer)  # (B,Hkv,Wp*BS,D)
+    v = gather_kv_dequant(v_pool, v_scale, bt, layer=layer)
     P = (Wp * BS) // S                             # columns per partition
     k = k.reshape(B, Hkv, S, P, D)
     v = v.reshape(B, Hkv, S, P, D)

@@ -14,12 +14,13 @@ chunks' contribution lives in the pool and the recurrence is order-free.
 
 Layout (same conventions as the decode kernel):
 
-* KV is the pool ``(N, Hkv, BS, D)``; ``block_tables`` holds each
-  sequence's physical block ids in *logical* order, so the key at logical
-  position ``p`` lives at ``pool[table[p // BS], :, p % BS]``.
-* The table and the per-row ``q_pos0`` are scalar-prefetch operands
-  (SMEM): the KV BlockSpec index maps do the gather, and the body reads
-  its row's first query position as a scalar.
+* KV is the pool of every layer ``(L, N, Hkv, BS, D)``, read at one
+  ``layer``; ``block_tables`` holds each sequence's physical block ids in
+  *logical* order, so the key at logical position ``p`` lives at
+  ``pool[layer, table[p // BS], :, p % BS]``.
+* The layer index, the table and the per-row ``q_pos0`` are
+  scalar-prefetch operands (SMEM): the KV BlockSpec index maps do the
+  gather, and the body reads its row's first query position as a scalar.
 * **GQA grouping** — grid axis 0 is ``B*Hkv``: one lane owns a whole GQA
   group with a ``(group, BQ, D)`` query tile (flattened to
   ``(group*BQ, D)`` for the dots), so the block-table gather runs once per
@@ -62,13 +63,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.numerics import NEG_INF
 from repro.core.softermax import softermax_finalize
-from repro.kernels.flash_decode_paged.flash_decode_paged import concat_tiles
+from repro.kernels.flash_decode_paged.flash_decode_paged import (
+    concat_tiles, layer_operands)
 from repro.kernels.flash_decode_paged.ref import split_layout
 
 
-def _paged_prefill_kernel(bt_ref, pos_ref, q_ref, *rest, intmax: bool,
-                          block_q: int, block_size: int, tile_blocks: int,
-                          group: int, kv_heads: int, quantized: bool):
+def _paged_prefill_kernel(layer_ref, bt_ref, pos_ref, q_ref, *rest,
+                          intmax: bool, block_q: int, block_size: int,
+                          tile_blocks: int, group: int, kv_heads: int,
+                          quantized: bool):
     T = tile_blocks
     k_refs, v_refs = rest[:T], rest[T:2 * T]
     n = 2 * T
@@ -94,8 +97,8 @@ def _paged_prefill_kernel(bt_ref, pos_ref, q_ref, *rest, intmax: bool,
         # (G, BQ, D) query tile flattened to (G*BQ, D): every group head
         # shares the gathered KV tile and the mask repeats per head
         q = q_ref[0].astype(jnp.float32).reshape(group * block_q, -1)
-        k = concat_tiles(k_refs)
-        v = concat_tiles(v_refs)
+        k = concat_tiles(k_refs, idx=(0, 0, 0))
+        v = concat_tiles(v_refs, idx=(0, 0, 0))
         s = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)       # (G*BQ, T*BS)
@@ -135,21 +138,27 @@ def _paged_prefill_kernel(bt_ref, pos_ref, q_ref, *rest, intmax: bool,
     static_argnames=("intmax", "block_q", "kv_tile_blocks", "interpret"))
 def flash_prefill_paged(
     q: jax.Array,             # (B, Hq, Sq, D) pre-scaled chunk queries
-    k_pool: jax.Array,        # (N, Hkv, BS, D) physical block pool
-    v_pool: jax.Array,        # (N, Hkv, BS, D)
+    k_pool: jax.Array,        # (L, N, Hkv, BS, D) physical block pool of
+    #                           every layer; (N, Hkv, BS, D) is one layer
+    v_pool: jax.Array,
     block_tables: jax.Array,  # (B, W) int32, logical order; must cover every
     #                           position <= pos0 + Sq - 1
     q_pos0: jax.Array,        # (B,) int32 absolute position of q[:, :, 0]
     *,
-    k_scale: jax.Array = None,   # (N, Hkv, BS) f32: int8 pools' row scales
-    v_scale: jax.Array = None,
+    layer=None,               # () int32 layer the kernel reads; None with
+    #                           a one-layer pool
+    k_scale: jax.Array = None,   # (L, N, Hkv, BS) f32: int8 pools' row
+    v_scale: jax.Array = None,   # scales, laid out as the pool (no L with
+    #                              a one-layer pool)
     intmax: bool = True,
     block_q: int = 128,
     kv_tile_blocks: int = 1,  # pool blocks gathered per kv grid step (T)
     interpret: bool = False,
 ) -> jax.Array:
+    k_pool, v_pool, k_scale, v_scale, lay = layer_operands(
+        k_pool, v_pool, k_scale, v_scale, layer)
     B, Hq, Sq, D = q.shape
-    N, Hkv, BS, _ = k_pool.shape
+    _, _, Hkv, BS, _ = k_pool.shape
     W = block_tables.shape[1]
     G = Hq // Hkv
     quantized = k_scale is not None
@@ -168,32 +177,32 @@ def flash_prefill_paged(
     qf = qp.reshape(B, Hkv, G, Sqp, D).reshape(B * Hkv, G, Sqp, D)
     pos = q_pos0.astype(jnp.int32)
 
-    def kv_map(t):
-        # one gather map per tile slot; values and scales share it
-        def _map(bh, i, j, bt_ref, pos_ref):
-            return (bt_ref[bh // Hkv, j * T + t], bh % Hkv, 0, 0)
-        return _map
+    def kv_spec(t, layered, block):
+        # one gather per tile slot: (layer, block id, KV head) of the
+        # pools, the layer scalar-prefetched beside the table; (block id,
+        # KV head) of one layer's scales
+        def _map(bh, i, j, layer_ref, bt_ref, pos_ref):
+            return ((layer_ref[0],) if layered else ()) + \
+                (bt_ref[bh // Hkv, j * T + t], bh % Hkv) + \
+                (0,) * (len(block) - 1)
+        return pl.BlockSpec((1,) * (1 + layered) + block, _map)
 
-    def q_map(bh, i, j, bt_ref, pos_ref):
+    def q_map(bh, i, j, *_):
         return (bh, 0, i, 0)
 
     in_specs = [pl.BlockSpec((1, G, block_q, D), q_map)]
-    in_specs += [pl.BlockSpec((1, 1, BS, D), kv_map(t)) for t in range(T)]
-    in_specs += [pl.BlockSpec((1, 1, BS, D), kv_map(t)) for t in range(T)]
+    in_specs += [kv_spec(t, True, (1, BS, D))
+                 for _ in "kv" for t in range(T)]
     inputs = [qf] + [k_pool] * T + [v_pool] * T
     if quantized:
         # scales ride the same scalar-prefetch gather as the values; the
-        # trailing unit axis keeps in-kernel reads 2-D (TPU-friendly)
-        ksr = k_scale.astype(jnp.float32).reshape(N, Hkv, 1, BS)
-        vsr = v_scale.astype(jnp.float32).reshape(N, Hkv, 1, BS)
-        in_specs += [pl.BlockSpec((1, 1, 1, BS), kv_map(t))
-                     for t in range(T)]
-        in_specs += [pl.BlockSpec((1, 1, 1, BS), kv_map(t))
-                     for t in range(T)]
-        inputs += [ksr] * T + [vsr] * T
+        # unit axis keeps in-kernel reads 2-D (TPU-friendly)
+        in_specs += [kv_spec(t, False, (1, 1, BS))
+                     for _ in "kv" for t in range(T)]
+        inputs += [k_scale] * T + [v_scale] * T
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B * Hkv, nq, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, G, block_q, D), q_map),
@@ -214,7 +223,7 @@ def flash_prefill_paged(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(bt, pos, *inputs)
+    )(lay, bt, pos, *inputs)
 
     out = out.reshape(B, Hkv, G, Sqp, D).reshape(B, Hq, Sqp, D)
     return out[:, :, :Sq, :]

@@ -31,32 +31,22 @@ from repro.kernels.flash_prefill_paged.ref import (paged_prefill_ref,
 
 
 def flash_prefill_paged_op(q, k_pool, v_pool, block_tables, q_pos0, *,
-                           k_scale=None, v_scale=None,
+                           layer=None, k_scale=None, v_scale=None,
                            intmax: bool = True,
                            kv_tile_blocks: int = 1,
                            interpret: bool = False,
                            split_tail_blocks: Optional[int] = None
                            ) -> jax.Array:
-    if interpret:
+    kw = dict(layer=layer, k_scale=k_scale, v_scale=v_scale, intmax=intmax)
+    if interpret or jax.default_backend() == "tpu":
         return flash_prefill_paged(q, k_pool, v_pool, block_tables, q_pos0,
-                                   k_scale=k_scale, v_scale=v_scale,
-                                   intmax=intmax,
                                    kv_tile_blocks=kv_tile_blocks,
-                                   interpret=True)
-    if jax.default_backend() == "tpu":
-        return flash_prefill_paged(q, k_pool, v_pool, block_tables, q_pos0,
-                                   k_scale=k_scale, v_scale=v_scale,
-                                   intmax=intmax,
-                                   kv_tile_blocks=kv_tile_blocks)
+                                   interpret=interpret, **kw)
     if split_tail_blocks is not None:
         return paged_prefill_split_ref(q, k_pool, v_pool, block_tables,
-                                       q_pos0,
-                                       tail_blocks=split_tail_blocks,
-                                       k_scale=k_scale, v_scale=v_scale,
-                                       intmax=intmax)
-    return paged_prefill_ref(q, k_pool, v_pool, block_tables, q_pos0,
-                             k_scale=k_scale, v_scale=v_scale,
-                             intmax=intmax)
+                                       q_pos0, tail_blocks=split_tail_blocks,
+                                       **kw)
+    return paged_prefill_ref(q, k_pool, v_pool, block_tables, q_pos0, **kw)
 
 
 __all__ = ["flash_prefill_paged_op", "flash_prefill_paged",

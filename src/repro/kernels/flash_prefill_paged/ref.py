@@ -76,20 +76,22 @@ def prefill_gather_oracle(
 
 def paged_prefill_ref(
     q: jax.Array,             # (B, Hq, Sq, D) pre-scaled
-    k_pool: jax.Array,        # (N, Hkv, BS, D)
+    k_pool: jax.Array,        # (N, Hkv, BS, D), or (L, N, ...) with layer
     v_pool: jax.Array,
     block_tables: jax.Array,  # (B, W) int32, logical order
     q_pos0: jax.Array,        # (B,) int32 absolute position of q[:, :, 0]
     *,
+    layer=None,               # () int32 layer of a pool with a layer axis
     k_scale: jax.Array = None,   # (N, Hkv, BS) f32 when the pools are int8
     v_scale: jax.Array = None,
     intmax: bool = True,
 ) -> jax.Array:
     B, Hq, Sq, D = q.shape
-    _, Hkv, BS, _ = k_pool.shape
+    Hkv = k_pool.shape[-3]
     group = Hq // Hkv
-    k = gather_kv_dequant(k_pool, k_scale, block_tables)  # (B,Hkv,W*BS,D)
-    v = gather_kv_dequant(v_pool, v_scale, block_tables)
+    k = gather_kv_dequant(k_pool, k_scale, block_tables,
+                          layer=layer)                   # (B,Hkv,W*BS,D)
+    v = gather_kv_dequant(v_pool, v_scale, block_tables, layer=layer)
     K = k.shape[2]
     qg = q.reshape(B, Hkv, group, Sq, D)
     s = jnp.einsum("bhgqd,bhkd->bhgqk", qg.astype(jnp.float32),
@@ -113,11 +115,12 @@ def paged_prefill_ref(
 
 def paged_prefill_split_ref(
     q: jax.Array,             # (B, Hq, Sq, D) pre-scaled
-    k_pool: jax.Array,        # (N, Hkv, BS, D)
+    k_pool: jax.Array,        # (N, Hkv, BS, D), or (L, N, ...) with layer
     v_pool: jax.Array,
     block_tables: jax.Array,  # (B, W) int32, logical order
     q_pos0: jax.Array,        # (B,) int32 absolute position of q[:, :, 0]
     *,
+    layer=None,               # () int32 layer of a pool with a layer axis
     tail_blocks: int,
     k_scale: jax.Array = None,   # (N, Hkv, BS) f32 when the pools are int8
     v_scale: jax.Array = None,
@@ -142,23 +145,27 @@ def paged_prefill_split_ref(
     ``paged_prefill_ref``.
     """
     B, Hq, Sq, D = q.shape
-    _, Hkv, BS, _ = k_pool.shape
+    Hkv, BS, _ = k_pool.shape[-3:]
     group = Hq // Hkv
     W = block_tables.shape[1]
     t = min(tail_blocks, W)
     qg = q.reshape(B, Hkv, group, Sq, D).astype(jnp.float32)
     qi = q_pos0.astype(jnp.int32)[:, None] + jnp.arange(Sq)[None, :]
 
-    k2 = gather_kv_dequant(k_pool, k_scale, block_tables[:, W - t:])
-    v2 = gather_kv_dequant(v_pool, v_scale, block_tables[:, W - t:])
+    k2 = gather_kv_dequant(k_pool, k_scale, block_tables[:, W - t:],
+                           layer=layer)
+    v2 = gather_kv_dequant(v_pool, v_scale, block_tables[:, W - t:],
+                           layer=layer)
     s2 = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k2.astype(jnp.float32))
     kj = (W - t) * BS + jnp.arange(t * BS, dtype=jnp.int32)
     valid = kj[None, None, :] <= qi[:, :, None]            # (B, Sq, t*BS)
     s2 = jnp.where(valid[:, None, None, :, :], s2, NEG_INF)
     m = jnp.max(s2, axis=-1, keepdims=True)
     if W > t:
-        k1 = gather_kv_dequant(k_pool, k_scale, block_tables[:, :W - t])
-        v1 = gather_kv_dequant(v_pool, v_scale, block_tables[:, :W - t])
+        k1 = gather_kv_dequant(k_pool, k_scale,
+                               block_tables[:, :W - t], layer=layer)
+        v1 = gather_kv_dequant(v_pool, v_scale,
+                               block_tables[:, :W - t], layer=layer)
         s1 = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k1.astype(jnp.float32))
         m = jnp.maximum(m, jnp.max(s1, axis=-1, keepdims=True))
     m = jnp.ceil(m) if intmax else m

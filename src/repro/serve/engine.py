@@ -68,8 +68,8 @@ from repro.serve.kernel_costs import decode_launch_cost, prefill_launch_cost
 from repro.serve.kv_pool import PagedKVCache, PoolExhausted
 from repro.serve.paged_step import (check_paged_support, paged_decode_step,
                                     paged_prefill, paged_prefill_chunked,
-                                    paged_prefill_suffix, scatter_prefill,
-                                    scatter_prefill_offset,
+                                    paged_prefill_suffix, scale_kwargs,
+                                    scatter_prefill, scatter_prefill_offset,
                                     table_width_bucket)
 from repro.serve.radix_cache import RadixCache
 from repro.serve.scheduler import (FINISH_DEADLINE, FINISH_QUARANTINED,
@@ -369,10 +369,6 @@ class ContinuousEngine:
         # ``self._pools()`` in and rebind whatever comes back.
         np_ = 4 if self.quantized else 2
 
-        def _sc(pools):
-            return {"k_scale": pools[2], "v_scale": pools[3]} \
-                if len(pools) == 4 else {}
-
         # greedy argmax is fused into the jitted steps so the common
         # (temperature 0) path never materializes logits on the host
         def _amax(lg):
@@ -391,29 +387,29 @@ class ContinuousEngine:
             out = paged_decode_step(p, t, pools[0], pools[1], bt, ln, cfg,
                                     kv_tile_blocks=tile,
                                     decode_split_k=split,
-                                    **_sc(pools))
+                                    **scale_kwargs(pools))
             return (_amax(out[0]), out[0]) + tuple(out[1:])
 
         def _prefill_suffix_fn(p, t, pos0, last_rel, pt, pl, *pools):
             lg, ks, vs = paged_prefill_suffix(p, t, pos0, last_rel,
                                               pools[0], pools[1], pt, pl,
-                                              cfg, **_sc(pools))
+                                              cfg, **scale_kwargs(pools))
             return _amax(lg), lg, ks, vs
 
         def _prefill_chunk_fn(p, t, pos0, last_rel, pt, blk, off, *pools):
             out = paged_prefill_chunked(p, t, pos0, last_rel, pools[0],
                                         pools[1], pt, blk, off, cfg,
                                         kv_tile_blocks=self._prefill_tile,
-                                        **_sc(pools))
+                                        **scale_kwargs(pools))
             return (_amax(out[0]), out[0]) + tuple(out[1:])
 
         def _scatter_fn(ks, vs, block_ids, *pools):
             return scatter_prefill(pools[0], pools[1], ks, vs, block_ids,
-                                   **_sc(pools))
+                                   **scale_kwargs(pools))
 
         def _scatter_off_fn(ks, vs, blk, off, *pools):
             return scatter_prefill_offset(pools[0], pools[1], ks, vs, blk,
-                                          off, **_sc(pools))
+                                          off, **scale_kwargs(pools))
 
         # On accelerators, donate the pools: they are rebound to the returned
         # arrays every call, so the update aliases in-place instead of

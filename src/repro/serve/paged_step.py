@@ -50,6 +50,14 @@ in the refs — and accumulate in fp32, so the only precision loss is the
 int8 rounding of the stored K/V rows, the same contract as the dense
 ``models/attention.py`` int8 decode branch. Functions that update the pool
 return the new scale tensors after the new pools (callers unpack by mode).
+
+**The pool is updated in place.** ``paged_prefill_chunked`` and
+``paged_decode_step`` carry the whole ``(L, N, Hkv, BS, Dh)`` pool (and
+its scale pools) through the layer scan, scatter each layer's new rows at
+``[layer, blk, :, off]`` into it, and hand both kernels the whole pool
+with the layer index. Only the new rows move: with the engine's donation
+the pool aliases from the program's input to its output, and no program
+slices a layer out of it or holds a second copy.
 """
 from __future__ import annotations
 
@@ -506,22 +514,57 @@ def scatter_prefill_offset(
 # ---------------------------------------------------------------------------
 
 
-def _chunk_attention(q, k_pool_l, v_pool_l, table, pos0, cfg, intmax,
-                     ksc_l=None, vsc_l=None, kv_tile_blocks=1):
-    """Chunk queries over block-table-resident KV through the one shared
-    dispatcher: Pallas kernel on TPU / under ``cfg.interpret_kernels``;
-    elsewhere the pure-JAX split oracle, which skips the causal mask on
-    the provably-valid prefix bulk. Passing ``split_tail_blocks`` is safe
-    here because ``paged_prefill_chunked`` requires an exact-cover or
-    chunk-quantized table (see the contract in its signature)."""
-    BS = k_pool_l.shape[2]
+def _pool_tuple(k_pool, v_pool, k_scale, v_scale):
+    """The pools a step carries through its layer scan: ``(k, v)``, or
+    ``(k, v, k_scale, v_scale)`` for an int8 pool."""
+    if k_pool.dtype == jnp.int8:
+        return (k_pool, v_pool, k_scale, v_scale)
+    return (k_pool, v_pool)
+
+
+def _write_rows(pools, layer, blk, off, k, v):
+    """Scatter new K/V rows ``(R, Hkv, Dh)`` into the carried pools of
+    every layer, row ``r`` at ``[layer, blk[r], :, off[r]]``; with an int8
+    pool each row is quantized against its own amax and its scales land
+    at the same coordinates of the scale pools. Returns the new pools
+    tuple. Inside the layer scan the pools are the carry, so XLA updates
+    them in place: only the rows move, never a layer's slice."""
+    h = jnp.arange(k.shape[1])
+    at = (layer, blk[:, None], h[None, :], off[:, None])
+    kp, vp = pools[:2]
+    scales = ()
+    if kp.dtype == jnp.int8:
+        k, k_sc = attn_mod.quantize_kv(k)             # (R, Hkv) row scales
+        v, v_sc = attn_mod.quantize_kv(v)
+        scales = (pools[2].at[at].set(k_sc), pools[3].at[at].set(v_sc))
+    return (kp.at[at].set(k.astype(kp.dtype)),
+            vp.at[at].set(v.astype(vp.dtype))) + scales
+
+
+def scale_kwargs(pools):
+    """The ``k_scale``/``v_scale`` keywords of a pools tuple: ``(k, v)``,
+    or ``(k, v, k_scale, v_scale)`` for an int8 pool."""
+    return {"k_scale": pools[2], "v_scale": pools[3]} \
+        if len(pools) == 4 else {}
+
+
+def _chunk_attention(q, pools, layer, table, pos0, cfg, intmax,
+                     kv_tile_blocks=1):
+    """Chunk queries over block-table-resident KV of one layer of the
+    pools through the one shared dispatcher: Pallas kernel on TPU / under
+    ``cfg.interpret_kernels``; elsewhere the pure-JAX split oracle, which
+    skips the causal mask on the provably-valid prefix bulk. Passing
+    ``split_tail_blocks`` is safe here because ``paged_prefill_chunked``
+    requires an exact-cover or chunk-quantized table (see the contract in
+    its signature)."""
+    BS = pools[0].shape[3]
     tail = 2 * (-(-q.shape[2] // BS)) + 1
-    return flash_prefill_paged_op(q, k_pool_l, v_pool_l, table, pos0,
-                                  k_scale=ksc_l, v_scale=vsc_l,
-                                  intmax=intmax,
+    return flash_prefill_paged_op(q, pools[0], pools[1], table, pos0,
+                                  layer=layer, intmax=intmax,
                                   kv_tile_blocks=kv_tile_blocks,
                                   interpret=cfg.interpret_kernels,
-                                  split_tail_blocks=tail)
+                                  split_tail_blocks=tail,
+                                  **scale_kwargs(pools))
 
 
 def paged_prefill_chunked(
@@ -568,38 +611,23 @@ def paged_prefill_chunked(
     params = maybe_cast_params(params, cfg)
     dh = cfg.head_dim_
     premult, intmax = attn_mod._mode(cfg)
-    quantized = k_pool.dtype == jnp.int8
     positions = pos0 + jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32),
                                         (B, C))
     x = embed(params["embed"], tokens, cfg)
-    Hkv = cfg.n_kv_heads
-    h_idx = jnp.arange(Hkv)
     qpos0 = jnp.broadcast_to(pos0, (B,)).astype(jnp.int32)
+    pools = _pool_tuple(k_pool, v_pool, k_scale, v_scale)
 
-    def body(x, xs):
-        if quantized:
-            bp, kp_l, vp_l, ksc_l, vsc_l = xs
-        else:
-            bp, kp_l, vp_l = xs
-            ksc_l = vsc_l = None
+    def body(carry, xs):
+        x, pools = carry
+        bp, layer = xs
         h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
         q, k, v = attn_mod._project_qkv(bp["mixer"], h, cfg, positions)
-        rows_k = jnp.swapaxes(k[0], 0, 1)             # (C, Hkv, Dh)
-        rows_v = jnp.swapaxes(v[0], 0, 1)
-        if quantized:
-            rows_k, sc_k = attn_mod.quantize_kv(rows_k)   # (C, Hkv) scales
-            rows_v, sc_v = attn_mod.quantize_kv(rows_v)
-            ksc_l = ksc_l.at[blk[:, None], h_idx[None, :],
-                             off[:, None]].set(sc_k)
-            vsc_l = vsc_l.at[blk[:, None], h_idx[None, :],
-                             off[:, None]].set(sc_v)
-        kp_l = kp_l.at[blk[:, None], h_idx[None, :], off[:, None], :].set(
-            rows_k.astype(kp_l.dtype))
-        vp_l = vp_l.at[blk[:, None], h_idx[None, :], off[:, None], :].set(
-            rows_v.astype(vp_l.dtype))
+        pools = _write_rows(pools, layer, blk, off,
+                            jnp.swapaxes(k[0], 0, 1),   # (C, Hkv, Dh)
+                            jnp.swapaxes(v[0], 0, 1))
         q = q * jnp.asarray(premult * dh ** -0.5, q.dtype)
-        o = _chunk_attention(q, kp_l, vp_l, table, qpos0, cfg, intmax,
-                             ksc_l, vsc_l, kv_tile_blocks)
+        o = _chunk_attention(q, pools, layer, table, qpos0, cfg, intmax,
+                             kv_tile_blocks)
         y = attn_mod._out_proj(bp["mixer"], o, cfg)
         x = x + y
         h2 = rmsnorm(bp["ln2"], x, cfg.norm_eps)
@@ -608,23 +636,16 @@ def paged_prefill_chunked(
         else:
             f = mlp(bp["ffn"], h2, cfg.activation)
         x = shard_act(x + f, ("batch", "seq", "act_embed"))
-        if quantized:
-            return x, (kp_l, vp_l, ksc_l, vsc_l)
-        return x, (kp_l, vp_l)
+        return (x, pools), None
 
-    if quantized:
-        x, (new_k, new_v, new_ksc, new_vsc) = jax.lax.scan(
-            body, x, (params["blocks"], k_pool, v_pool, k_scale, v_scale))
-    else:
-        x, (new_k, new_v) = jax.lax.scan(body, x, (params["blocks"], k_pool,
-                                                   v_pool))
+    layers = jnp.arange(k_pool.shape[0])
+    (x, pools), _ = jax.lax.scan(body, (x, pools),
+                                 (params["blocks"], layers))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     x_last = jnp.take_along_axis(
         x, last_rel[:, None, None].astype(jnp.int32), axis=1)  # (1, 1, d)
     lg = logits(params["embed"], x_last, cfg)[:, 0]
-    if quantized:
-        return lg, new_k, new_v, new_ksc, new_vsc
-    return lg, new_k, new_v
+    return (lg,) + pools
 
 
 # ---------------------------------------------------------------------------
@@ -632,19 +653,19 @@ def paged_prefill_chunked(
 # ---------------------------------------------------------------------------
 
 
-def _paged_attention(q, k_pool_l, v_pool_l, block_tables, new_len, cfg,
-                     intmax, ksc_l=None, vsc_l=None, kv_tile_blocks=None,
-                     split_k=1):
-    """Fused-batch decode attention through the one shared dispatcher
-    (``kernels/flash_decode_paged/ops.py``): grouped/tiled/split Pallas
-    kernel on TPU or under ``cfg.interpret_kernels``, the pure-JAX gather
-    oracle elsewhere (tile/split are layout knobs — same math)."""
-    return flash_decode_paged_op(q, k_pool_l, v_pool_l, block_tables,
-                                 new_len, k_scale=ksc_l, v_scale=vsc_l,
-                                 intmax=intmax,
+def _paged_attention(q, pools, layer, block_tables, new_len, cfg, intmax,
+                     kv_tile_blocks=None, split_k=1):
+    """Fused-batch decode attention over one layer of the pools through
+    the one shared dispatcher (``kernels/flash_decode_paged/ops.py``):
+    grouped/tiled/split Pallas kernel on TPU or under
+    ``cfg.interpret_kernels``, the pure-JAX gather oracle elsewhere
+    (tile/split are layout knobs — same math)."""
+    return flash_decode_paged_op(q, pools[0], pools[1], block_tables,
+                                 new_len, layer=layer, intmax=intmax,
                                  kv_tile_blocks=kv_tile_blocks,
                                  split_k=split_k,
-                                 interpret=cfg.interpret_kernels)
+                                 interpret=cfg.interpret_kernels,
+                                 **scale_kwargs(pools))
 
 
 def paged_decode_step(
@@ -672,13 +693,10 @@ def paged_decode_step(
     lengths by one afterwards.
     """
     params = maybe_cast_params(params, cfg)
-    B = tokens1.shape[0]
     BS = k_pool.shape[3]
-    Hkv = cfg.n_kv_heads
     dt = cfg.compute_dtype_
     dh = cfg.head_dim_
     premult, intmax = attn_mod._mode(cfg)
-    quantized = k_pool.dtype == jnp.int8
 
     table = params["embed"]["embedding"].astype(dt)
     x1 = shard_act(table[tokens1], ("batch", "act_embed"))
@@ -687,14 +705,11 @@ def paged_decode_step(
                               axis=1)[:, 0]           # (B,) physical block
     off = lengths % BS
     new_len = lengths + 1
-    h_idx = jnp.arange(Hkv)
+    pools = _pool_tuple(k_pool, v_pool, k_scale, v_scale)
 
-    def body(x1, xs):
-        if quantized:
-            bp, kp_l, vp_l, ksc_l, vsc_l = xs
-        else:
-            bp, kp_l, vp_l = xs
-            ksc_l = vsc_l = None
+    def body(carry, xs):
+        x1, pools = carry
+        bp, layer = xs
         h = rmsnorm(bp["ln1"], x1, cfg.norm_eps)
         q = jnp.einsum("bd,dhk->bhk", h, bp["mixer"]["wq"].astype(dt))
         k = jnp.einsum("bd,dhk->bhk", h, bp["mixer"]["wk"].astype(dt))
@@ -706,21 +721,10 @@ def paged_decode_step(
             pos = lengths[:, None]                    # (B, 1): next position
             q = rope(q[:, :, None, :], pos[:, :, None], cfg.rope_theta)[:, :, 0]
             k = rope(k[:, :, None, :], pos[:, :, None], cfg.rope_theta)[:, :, 0]
-        if quantized:
-            k, k_sc = attn_mod.quantize_kv(k)         # (B, Hkv) row scales
-            v, v_sc = attn_mod.quantize_kv(v)
-            ksc_l = ksc_l.at[blk[:, None], h_idx[None, :],
-                             off[:, None]].set(k_sc)
-            vsc_l = vsc_l.at[blk[:, None], h_idx[None, :],
-                             off[:, None]].set(v_sc)
-        kp_l = kp_l.at[blk[:, None], h_idx[None, :], off[:, None], :].set(
-            k.astype(kp_l.dtype))
-        vp_l = vp_l.at[blk[:, None], h_idx[None, :], off[:, None], :].set(
-            v.astype(vp_l.dtype))
+        pools = _write_rows(pools, layer, blk, off, k, v)
         q = q * jnp.asarray(premult * dh ** -0.5, q.dtype)
-        o = _paged_attention(q, kp_l, vp_l, block_tables, new_len, cfg,
-                             intmax, ksc_l, vsc_l, kv_tile_blocks,
-                             decode_split_k)
+        o = _paged_attention(q, pools, layer, block_tables, new_len, cfg,
+                             intmax, kv_tile_blocks, decode_split_k)
         y = jnp.einsum("bhk,hkd->bd", o, bp["mixer"]["wo"].astype(dt))
         x1 = x1 + y
         h2 = rmsnorm(bp["ln2"], x1, cfg.norm_eps)
@@ -729,18 +733,11 @@ def paged_decode_step(
             f = f[:, 0]
         else:
             f = mlp(bp["ffn"], h2, cfg.activation)
-        if quantized:
-            return x1 + f, (kp_l, vp_l, ksc_l, vsc_l)
-        return x1 + f, (kp_l, vp_l)
+        return (x1 + f, pools), None
 
-    if quantized:
-        x1, (new_k, new_v, new_ksc, new_vsc) = jax.lax.scan(
-            body, x1, (params["blocks"], k_pool, v_pool, k_scale, v_scale))
-    else:
-        x1, (new_k, new_v) = jax.lax.scan(body, x1, (params["blocks"],
-                                                     k_pool, v_pool))
+    layers = jnp.arange(k_pool.shape[0])
+    (x1, pools), _ = jax.lax.scan(body, (x1, pools),
+                                  (params["blocks"], layers))
     x1 = rmsnorm(params["final_norm"], x1, cfg.norm_eps)
     lg = logits(params["embed"], x1[:, None, :], cfg)[:, 0]
-    if quantized:
-        return lg, new_k, new_v, new_ksc, new_vsc
-    return lg, new_k, new_v
+    return (lg,) + pools

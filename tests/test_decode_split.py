@@ -362,6 +362,63 @@ class TestGroupedSplitDecodeKernel:
         assert list(np.asarray(live_block_column(cols, 96, 16, 6))) == \
             [0, 1, 2, 3, 4, 5, 5, 5]
 
+    @pytest.mark.parametrize("geom,T", [
+        pytest.param(SMALL, 2, id="T2"),
+        pytest.param(QWEN, None, id="qwen-derived")])
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    def test_layer_index_reads_that_layer(self, geom, T, quantized):
+        """A pool that keeps its layer axis, read at ``layer=1``, gives bit
+        for bit what the one-layer pool ``pool[1]`` gives: under the
+        interpreter and through the CPU references."""
+        (B, Hq, Hkv, D, BS, W), lens = geom
+        layers = [_random_paged_kv(B, Hkv, D, BS, W, quantized)
+                  for _ in range(3)]
+        bt = layers[0][2]
+
+        def stack(i):
+            a = jnp.stack([layer[i] for layer in layers])
+            return a if quantized or i > 1 else a.astype(jnp.bfloat16)
+
+        kp, vp = stack(0), stack(1)
+        sc = {"k_scale": stack(3), "v_scale": stack(4)} if quantized else {}
+        sc1 = {k: v[1] for k, v in sc.items()}
+        q = jnp.asarray(_rng.normal(size=(B, Hq, D)),
+                        jnp.float32) / np.sqrt(D)
+        lens = jnp.asarray(lens, jnp.int32)
+        pairs = [
+            (flash_decode_paged(q, kp, vp, bt, lens, layer=1,
+                                kv_tile_blocks=T, interpret=True, **sc),
+             flash_decode_paged(q, kp[1], vp[1], bt, lens,
+                                kv_tile_blocks=T, interpret=True, **sc1)),
+            (paged_decode_ref(q, kp, vp, bt, lens, layer=1, **sc),
+             paged_decode_ref(q, kp[1], vp[1], bt, lens, **sc1)),
+            (paged_decode_split_ref(q, kp, vp, bt, lens, layer=1,
+                                    kv_tile_blocks=T or 1, split_k=2, **sc),
+             paged_decode_split_ref(q, kp[1], vp[1], bt, lens,
+                                    kv_tile_blocks=T or 1, split_k=2,
+                                    **sc1))]
+        for got, want in pairs:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # and the layer read is layer 1's, not another's
+        other = paged_decode_ref(q, kp[2], vp[2], bt, lens,
+                                 **{k: v[2] for k, v in sc.items()})
+        assert not np.array_equal(np.asarray(pairs[0][0])[:3],
+                                  np.asarray(other)[:3])
+
+    def test_layer_index_goes_with_the_layer_axis(self):
+        """A layer index needs a pool with its layer axis, and a pool with
+        its layer axis needs a layer index."""
+        (B, Hq, Hkv, D, BS, W), lens = SMALL
+        kp, vp, bt, _, _ = _random_paged_kv(B, Hkv, D, BS, W)
+        q = jnp.zeros((B, Hq, D), jnp.float32)
+        lens = jnp.asarray(lens, jnp.int32)
+        with pytest.raises(ValueError, match="layer"):
+            flash_decode_paged(q, kp, vp, bt, lens, layer=0, interpret=True)
+        with pytest.raises(ValueError, match="layer"):
+            flash_decode_paged(q, kp[None], vp[None], bt, lens,
+                               interpret=True)
+
     @pytest.mark.tpu
     def test_compiled_matches_interpret(self):
         """Compiled-Pallas parity for the grouped/tiled/split grid — only

@@ -11,6 +11,7 @@ from repro.kernels.flash_prefill_paged import (flash_prefill_paged,
                                                flash_prefill_paged_op,
                                                paged_prefill_ref,
                                                paged_prefill_split_ref)
+from repro.models.attention import quantize_kv
 from repro.models.registry import get_config, model_fns, reduce_config
 from repro.serve import ContinuousEngine
 from repro.serve.kv_pool import PagedKVCache
@@ -81,6 +82,49 @@ class TestFlashPrefillPagedKernel:
         want = paged_prefill_ref(q, kp, vp, bt, pos0)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5)
+
+    @pytest.mark.parametrize("T", [1, 2])
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    def test_layer_index_reads_that_layer(self, T, quantized):
+        """A pool that keeps its layer axis, read at ``layer=1``, gives bit
+        for bit what the one-layer pool ``pool[1]`` gives: under the
+        interpreter and through both CPU references."""
+        B, Hq, Hkv, D, BS, Sq, bq = 2, 8, 2, 16, 8, 19, 8
+        pos0s = (11, 26)
+        W = -(-(max(pos0s) + Sq) // BS)
+        kp, vp, bt = _random_paged_kv(B, Hkv, D, BS, W)
+        kp = jnp.stack([kp, kp + 1.0, kp - 1.0])
+        vp = jnp.stack([vp, vp * 0.5, vp * 2.0])
+        sc = {}
+        if quantized:
+            kp, ksc = quantize_kv(kp)
+            vp, vsc = quantize_kv(vp)
+            sc = {"k_scale": ksc, "v_scale": vsc}
+        else:
+            kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+        sc1 = {k: v[1] for k, v in sc.items()}
+        q = jnp.asarray(_rng.normal(size=(B, Hq, Sq, D)),
+                        jnp.float32) / np.sqrt(D)
+        pos0 = jnp.asarray(pos0s, jnp.int32)
+        tail = 2 * (-(-Sq // BS)) + 1
+        pairs = [
+            (flash_prefill_paged(q, kp, vp, bt, pos0, layer=1, block_q=bq,
+                                 kv_tile_blocks=T, interpret=True, **sc),
+             flash_prefill_paged(q, kp[1], vp[1], bt, pos0, block_q=bq,
+                                 kv_tile_blocks=T, interpret=True, **sc1)),
+            (paged_prefill_ref(q, kp, vp, bt, pos0, layer=1, **sc),
+             paged_prefill_ref(q, kp[1], vp[1], bt, pos0, **sc1)),
+            (paged_prefill_split_ref(q, kp, vp, bt, pos0, layer=1,
+                                     tail_blocks=tail, **sc),
+             paged_prefill_split_ref(q, kp[1], vp[1], bt, pos0,
+                                     tail_blocks=tail, **sc1))]
+        for got, want in pairs:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        other = paged_prefill_ref(q, kp[0], vp[0], bt, pos0,
+                                  **{k: v[0] for k, v in sc.items()})
+        assert not np.array_equal(np.asarray(pairs[0][0]),
+                                  np.asarray(other))
 
     def test_ref_matches_dense_suffix_attention(self):
         """The single-table positional-causal oracle computes the same

@@ -12,6 +12,7 @@ from repro.kernels.flash_decode_paged.ref import gather_kv
 from repro.models.registry import get_config, model_fns, reduce_config
 from repro.serve import (ContinuousEngine, PagedKVCache, PoolExhausted,
                          Scheduler, ServeEngine)
+from repro.serve.paged_step import paged_decode_step, paged_prefill_chunked
 
 _rng = np.random.default_rng(11)
 
@@ -183,6 +184,85 @@ class TestPagedDecodeKernel:
                                  interpret=True)
         np.testing.assert_array_equal(np.asarray(out),
                                       np.zeros_like(np.asarray(out)))
+
+
+def _random_pools(cfg, n_blocks, bs, quantized):
+    """Pools of every layer filled with random rows, so that any write
+    shows: bf16 values, or int8 codes with positive f32 scales."""
+    shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads, bs, cfg.head_dim_)
+    if not quantized:
+        return tuple(jnp.asarray(_rng.normal(size=shape), jnp.bfloat16)
+                     for _ in "kv")
+    codes = tuple(jnp.asarray(_rng.integers(-127, 128, shape), jnp.int8)
+                  for _ in "kv")
+    scales = tuple(jnp.asarray(_rng.uniform(0.01, 1.0, shape[:-1]),
+                               jnp.float32) for _ in "kv")
+    return codes + scales
+
+
+def _changed_rows(old, new):
+    """{(layer, block, head, offset)} of the pool rows that differ."""
+    o, n = np.asarray(old), np.asarray(new)
+    diff = o != n
+    if diff.ndim == 5:
+        diff = diff.any(axis=-1)
+    return {tuple(int(i) for i in ix) for ix in np.argwhere(diff)}
+
+
+class TestInPlacePoolUpdate:
+    """The decode and chunk steps write each layer's new rows straight
+    into the pool of every layer: after one step the pools differ from
+    their input only at the written ``[layer, blk, :, off]`` rows and in
+    the garbage block 0."""
+
+    def _check(self, cfg, pools, out, rows):
+        written = {(lay, b, h, o) for lay in range(cfg.n_layers)
+                   for b, o in rows for h in range(cfg.n_kv_heads)}
+        for old, new in zip(pools, out):
+            assert new.shape == old.shape and new.dtype == old.dtype
+            changed = _changed_rows(old, new)
+            assert {r for r in changed if r[1] != 0} == written
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    def test_decode_step_writes_only_its_rows(self, setup, quantized):
+        cfg, params = setup
+        bs = 8
+        pools = _random_pools(cfg, 9, bs, quantized)
+        lengths = np.asarray([5, 17, 0], np.int32)   # row 2 is a zombie
+        tables = np.asarray([[1, 2, 0], [3, 4, 5], [0, 0, 0]], np.int32)
+        tokens = jnp.asarray(_rng.integers(1, cfg.vocab_size, 3), jnp.int32)
+        sc = {"k_scale": pools[2], "v_scale": pools[3]} if quantized else {}
+        lg, *out = paged_decode_step(params, tokens, pools[0], pools[1],
+                                     jnp.asarray(tables),
+                                     jnp.asarray(lengths), cfg, **sc)
+        assert lg.shape[0] == 3 and len(out) == len(pools)
+        rows = [(int(tables[b, ln // bs]), int(ln % bs))
+                for b, ln in enumerate(lengths[:2])]
+        self._check(cfg, pools, out, rows)
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    def test_chunk_step_writes_only_its_rows(self, setup, quantized):
+        cfg, params = setup
+        bs, C, sl, m = 8, 8, 6, 10     # 6 real rows at 10..15, 2 pad rows
+        pools = _random_pools(cfg, 9, bs, quantized)
+        table = np.asarray([6, 7], np.int32)          # exact cover of 16
+        pos = m + np.arange(sl)
+        blk = np.zeros(C, np.int32)                   # pad rows -> block 0
+        off = np.zeros(C, np.int32)
+        blk[:sl], off[:sl] = table[pos // bs], pos % bs
+        tokens = np.zeros((1, C), np.int32)
+        tokens[0, :sl] = _rng.integers(1, cfg.vocab_size, sl)
+        sc = {"k_scale": pools[2], "v_scale": pools[3]} if quantized else {}
+        lg, *out = paged_prefill_chunked(
+            params, jnp.asarray(tokens), jnp.asarray(m, jnp.int32),
+            jnp.asarray([sl - 1], jnp.int32), pools[0], pools[1],
+            jnp.asarray(table[None]), jnp.asarray(blk), jnp.asarray(off),
+            cfg, **sc)
+        assert lg.shape[0] == 1 and len(out) == len(pools)
+        self._check(cfg, pools, out, list(zip(blk[:sl].tolist(),
+                                              off[:sl].tolist())))
 
 
 class TestContinuousEngine:
