@@ -53,20 +53,15 @@ def _mode_kernels() -> str:
 # and the committed full record to cross-reference (file, key) if any.
 def _entries():
     from benchmarks import (autotune_bench, decode_paged_bench, fleet_bench,
-                            kv_int8_bench, prefill_paged_bench,
-                            prefix_cache_bench, resilience_bench,
-                            restore_bench, serve_throughput)
+                            kv_int8_bench, prefix_cache_bench,
+                            resilience_bench, restore_bench,
+                            serve_throughput)
     return {
         "decode_paged": {
             "run": lambda: decode_paged_bench.main(["--smoke"]),
             "metric": "ratio_per_head_over_grouped",
             "mode": _mode_kernels, "kind": "timing",
             "full": ("BENCH_decode.json", "ratio_per_head_over_grouped")},
-        "prefill_paged": {
-            "run": lambda: prefill_paged_bench.main(["--smoke"]),
-            "metric": "ratio_dense_over_chunked",
-            "mode": lambda: _mode_backend("measured"), "kind": "timing",
-            "full": ("BENCH_prefill.json", "ratio_dense_over_chunked")},
         "kv_int8": {
             "run": lambda: kv_int8_bench.main(["--smoke"]),
             "metric": "tok_s_ratio_int8_over_bf16",

@@ -30,10 +30,10 @@ Two measurements:
    amortizes on hardware (the JSON records which mode produced the
    number). The modeled per-token gather traffic (the serve/README DMA
    math) is reported alongside as the hardware-side view.
-2. **Engine-level greedy equality**: one-shot (cold + cached/COW-fork) and
-   chunked engines at baseline and at tiled/split grid settings, bf16 and
-   int8, must produce identical token streams per dtype — the grid knobs
-   are layout, not math.
+2. **Engine-level greedy equality**: whole-prompt chunks (cold +
+   cached/COW-fork) and 16-token chunks at baseline and at tiled/split
+   grid settings, bf16 and int8, must produce identical token streams
+   per dtype — the grid knobs are layout, not math.
 
 Full mode writes ``BENCH_decode.json`` (repo root) for the perf
 trajectory. Prints ``decode_paged_bench,...`` CSV lines, last one the
@@ -135,9 +135,9 @@ def _time_kernels(args, rng):
 
 
 def _engine_equality(args, rng):
-    """Five serving paths (one-shot cold, one-shot cached incl. COW fork
-    and rehit, chunked), baseline vs tiled/split grids, bf16 + int8:
-    greedy streams must be identical per dtype."""
+    """Five serving paths (whole-prompt chunks cold, cached incl. COW
+    fork and rehit, 16-token chunks), baseline vs tiled/split grids,
+    bf16 + int8: greedy streams must be identical per dtype."""
     import jax
     from repro.models.registry import get_config, model_fns, reduce_config
     from repro.serve import ContinuousEngine

@@ -70,11 +70,23 @@ def _serve_once(eng, prompts, max_new):
 
 
 def _prefill_logits(cfg, params, tokens, quantize):
+    """Last-token logits of one prompt prefilled as one chunk through
+    ``paged_prefill_chunked`` into a fresh pool: int8 when ``quantize``,
+    else the model's own precision."""
     import jax.numpy as jnp
-    from repro.serve.paged_step import paged_prefill
-    t = jnp.asarray(np.asarray(tokens, np.int32)[None])
-    last = jnp.asarray([t.shape[1] - 1], jnp.int32)
-    lg, _, _ = paged_prefill(params, t, last, cfg, kv_quantize=quantize)
+    from repro.serve.kv_pool import PagedKVCache
+    from repro.serve.paged_step import paged_prefill_chunked
+    n, bs = len(tokens), 16
+    nb = -(-n // bs)
+    pool = PagedKVCache(cfg, nb, bs, kv_dtype="int8" if quantize else "auto")
+    table = np.arange(1, nb + 1, dtype=np.int32)   # block 0: garbage
+    pos = np.arange(n)
+    lg = paged_prefill_chunked(
+        params, jnp.asarray(np.asarray(tokens, np.int32)[None]),
+        jnp.asarray(0, jnp.int32), jnp.asarray([n - 1], jnp.int32), pool.k,
+        pool.v, jnp.asarray(table[None]), jnp.asarray(table[pos // bs]),
+        jnp.asarray(pos % bs, jnp.int32), cfg, k_scale=pool.k_scale,
+        v_scale=pool.v_scale)[0]
     return np.asarray(lg[0, :cfg.vocab_size])
 
 

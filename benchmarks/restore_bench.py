@@ -128,9 +128,13 @@ def fresh_batch(arrivals, vocab: int, seed: int, n: int) -> List[np.ndarray]:
 def make_factory(cfg, params, case: str, max_new: int,
                  prefill_chunk: Optional[int] = None):
     from repro.serve import ContinuousEngine, EngineGuard
+    from repro.serve.engine import PREFILL_CHUNK as ENGINE_CHUNK
 
     if prefill_chunk is None:
-        prefill_chunk = PREFILL_CHUNK if case == "midprefill" else 0
+        # a mid-prefill kill needs several chunks a prompt; the other
+        # cases keep the engine's chunk, which takes each prompt whole
+        prefill_chunk = PREFILL_CHUNK if case == "midprefill" \
+            else ENGINE_CHUNK
 
     def factory():
         eng = ContinuousEngine(
@@ -448,8 +452,8 @@ def main(argv=None) -> float:
     # prompts would be a bogus probe — their recompute republishes the
     # stems chunk-by-chunk on the cold path too.)  The measurement
     # engines prefill chunked so first-token latency counts supervision
-    # ticks per stem chunk; that's legal against the unchunked child's
-    # snapshot because the fingerprint covers state geometry, not
+    # ticks per stem chunk; that's legal against the child's snapshot,
+    # taken at another chunk size, because the fingerprint covers state geometry, not
     # serving policy, and greedy streams are chunk-invariant.  Both
     # TTFT-in-ticks and prefill-token counts are deterministic — no
     # timing-noise retries needed.

@@ -52,10 +52,6 @@ def main() -> None:
     from benchmarks import prefix_cache_bench
     prefix_cache_bench.main(["--smoke"] if args.fast else [])
 
-    print("# Chunked prefill — flash-prefill kernel vs dense one-shot")
-    from benchmarks import prefill_paged_bench
-    prefill_paged_bench.main(["--smoke"] if args.fast else [])
-
     print("# Int8 KV pool — equal-HBM capacity + throughput vs bf16")
     from benchmarks import kv_int8_bench
     kv_int8_bench.main(["--smoke"] if args.fast else [])

@@ -25,6 +25,7 @@ from repro.models.registry import (GRID_ARCHS, get_config, model_fns,
                                    reduce_config)
 from repro.parallel.sharding import SERVE_RULES, sharding_context
 from repro.serve import ContinuousEngine, ServeEngine
+from repro.serve.engine import PREFILL_CHUNK
 from repro.utils.logging import get_logger
 
 log = get_logger("launch.serve")
@@ -170,13 +171,12 @@ def main() -> None:
     ap.add_argument("--evict-policy", choices=("lru", "fifo"), default="lru",
                     help="prefix cache: order in which unreferenced cached "
                          "blocks are reclaimed")
-    ap.add_argument("--prefill-chunk", type=int, default=0,
-                    help="paged engine: prefill long prompts this many "
-                         "tokens per step through the flash-prefill kernel "
+    ap.add_argument("--prefill-chunk", type=int, default=PREFILL_CHUNK,
+                    help="paged engine: prefill prompts this many tokens "
+                         "per step through the flash-prefill kernel "
                          "(rounded up to a block multiple; chunks "
                          "interleave with decode steps so long prompts "
-                         "don't stall running requests; 0 = one-shot "
-                         "prefill)")
+                         "don't stall running requests)")
     ap.add_argument("--prefill-budget", type=int, default=0,
                     help="paged engine: cap the TOTAL prefill chunk tokens "
                          "dealt per step across all requests (the oldest "
@@ -414,11 +414,10 @@ def main() -> None:
                      eng.metrics.peak_blocks,
                      100.0 * eng.metrics.peak_blocks / args.num_blocks,
                      args.num_blocks, eng.metrics.preemptions)
-            if args.prefill_chunk:
-                log.info("chunked prefill[%d]: %d chunks over %d prefills "
-                         "(%d prompt tokens computed)",
-                         eng.prefill_chunk, eng.metrics.prefill_chunks,
-                         eng.metrics.prefills, eng.metrics.prefill_tokens)
+            log.info("chunked prefill[%d]: %d chunks over %d prefills "
+                     "(%d prompt tokens computed)",
+                     eng.prefill_chunk, eng.metrics.prefill_chunks,
+                     eng.metrics.prefills, eng.metrics.prefill_tokens)
             if eng.prefix_cache is not None:
                 cs = eng.prefix_cache.stats
                 log.info("prefix cache[%s]: hit %d/%d prompt tokens "

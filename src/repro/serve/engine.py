@@ -24,13 +24,13 @@ Two engines share the model zoo and the softermax sampling head:
   charges only the uncached suffix, prefill runs offset-aware from the
   first uncached token, and finished requests release their prompt blocks
   — and their drained generated tokens — back to the tree, so multi-turn
-  conversations readmit as near-full hits. With ``prefill_chunk > 0`` long
-  prompts prefill in fixed-size chunks through the flash-prefill kernel
-  (``kernels/flash_prefill_paged``): one chunk per request per step,
-  interleaved with decode steps (``prefill_budget`` caps the *total* chunk
-  tokens dealt per step across requests), each chunk attending the cached
-  prefix and every earlier chunk directly out of the pool — no quadratic
-  one-shot score matrix, no per-layer prefix gather. With
+  conversations readmit as near-full hits. Prompts prefill in chunks of
+  ``prefill_chunk`` tokens through the flash-prefill kernel
+  (``kernels/flash_prefill_paged``), the one prefill path: one chunk per
+  request per step, interleaved with decode steps (``prefill_budget`` caps
+  the *total* chunk tokens dealt per step across requests), each chunk
+  attending the cached prefix and every earlier chunk directly out of the
+  pool — no quadratic score matrix, no per-layer prefix gather. With
   ``kv_dtype="int8"`` (the default when ``cfg.opt_int8_kv`` is set) the
   pool stores K/V as int8 with per-row scales — half the gather bytes,
   ~2x the tokens at equal HBM — quantizing on scatter and dequantizing
@@ -67,14 +67,15 @@ from repro.serve.guard import (EngineGuard, EngineSheddingError,
 from repro.serve.kernel_costs import decode_launch_cost, prefill_launch_cost
 from repro.serve.kv_pool import PagedKVCache, PoolExhausted
 from repro.serve.paged_step import (check_paged_support, paged_decode_step,
-                                    paged_prefill, paged_prefill_chunked,
-                                    paged_prefill_suffix, scale_kwargs,
-                                    scatter_prefill, scatter_prefill_offset,
+                                    paged_prefill_chunked, scale_kwargs,
                                     table_width_bucket)
 from repro.serve.radix_cache import RadixCache
 from repro.serve.scheduler import (FINISH_DEADLINE, FINISH_QUARANTINED,
                                    PREFILL, Request, Scheduler)
 from repro.serve.telemetry import Telemetry, span
+
+# prompt tokens a prefill chunk computes when the caller names no size
+PREFILL_CHUNK = 256
 
 
 def _current_device():
@@ -210,7 +211,8 @@ class ContinuousEngine:
                  max_batch: int = 8, max_len: int = 512,
                  max_admit_per_step: int = 2, seed: int = 0,
                  prefix_cache: bool = True, evict_policy: str = "lru",
-                 prefill_chunk: int = 0, prefill_budget: int = 0,
+                 prefill_chunk: int = PREFILL_CHUNK,
+                 prefill_budget: int = 0,
                  kv_dtype: Optional[str] = None,
                  kv_tile_blocks: Optional[int] = None,
                  decode_split_k: int = 1,
@@ -245,17 +247,17 @@ class ContinuousEngine:
         self.max_batch = max_batch
         self.max_len = max_len
         self.max_admit_per_step = max_admit_per_step
-        # Chunked prefill: long prompts are computed ``prefill_chunk``
-        # tokens at a time through the flash-prefill kernel (one chunk per
-        # prefilling request per step, interleaved with decode steps).
-        # 0 disables it — prompts prefill in one shot as before. The chunk
-        # is rounded up to a block multiple so chunk boundaries and block
-        # boundaries line up and every non-final chunk scatters whole rows.
-        if prefill_chunk < 0:
-            raise ValueError(f"prefill_chunk must be >= 0, "
+        # Prompts are computed ``prefill_chunk`` tokens at a time through
+        # the flash-prefill kernel (one chunk per prefilling request per
+        # step, interleaved with decode steps). The chunk is rounded up to
+        # a block multiple so chunk boundaries and block boundaries line
+        # up and every non-final chunk scatters whole rows, and is cut to
+        # ``max_len`` rounded up the same way: no prompt needs more.
+        if prefill_chunk <= 0:
+            raise ValueError(f"prefill_chunk must be > 0, "
                              f"got {prefill_chunk}")
-        self.prefill_chunk = (-(-prefill_chunk // block_size) * block_size
-                              if prefill_chunk else 0)
+        self.prefill_chunk = -(-min(prefill_chunk, max_len) // block_size) \
+            * block_size
         # Prefill token budget per step: caps the TOTAL chunk tokens dealt
         # across requests each step (not one chunk per request), so a herd
         # of concurrent long prompts can't crowd decode steps out. 0 = no
@@ -374,11 +376,6 @@ class ContinuousEngine:
         def _amax(lg):
             return jnp.argmax(lg[:, :cfg.vocab_size], -1).astype(jnp.int32)
 
-        def _prefill_fn(p, t, lp):
-            lg, ks, vs = paged_prefill(p, t, lp, cfg,
-                                       kv_quantize=self.quantized)
-            return _amax(lg), lg, ks, vs
-
         # grid knobs are trace-time constants: static kwargs of the jit,
         # so the per-step planner can swap grids without retracing tricks
         # — each (tile, split, table-width) lands in its own cache entry,
@@ -390,26 +387,12 @@ class ContinuousEngine:
                                     **scale_kwargs(pools))
             return (_amax(out[0]), out[0]) + tuple(out[1:])
 
-        def _prefill_suffix_fn(p, t, pos0, last_rel, pt, pl, *pools):
-            lg, ks, vs = paged_prefill_suffix(p, t, pos0, last_rel,
-                                              pools[0], pools[1], pt, pl,
-                                              cfg, **scale_kwargs(pools))
-            return _amax(lg), lg, ks, vs
-
         def _prefill_chunk_fn(p, t, pos0, last_rel, pt, blk, off, *pools):
             out = paged_prefill_chunked(p, t, pos0, last_rel, pools[0],
                                         pools[1], pt, blk, off, cfg,
                                         kv_tile_blocks=self._prefill_tile,
                                         **scale_kwargs(pools))
             return (_amax(out[0]), out[0]) + tuple(out[1:])
-
-        def _scatter_fn(ks, vs, block_ids, *pools):
-            return scatter_prefill(pools[0], pools[1], ks, vs, block_ids,
-                                   **scale_kwargs(pools))
-
-        def _scatter_off_fn(ks, vs, blk, off, *pools):
-            return scatter_prefill_offset(pools[0], pools[1], ks, vs, blk,
-                                          off, **scale_kwargs(pools))
 
         # On accelerators, donate the pools: they are rebound to the returned
         # arrays every call, so the update aliases in-place instead of
@@ -420,13 +403,8 @@ class ContinuousEngine:
                 return ()
             return tuple(range(first, first + np_))
 
-        self._prefill = jax.jit(_prefill_fn)
-        self._prefill_suffix = jax.jit(_prefill_suffix_fn)
         self._prefill_chunk_fn = jax.jit(_prefill_chunk_fn,
                                          donate_argnums=_donate(7))
-        self._scatter = jax.jit(_scatter_fn, donate_argnums=_donate(3))
-        self._scatter_off = jax.jit(_scatter_off_fn,
-                                    donate_argnums=_donate(4))
         self._decode = jax.jit(_decode_fn, donate_argnums=_donate(4),
                                static_argnames=("tile", "split"))
 
@@ -495,14 +473,13 @@ class ContinuousEngine:
 
     def warmup(self) -> None:
         """Take the greedy serving path's compiles out of serving latency:
-        jit shapes first (prefill/scatter per block-count bucket, decode per
-        table-width bucket; writes only into the reserved garbage block),
-        then a synthetic mini-workload through the real submit/step path so
-        the one-time eager-op compiles (token fetches, host→device
-        converts) happen now too; with the prefix cache on, the synthetic
-        prompts share prefixes, so the suffix-prefill/COW path compiles a
-        first set of buckets as well (other suffix shapes compile on first
-        hit at serve time). The cache is flushed afterwards. Temperature-
+        jit shapes first (the chunk program per chunk-table-width bucket,
+        decode per table-width bucket; writes only into the reserved
+        garbage block), then a synthetic mini-workload through the real
+        submit/step path so the one-time eager-op compiles (token fetches,
+        host→device converts) happen now too; with the prefix cache on,
+        the synthetic prompts share prefixes, so the copy-on-write path
+        compiles as well. The cache is flushed afterwards. Temperature-
         sampled requests use eager host-side sampling whose small one-time
         compiles are not covered. Call once before serving traffic."""
         if self.sched.has_work():
@@ -510,36 +487,25 @@ class ContinuousEngine:
                 "warmup() must run before any requests are submitted "
                 "(its synthetic workload would consume and discard them)")
         zeros = jnp.zeros
-        if self.prefill_chunk:
-            # chunked engines never run the one-shot step: compile the
-            # chunk step once per table-width bucket (all writes land in
-            # the reserved garbage block 0; inputs are shape-only — wide
-            # tables with pos0=0 break the split-path table contract, so
-            # outputs are garbage, but they are finite and discarded)
-            C = self.prefill_chunk
-            cq = C // self.block_size
-            # exactly the serve-time bucket set: every cover width any
-            # in-range request can produce, through the one shared policy
-            widths = sorted({table_width_bucket(n, chunk_blocks=cq)
-                             for n in range(1, self.nb_max + 1)})
-            for w in widths:
-                _, _, *pools = self._prefill_chunk_fn(
-                    self.params, zeros((1, C), jnp.int32),
-                    jnp.asarray(0, jnp.int32),
-                    jnp.asarray([C - 1], jnp.int32),
-                    zeros((1, w), jnp.int32),
-                    zeros((C,), jnp.int32), zeros((C,), jnp.int32),
-                    *self._pools())
-                self._set_pools(pools)
-        else:
-            for nb in range(1, self.nb_max + 1):
-                Sp = nb * self.block_size
-                _, _, ks, vs = self._prefill(
-                    self.params, zeros((1, Sp), jnp.int32),
-                    jnp.asarray([Sp - 1], jnp.int32))
-                self._set_pools(self._scatter(ks, vs,
-                                              zeros((nb,), jnp.int32),
-                                              *self._pools()))
+        # the chunk step once per table-width bucket (all writes land in
+        # the reserved garbage block 0; inputs are shape-only — wide
+        # tables with pos0=0 break the split-path table contract, so
+        # outputs are garbage, but they are finite and discarded)
+        C = self.prefill_chunk
+        cq = C // self.block_size
+        # exactly the serve-time bucket set: every cover width any
+        # in-range request can produce, through the one shared policy
+        widths = sorted({table_width_bucket(n, chunk_blocks=cq)
+                         for n in range(1, self.nb_max + 1)})
+        for w in widths:
+            _, _, *pools = self._prefill_chunk_fn(
+                self.params, zeros((1, C), jnp.int32),
+                jnp.asarray(0, jnp.int32),
+                jnp.asarray([C - 1], jnp.int32),
+                zeros((1, w), jnp.int32),
+                zeros((C,), jnp.int32), zeros((C,), jnp.int32),
+                *self._pools())
+            self._set_pools(pools)
         # per-step autotuning picks among these exact entries at serve
         # time, so the whole candidate × width grid compiles here — the
         # planner never triggers a mid-serve compile
@@ -610,8 +576,8 @@ class ContinuousEngine:
 
     def step(self) -> Dict[int, List[int]]:
         """Advance the world one iteration: admit+prefill (one *chunk* per
-        prefilling request when chunked prefill is on — long prompts no
-        longer stall in-flight decodes), join, one fused decode step,
+        prefilling request — long prompts never stall in-flight decodes),
+        join, one fused decode step,
         evict. Returns {req_id: fresh tokens} — temperature-sampled tokens
         appear here each step; greedy tokens normally stay on device until
         ``drain()`` (``run(on_token=...)`` drains every step for
@@ -645,17 +611,13 @@ class ContinuousEngine:
             if tel is not None:
                 for req in admitted:
                     tel.on_admit(req)
-            # chunked: admitted requests stay PREFILL; prefilling
-            # requests advance one chunk each, oldest first, until the
-            # per-step prefill token budget (if any) is spent — decodes
-            # keep their share of every step even under a herd of long
-            # prompts
-            todo = (self.sched.chunk_schedule(self.prefill_chunk, budget)
-                    if self.prefill_chunk else admitted)
-        prefill = (self._do_prefill_chunk if self.prefill_chunk
-                   else self._do_prefill)
+            # admitted requests stay PREFILL; prefilling requests advance
+            # one chunk each, oldest first, until the per-step prefill
+            # token budget (if any) is spent — decodes keep their share of
+            # every step even under a herd of long prompts
+            todo = self.sched.chunk_schedule(self.prefill_chunk, budget)
         for req in todo:
-            prefill(req, events)
+            self._do_prefill_chunk(req, events)
         self._drain_if_finishing(events)
         self._evict_finished(tel)                # max_new == 1 requests
 
@@ -846,28 +808,31 @@ class ContinuousEngine:
 
     def _readback_audit(self, req: Request, lg) -> float:
         """Scatter-readback KV-integrity audit: recompute the final prompt
-        token's logits READING the just-scattered blocks out of the pool
-        (1-token suffix prefill) and compare against the prefill's own
-        final logits. Clean pools agree to within quantization error;
-        corrupted blocks produce a large delta → quarantine. Returns the
-        max-abs logit delta (0.0 when the prompt is too short to audit)."""
+        token's logits as a one-token chunk that READS every prompt row,
+        its own included, out of the just-written blocks, and compare
+        against the prefill's own final logits. Clean pools agree to
+        within rounding; corrupted blocks produce a large delta →
+        quarantine. Returns the max-abs logit delta (0.0 when the prompt
+        is too short to audit)."""
         plen = req.prompt_len
         m = plen - 1
         if m < 1:
             return 0.0
-        bs = self.block_size
-        tokens = np.zeros((1, bs), np.int32)
+        bs, C = self.block_size, self.prefill_chunk
+        tokens = np.zeros((1, C), np.int32)
         tokens[0, 0] = req.prompt[m]
-        table = np.asarray(self.pool.blocks_of(req.req_id), np.int32)
-        nb_p = -(-m // bs)
-        w = self._pow2_bucket(nb_p)
-        pt = np.zeros((1, w), np.int32)
-        pt[0, :nb_p] = table[:nb_p]
-        _, lg2, _ks, _vs = self._prefill_suffix(
+        cover = -(-plen // bs)
+        pt = np.zeros((1, table_width_bucket(cover, chunk_blocks=C // bs)),
+                      np.int32)
+        pt[0, :cover] = self.pool.blocks_of(req.req_id)[:cover]
+        # every row's write goes to garbage block 0: the audit leaves the
+        # request's blocks as they are
+        rows = jnp.zeros((C,), jnp.int32)
+        _, lg2, *pools = self._prefill_chunk_fn(
             self.params, jnp.asarray(tokens), jnp.asarray(m, jnp.int32),
-            jnp.asarray([0], jnp.int32), jnp.asarray(pt),
-            jnp.asarray([m], jnp.int32), *self._pools())
-        # readback only — the recomputed K/V rows are NOT scattered
+            jnp.asarray([0], jnp.int32), jnp.asarray(pt), rows, rows,
+            *self._pools())
+        self._set_pools(pools)
         V = self.cfg.vocab_size
         err = float(jnp.max(jnp.abs(lg2[:, :V] - lg[:, :V])))
         self.metrics.readback_audits += 1
@@ -987,79 +952,6 @@ class ContinuousEngine:
         self._key, sub = jax.random.split(self._key)
         return sub
 
-    def _prefill_full(self, req: Request):
-        """Cold prefill: the whole prompt through ``paged_prefill``, K/V
-        scattered block-aligned into the request's (all-fresh) blocks."""
-        bs = self.block_size
-        plen = req.prompt_len
-        Sp = -(-plen // bs) * bs
-        tokens = np.zeros((1, Sp), np.int32)
-        tokens[0, :plen] = req.prompt
-        greedy, lg, ks, vs = self._prefill(self.params, jnp.asarray(tokens),
-                                           jnp.asarray([plen - 1], jnp.int32))
-        blocks = jnp.asarray(self.pool.blocks_of(req.req_id), jnp.int32)
-        self._set_pools(self._scatter(ks, vs, blocks, *self._pools()))
-        return greedy, lg
-
-    def _prefill_from_offset(self, req: Request, m: int):
-        """Prefix-cache hit: only the uncached suffix (positions ``m..``)
-        runs through the model; attention reads the shared prefix blocks
-        out of the pool, and the suffix K/V rows scatter to per-row
-        (block, offset) targets — the first may sit mid-block after a
-        copy-on-write tail splice. Pad rows route to garbage block 0."""
-        bs = self.block_size
-        plen = req.prompt_len
-        sl = plen - m
-        Sp = -(-sl // bs) * bs
-        tokens = np.zeros((1, Sp), np.int32)
-        tokens[0, :sl] = req.prompt[m:]
-        table = np.asarray(self.pool.blocks_of(req.req_id), np.int32)
-        nb_p = -(-m // bs)               # prefix blocks incl. the COW tail
-        w = self._pow2_bucket(nb_p)
-        pt = np.zeros((1, w), np.int32)
-        pt[0, :nb_p] = table[:nb_p]
-        pos = m + np.arange(Sp)
-        blk = np.zeros((Sp,), np.int32)
-        off = np.zeros((Sp,), np.int32)
-        blk[:sl] = table[pos[:sl] // bs]
-        off[:sl] = pos[:sl] % bs
-        greedy, lg, ks, vs = self._prefill_suffix(
-            self.params, jnp.asarray(tokens), jnp.asarray(m, jnp.int32),
-            jnp.asarray([sl - 1], jnp.int32), jnp.asarray(pt),
-            jnp.asarray([m], jnp.int32), *self._pools())
-        self._set_pools(self._scatter_off(ks, vs, jnp.asarray(blk),
-                                          jnp.asarray(off), *self._pools()))
-        return greedy, lg
-
-    def _do_prefill(self, req: Request, events: Dict[int, List[int]]) -> None:
-        tel = self.telemetry
-        t = self._clock() if tel is not None else 0.0
-        plen = req.prompt_len
-        m = req.n_prefix_hit
-        width = self._pow2_bucket(-(-plen // self.block_size))
-        with span("serve.prefill", req=req.req_id, tokens=plen - m,
-                  width=width):
-            if m > 0:
-                greedy, lg = self._prefill_from_offset(req, m)
-            else:
-                greedy, lg = self._prefill_full(req)
-            req.n_prefilled = plen
-            self.metrics.prefill_tokens += plen - m
-            self.metrics.prefix_hit_tokens += m
-            if tel is not None:
-                tel.on_prefill(req, "prefill-suffix" if m > 0 else "prefill",
-                               plen - m, width, t, self._clock() - t)
-            if self.faults is not None and self.faults.take_kv_corrupt():
-                self._corrupt_request_blocks(req)      # bad scatter, post hoc
-            self._join_decode(req, greedy, lg, events)
-            if tel is not None:
-                probe = tel.maybe_numerics_probe(self, req)
-                if probe:
-                    self._step_logit_err = max(
-                        self._step_logit_err,
-                        float(probe.get("logit_error", 0.0)))
-            self._audit_and_quarantine(req, lg)
-
     def _do_prefill_chunk(self, req: Request,
                           events: Dict[int, List[int]]) -> None:
         """Advance one prefilling request by one chunk: compute + scatter
@@ -1108,8 +1000,7 @@ class ContinuousEngine:
                     head_dim=self.cfg.head_dim, block_size=self.block_size,
                     kv_tile_blocks=self._prefill_tile,
                     kv_dtype=self.pool.kv_dtype)
-                tel.on_prefill(req, "prefill-chunk", sl, w, t,
-                               self._clock() - t, cost=cost,
+                tel.on_prefill(req, sl, w, t, self._clock() - t, cost=cost,
                                launches=self.cfg.n_layers)
             if req.n_prefilled == req.prompt_len:
                 if self.faults is not None and self.faults.take_kv_corrupt():
@@ -1172,7 +1063,7 @@ class ContinuousEngine:
             self.telemetry.on_first_token(req)
 
     def _pow2_bucket(self, need: int) -> int:
-        """Decode/suffix table width via the stack-wide bucketing policy
+        """Decode table width via the stack-wide bucketing policy
         (``serve/paged_step.table_width_bucket``)."""
         return table_width_bucket(need, nb_max=self.nb_max)
 

@@ -81,7 +81,7 @@ class GuardConfig:
     prefill_budget_factor: float = 0.5
     max_admit_factor: float = 0.5
     # run the scatter-readback KV-integrity audit after each completed
-    # prefill (the quarantine detector; costs one 1-token suffix prefill)
+    # prefill (the quarantine detector; costs one one-token chunk)
     readback_audit: bool = True
 
 

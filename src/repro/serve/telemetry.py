@@ -7,10 +7,10 @@ what makes the disabled mode free). When attached, the engine calls the
 ``on_*`` hooks at the lifecycle points below; everything else here is
 host-side bookkeeping — no device work happens in any hook.
 
-    submit ──► admit ──► prefill[-chunk|-suffix]* ──► first token ──►
+    submit ──► admit ──► prefill-chunk* ──► first token ──►
       decode token* ──► finish
-                 ▲                                        │
-                 └──────────────── preempt ◄──────────────┘
+                 ▲                              │
+                 └─────────── preempt ◄─────────┘
 
 **Per-request tracing** (``RequestTrace``): monotonic timestamps for every
 lifecycle edge, queue-wait (submit→admit), TTFT (submit→first token), TPOT
@@ -20,7 +20,7 @@ without per-sample storage); the full per-token event list is kept only on
 the traced requests themselves and is bounded by ``max_new``.
 
 **Step timeline** (``StepTimeline``): one Chrome trace-event record per
-engine phase — prefill/prefill-chunk/prefill-suffix/decode/drain — with
+engine phase — prefill-chunk/decode/drain — with
 batch rows, the table-width bucket chosen, the split-K/tile grid knobs,
 and host↔device sync duration in the args. ``save_chrome_trace`` writes
 the standard ``{"traceEvents": [...]}`` JSON that chrome://tracing and
@@ -32,7 +32,7 @@ instants land on tid = req_id so Perfetto shows one lane per request.
 
 **Profiler spans** (``span``): independent of any ``Telemetry``, the engine
 wraps its phases in ``jax.profiler`` annotations (``serve.step``,
-``serve.admit``, ``serve.prefill_chunk``/``serve.prefill``,
+``serve.admit``, ``serve.prefill_chunk``,
 ``serve.decode``, ``serve.sync``, ``serve.evict``). A running
 ``jax.profiler`` trace records them on the host line, on the same clock as
 the device's operations; with no trace running each costs about a
@@ -338,18 +338,17 @@ class Telemetry:
         self._mark(req, "readmit" if req.n_preemptions else "admit",
                    req.t_admit)
 
-    def on_prefill(self, req, kind: str, n_tokens: int, table_width: int,
+    def on_prefill(self, req, n_tokens: int, table_width: int,
                    t_start: float, dur: float, cost=None,
                    launches: int = 1) -> None:
-        """kind: "prefill" (one-shot cold), "prefill-suffix" (cache hit),
-        or "prefill-chunk". ``cost`` is the per-launch ``LaunchCost`` when
-        the phase ran a paged kernel (chunked prefill); ``launches`` is
-        kernel launches in the phase (= model layers)."""
+        """One prefill chunk of ``req`` (phase "prefill-chunk"). ``cost``
+        is the per-launch ``LaunchCost`` of its paged-prefill kernel;
+        ``launches`` is kernel launches in the phase (= model layers)."""
+        kind = "prefill-chunk"
         tr = self._trace(req)
         if tr is not None:
             tr.events.append((kind, t_start))
-            if kind == "prefill-chunk":
-                tr.prefill_chunks += 1
+            tr.prefill_chunks += 1
         extra = self.on_kernel_launch(kind, cost, launches)
         if self.timeline is not None:
             self.timeline.complete(kind, t_start, dur,

@@ -495,8 +495,9 @@ def _serve(cfg, params, prompts, max_new=6, **kw):
 
 class TestEngineGridSettings:
     def test_greedy_identical_across_tile_split_settings(self, setup):
-        """Tile/split are layout knobs: one-shot, chunked, and cached
-        engines produce identical greedy streams at any setting."""
+        """Tile/split are layout knobs: whole-prompt-chunk, small-chunk,
+        and cached engines produce identical greedy streams at any
+        setting."""
         cfg, params = setup
         shared = _rng.integers(1, cfg.vocab_size, (21,)).astype(np.int32)
         prompts = [np.concatenate(

@@ -47,7 +47,7 @@ def _steps(eng):
 
 
 class TestCounters:
-    @pytest.mark.parametrize("chunk", [0, 8])
+    @pytest.mark.parametrize("chunk", [48, 8])     # whole prompts; several
     def test_forced_syncs_one_per_finishing_step(self, setup, chunk):
         cfg, params = setup
         eng = _engine(cfg, params, prefill_chunk=chunk)
@@ -172,7 +172,7 @@ def _host_spans(log_dir):
 
 
 class TestSpans:
-    @pytest.mark.parametrize("chunk", [0, 8])
+    @pytest.mark.parametrize("chunk", [48, 8])     # whole prompts; several
     def test_phases_on_the_profilers_clock(self, setup, tmp_path, chunk):
         cfg, params = setup
         eng = _engine(cfg, params, prefill_chunk=chunk)
@@ -185,7 +185,7 @@ class TestSpans:
             jax.profiler.stop_trace()
         spans = _host_spans(str(tmp_path))
         names = {s[0] for s in spans}
-        prefill = "serve.prefill_chunk" if chunk else "serve.prefill"
+        prefill = "serve.prefill_chunk"
         assert {"serve.step", "serve.admit", prefill, "serve.decode",
                 "serve.sync", "serve.evict"} <= names
         steps = [s for s in spans if s[0] == "serve.step"]

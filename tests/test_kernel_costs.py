@@ -310,10 +310,11 @@ class TestEngineIntegration:
         for e in decode_evs:
             assert e["args"]["dma_bytes"] > 0
             assert e["args"]["flops"] > 0
-        # counter totals == sum over timeline-stamped phases (all phases
-        # that ran a paged kernel are decode slices in this one-shot
-        # prefill engine)
-        assert sum(e["args"]["dma_bytes"] for e in decode_evs) == dma
+        # counter totals == sum over the timeline-stamped phases that ran
+        # a paged kernel: the decode and prefill-chunk slices
+        kernel_evs = [e for e in tel.timeline.events
+                      if e["name"] in ("decode", "prefill-chunk")]
+        assert sum(e["args"]["dma_bytes"] for e in kernel_evs) == dma
 
     def test_engine_decode_cost_matches_direct_model(self, setup):
         cfg, params = setup

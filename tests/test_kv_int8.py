@@ -1,6 +1,6 @@
 """Int8 quantized paged KV pool: quantize/dequantize roundtrip properties,
 fused dequant-on-gather kernel parity, scales traveling with shared/copied
-blocks, engine equality through decode / one-shot suffix prefill / chunked
+blocks, engine equality through decode / whole-prompt and small-chunk
 prefill / COW fork / prefix-cache rehit, and the per-step prefill token
 budget + partial-tail publishing satellites."""
 import jax
@@ -19,7 +19,7 @@ from repro.models.attention import dequantize_kv, quantize_kv
 from repro.models.registry import get_config, model_fns, reduce_config
 from repro.serve import ContinuousEngine, PagedKVCache
 from repro.serve.kv_pool import KV_DTYPES
-from repro.serve.paged_step import paged_prefill, scatter_prefill
+from repro.serve.paged_step import _write_rows, paged_prefill_chunked
 from repro.serve.scheduler import Scheduler
 
 _rng = np.random.default_rng(31)
@@ -102,16 +102,18 @@ class TestQuantizeRoundtrip:
 
 
 def _fill_block(pool, block, seed):
-    """Scatter one block of random K/V rows (quantize-on-scatter)."""
+    """Write one block of random K/V rows in every layer through the
+    serving steps' row writer (quantize-on-scatter)."""
     cfg = pool.cfg
     rng = np.random.default_rng(seed)
-    L, Hkv, BS, Dh = (cfg.n_layers, cfg.n_kv_heads, pool.block_size,
-                      cfg.head_dim_)
-    ks = jnp.asarray(rng.normal(size=(L, 1, Hkv, BS, Dh)), jnp.float32)
-    vs = jnp.asarray(rng.normal(size=(L, 1, Hkv, BS, Dh)), jnp.float32)
-    pool.k, pool.v, pool.k_scale, pool.v_scale = scatter_prefill(
-        pool.k, pool.v, ks, vs, jnp.asarray([block], jnp.int32),
-        pool.k_scale, pool.v_scale)
+    Hkv, BS, Dh = cfg.n_kv_heads, pool.block_size, cfg.head_dim_
+    pools = (pool.k, pool.v, pool.k_scale, pool.v_scale)
+    blk = jnp.full((BS,), block, jnp.int32)
+    for layer in range(cfg.n_layers):
+        k, v = (jnp.asarray(rng.normal(size=(BS, Hkv, Dh)), jnp.float32)
+                for _ in range(2))
+        pools = _write_rows(pools, layer, blk, jnp.arange(BS), k, v)
+    pool.k, pool.v, pool.k_scale, pool.v_scale = pools
 
 
 class TestInt8Pool:
@@ -266,6 +268,22 @@ class TestInt8KernelParity:
 # ---------------------------------------------------------------------------
 
 
+def _prefill_logits(cfg, params, tokens, kv_dtype):
+    """Last-token logits of one prompt prefilled as one chunk through
+    ``paged_prefill_chunked`` into a fresh pool of ``kv_dtype``."""
+    n, bs = tokens.shape[1], 8
+    nb = -(-n // bs)
+    pool = PagedKVCache(cfg, nb, bs, kv_dtype=kv_dtype)
+    table = np.arange(1, nb + 1, dtype=np.int32)     # block 0: garbage
+    pos = np.arange(n)
+    return np.asarray(paged_prefill_chunked(
+        params, tokens, jnp.asarray(0, jnp.int32),
+        jnp.asarray([n - 1], jnp.int32), pool.k, pool.v,
+        jnp.asarray(table[None]), jnp.asarray(table[pos // bs]),
+        jnp.asarray(pos % bs, jnp.int32), cfg, k_scale=pool.k_scale,
+        v_scale=pool.v_scale)[0])
+
+
 def _run(cfg, params, prompts, max_new=6, **kw):
     kw.setdefault("block_size", 8)
     kw.setdefault("num_blocks", 64)
@@ -281,17 +299,16 @@ class TestInt8Engine:
     def test_bounded_logit_error_vs_full_precision(self, setup):
         """The documented accuracy guardrail: per-row int8 storage with
         fp32 accumulation perturbs prefill logits by well under 0.05 on
-        the reduced config — greedy outputs only flip where the top-2 gap
-        is inside that noise band."""
+        the reduced config (an int8 pool against one in the model's own
+        precision) — greedy outputs only flip where the top-2 gap is
+        inside that noise band."""
         cfg, params = setup
         for n in (5, 20, 37, 64):
             p = jnp.asarray(
                 _rng.integers(1, cfg.vocab_size, (1, n)), jnp.int32)
-            last = jnp.asarray([n - 1], jnp.int32)
-            lg_f, _, _ = paged_prefill(params, p, last, cfg)
-            lg_q, _, _ = paged_prefill(params, p, last, cfg,
-                                       kv_quantize=True)
-            err = np.abs(np.asarray(lg_f) - np.asarray(lg_q)).max()
+            lg_f = _prefill_logits(cfg, params, p, "auto")
+            lg_q = _prefill_logits(cfg, params, p, "int8")
+            err = np.abs(lg_f - lg_q).max()
             assert err <= 0.05, f"prompt len {n}: logit error {err}"
 
     def test_one_shot_greedy_matches_bf16(self, setup):
@@ -309,10 +326,10 @@ class TestInt8Engine:
         assert eng.metrics.pool_token_capacity == 64 * 8
 
     def test_int8_self_consistent_across_all_paths(self, setup):
-        """Decode, one-shot suffix prefill, chunked prefill, COW fork and
+        """Decode, whole-prompt and 16-token-chunk prefill, COW fork and
         prefix-cache rehit must produce identical greedy streams within
-        int8 mode: every path reads the same quantized codes (fake-quant
-        at dense prefill, quantize-on-scatter elsewhere)."""
+        int8 mode: every path reads the same quantized codes (quantize-on-
+        scatter, a chunk attending its own rows through the pool)."""
         cfg, params = setup
         rng = np.random.default_rng(5)
         shared = rng.integers(1, cfg.vocab_size, (21,)).astype(np.int32)

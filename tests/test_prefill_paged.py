@@ -1,7 +1,8 @@
-"""Paged chunked prefill: kernel vs oracle parity, equivalence with the
-dense one-shot suffix path, engine-level greedy equality across chunk
-boundaries, decode/prefill interleaving, and multi-turn generated-token
-reuse."""
+"""Paged chunked prefill, the one prefill path: kernel vs oracle parity,
+chunked walks against one whole-prompt chunk and against the contiguous
+``models/lm.py`` forward, decode as a one-token chunk, engine-level greedy
+equality across chunk sizes and with the prefix cache on or off,
+decode/prefill interleaving, and multi-turn generated-token reuse."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,12 +13,13 @@ from repro.kernels.flash_prefill_paged import (flash_prefill_paged,
                                                paged_prefill_ref,
                                                paged_prefill_split_ref)
 from repro.models.attention import quantize_kv
+from repro.models.lm import lm_prefill
 from repro.models.registry import get_config, model_fns, reduce_config
 from repro.serve import ContinuousEngine
+from repro.serve.engine import PREFILL_CHUNK
 from repro.serve.kv_pool import PagedKVCache
-from repro.serve.paged_step import (paged_prefill, paged_prefill_chunked,
-                                    paged_prefill_suffix, scatter_prefill,
-                                    scatter_prefill_offset)
+from repro.serve.paged_step import (paged_decode_step, paged_prefill_chunked,
+                                    scale_kwargs)
 
 _rng = np.random.default_rng(23)
 
@@ -126,35 +128,6 @@ class TestFlashPrefillPagedKernel:
         assert not np.array_equal(np.asarray(pairs[0][0]),
                                   np.asarray(other))
 
-    def test_ref_matches_dense_suffix_attention(self):
-        """The single-table positional-causal oracle computes the same
-        attention as PR-2's gather-and-concat ``_suffix_attention`` when
-        the suffix KV is pool-resident."""
-        from repro.serve.paged_step import _suffix_attention
-        B, Hq, Hkv, D, BS, Sq, pos0 = 1, 4, 2, 16, 8, 19, 21
-        W = -(-(pos0 + Sq) // BS)
-        kp, vp, bt = _random_paged_kv(B, Hkv, D, BS, W)
-        q = jnp.asarray(_rng.normal(size=(B, Hq, Sq, D)),
-                        jnp.float32) / np.sqrt(D)
-        got = paged_prefill_ref(q, kp, vp, bt,
-                                jnp.asarray([pos0], jnp.int32))
-        # dense path: gather prefix rows [0, pos0) and suffix rows
-        # [pos0, pos0+Sq) out of the same pool, then concat + mask
-        from repro.kernels.flash_decode_paged.ref import gather_kv
-        kv_all_k = gather_kv(kp, bt)
-        kv_all_v = gather_kv(vp, bt)
-        W_pre = -(-pos0 // BS)          # prefix table incl. partial tail
-        k_pre = gather_kv(kp, bt[:, :W_pre])
-        v_pre = gather_kv(vp, bt[:, :W_pre])
-        k_suf = kv_all_k[:, :, pos0:pos0 + Sq]
-        v_suf = kv_all_v[:, :, pos0:pos0 + Sq]
-        pre_valid = jnp.arange(W_pre * BS)[None, :] < pos0
-        q_pos = pos0 + jnp.arange(Sq)[None, :]
-        want = _suffix_attention(q, k_pre, v_pre, k_suf, v_suf, pre_valid,
-                                 q_pos, intmax=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=1e-5)
-
     @pytest.mark.parametrize("Sq,pos0,pad_to_cq", [
         (16, 24, False),    # exact cover, mid-block-free
         (16, 21, False),    # exact cover, mid-block pos0
@@ -219,78 +192,122 @@ class TestFlashPrefillPagedKernel:
                                    atol=1e-5)
 
 
-class TestChunkedPrefillStep:
-    """Model-level: chunked == one-shot over identical pool state."""
+def _chunk(cfg, params, prompt, m, c, pools, table, bs):
+    """Prefill ``prompt[m:m + c]`` as one chunk at ``pos0 = m`` through
+    the exact-cover table; returns (logits, pools)."""
+    cover = -(-(m + c) // bs)
+    pos = m + np.arange(c)
+    lg, *pools = paged_prefill_chunked(
+        params, jnp.asarray(prompt[None, m:m + c], jnp.int32),
+        jnp.asarray(m, jnp.int32), jnp.asarray([c - 1], jnp.int32),
+        pools[0], pools[1], jnp.asarray(table[None, :cover], jnp.int32),
+        jnp.asarray(table[pos // bs], jnp.int32),
+        jnp.asarray(pos % bs, jnp.int32), cfg, **scale_kwargs(pools))
+    return lg, tuple(pools)
 
-    def _resident_prefix(self, cfg, params, prompt, m0, pool, table, bs):
-        toks = jnp.asarray(prompt[None, :m0], jnp.int32)
-        _, ks, vs = paged_prefill(params, toks,
-                                  jnp.asarray([m0 - 1], jnp.int32), cfg)
-        pool.k, pool.v = scatter_prefill(
-            pool.k, pool.v, ks, vs, jnp.asarray(table[:m0 // bs], jnp.int32))
+
+def _fresh_pool(cfg, nb, bs, kv_dtype="auto"):
+    pool = PagedKVCache(cfg, num_blocks=nb, block_size=bs, kv_dtype=kv_dtype)
+    table = np.asarray(pool.alloc(0, nb), np.int32)
+    pools = (pool.k, pool.v) + ((pool.k_scale, pool.v_scale)
+                                if pool.quantized else ())
+    return pools, table
+
+
+class TestChunkedPrefillStep:
+    """Model-level: chunk walks over identical pool state."""
+
+    def _resident_prefix(self, cfg, params, prompt, m0, pools, table, bs):
+        """The first ``m0`` prompt tokens made pool-resident by one chunk
+        from position 0."""
+        return _chunk(cfg, params, prompt, 0, m0, pools, table, bs)[1]
 
     @pytest.mark.parametrize("m0,chunk", [(16, 16), (16, 24), (32, 8)])
     def test_chunked_equals_one_shot_suffix(self, setup, m0, chunk):
-        """Walking the suffix in chunks (incl. chunk sizes that straddle
-        block boundaries) must reproduce the one-shot dense suffix
-        prefill: same final logits, same pool contents."""
+        """Walking the suffix in chunks over a resident prefix (incl.
+        chunk sizes that straddle block boundaries) must reproduce one
+        chunk of the whole prompt from position 0: same final logits,
+        same pool contents."""
         cfg, params = setup
         bs = 8
         S = 72                           # suffix of 56 = 7 blocks
         prompt = _rng.integers(1, cfg.vocab_size, (S,)).astype(np.int32)
-        pools = {}
-        for mode in ("dense", "chunked"):
-            pool = PagedKVCache(cfg, num_blocks=S // bs, block_size=bs)
-            table = np.asarray(pool.alloc(0, S // bs), np.int32)
-            self._resident_prefix(cfg, params, prompt, m0, pool, table, bs)
-            sl = S - m0
-            pos = m0 + np.arange(sl)
-            blk = jnp.asarray(table[pos // bs], jnp.int32)
-            off = jnp.asarray(pos % bs, jnp.int32)
-            if mode == "dense":
-                lg, ks, vs = paged_prefill_suffix(
-                    params, jnp.asarray(prompt[None, m0:], jnp.int32),
-                    jnp.asarray(m0, jnp.int32),
-                    jnp.asarray([sl - 1], jnp.int32), pool.k, pool.v,
-                    jnp.asarray(table[None, :m0 // bs], jnp.int32),
-                    jnp.asarray([m0], jnp.int32), cfg)
-                pool.k, pool.v = scatter_prefill_offset(
-                    pool.k, pool.v, ks, vs, blk, off)
+        out = {}
+        for mode in ("whole", "chunked"):
+            pools, table = _fresh_pool(cfg, S // bs, bs)
+            if mode == "whole":
+                lg, pools = _chunk(cfg, params, prompt, 0, S, pools, table,
+                                   bs)
             else:
+                pools = self._resident_prefix(cfg, params, prompt, m0,
+                                              pools, table, bs)
                 m = m0
                 while m < S:
                     c = min(chunk, S - m)
-                    cover = -(-(m + c) // bs)
-                    lg, pool.k, pool.v = paged_prefill_chunked(
-                        params, jnp.asarray(prompt[None, m:m + c],
-                                            jnp.int32),
-                        jnp.asarray(m, jnp.int32),
-                        jnp.asarray([c - 1], jnp.int32), pool.k, pool.v,
-                        jnp.asarray(table[None, :cover], jnp.int32),
-                        blk[m - m0:m - m0 + c], off[m - m0:m - m0 + c],
-                        cfg)
+                    lg, pools = _chunk(cfg, params, prompt, m, c, pools,
+                                       table, bs)
                     m += c
-            pools[mode] = (np.asarray(lg), np.asarray(pool.k),
-                           np.asarray(pool.v))
-        lg_d, k_d, v_d = pools["dense"]
-        lg_c, k_c, v_c = pools["chunked"]
-        np.testing.assert_allclose(lg_c, lg_d, atol=2e-4)
-        assert np.argmax(lg_c) == np.argmax(lg_d)
-        np.testing.assert_allclose(k_c, k_d, atol=1e-5)
-        np.testing.assert_allclose(v_c, v_d, atol=1e-5)
+            out[mode] = (np.asarray(lg), np.asarray(pools[0]),
+                         np.asarray(pools[1]))
+        lg_w, k_w, v_w = out["whole"]
+        lg_c, k_c, v_c = out["chunked"]
+        np.testing.assert_allclose(lg_c, lg_w, atol=2e-4)
+        assert np.argmax(lg_c) == np.argmax(lg_w)
+        np.testing.assert_allclose(k_c, k_w, atol=1e-5)
+        np.testing.assert_allclose(v_c, v_w, atol=1e-5)
+
+    @pytest.mark.parametrize("n", [13, 16])      # mid-block; block start
+    @pytest.mark.parametrize("kv_dtype", ["auto", "int8"])
+    def test_decode_step_is_a_one_token_chunk(self, setup, n, kv_dtype):
+        """Both serving programs run one layer: a decode step of token t
+        at length n gives the logits and pool rows of a one-token chunk
+        of t at ``pos0 = n``."""
+        cfg, params = setup
+        bs = 8
+        prompt = _rng.integers(1, cfg.vocab_size, (n + 1,)).astype(np.int32)
+        pools, table = _fresh_pool(cfg, 4, bs, kv_dtype)
+        pools = self._resident_prefix(cfg, params, prompt, n, pools, table,
+                                      bs)
+        lg_c, after_c = _chunk(cfg, params, prompt, n, 1, pools, table, bs)
+        lg_d, *after_d = paged_decode_step(
+            params, jnp.asarray(prompt[n:n + 1], jnp.int32), pools[0],
+            pools[1], jnp.asarray(table[None], jnp.int32),
+            jnp.asarray([n], jnp.int32), cfg, **scale_kwargs(pools))
+        np.testing.assert_allclose(np.asarray(lg_d), np.asarray(lg_c),
+                                   atol=1e-5)
+        for a, b in zip(after_d, after_c):
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32), atol=1e-5)
+        blk, off = table[n // bs], n % bs
+        assert np.abs(np.asarray(after_d[0][:, blk, :, off],
+                                 np.float32)).max() > 0     # row written
+
+    def test_whole_prompt_chunk_matches_contiguous_forward(self, setup):
+        """One chunk of a whole prompt from position 0 gives the last-token
+        logits of ``models/lm.py``'s contiguous prefill."""
+        cfg, params = setup
+        bs, S = 8, 37
+        prompt = _rng.integers(1, cfg.vocab_size, (S,)).astype(np.int32)
+        pools, table = _fresh_pool(cfg, -(-S // bs), bs)
+        lg, _ = _chunk(cfg, params, prompt, 0, S, pools, table, bs)
+        want, _ = lm_prefill(params, jnp.asarray(prompt[None]), cfg, S)
+        np.testing.assert_allclose(np.asarray(lg), np.asarray(want),
+                                   atol=1e-4)
+        assert np.argmax(lg) == np.argmax(want)
 
 
 class TestChunkedEngine:
     @pytest.mark.parametrize("chunk", [8, 24])
     def test_greedy_identical_to_one_shot(self, setup, chunk):
         """Odd prompt lengths (ragged final chunks, mid-block ends) decode
-        identically chunked vs one-shot."""
+        identically in small chunks and in one chunk covering the longest
+        prompt."""
         cfg, params = setup
         lens = (5, 20, 37, 64)
         prompts = [_rng.integers(1, cfg.vocab_size, (n,)).astype(np.int32)
                    for n in lens]
         outs = {}
-        for c in (0, chunk):
+        for c in (max(lens), chunk):
             eng = ContinuousEngine(cfg, params, block_size=8, num_blocks=64,
                                    max_batch=4, max_len=96, prefill_chunk=c)
             hs = [eng.submit(p, 6) for p in prompts]
@@ -298,25 +315,30 @@ class TestChunkedEngine:
             outs[c] = [res[h.req_id].tokens for h in hs]
             for toks in outs[c]:
                 assert len(toks) == 6
-        assert outs[0] == outs[chunk]
+        assert outs[max(lens)] == outs[chunk]
 
     def test_prefix_cache_mid_block_offsets(self, setup):
         """Shared prefix of non-block-multiple length: chunked prefill
-        starts mid-block after the COW tail splice and must agree with the
-        one-shot path."""
+        starts mid-block after the COW tail splice and must agree with an
+        engine that computes every prompt from position 0."""
         cfg, params = setup
         shared = _rng.integers(1, cfg.vocab_size, (21,)).astype(np.int32)
         prompts = [np.concatenate(
             [shared, _rng.integers(1, cfg.vocab_size, (n,))]).astype(
                 np.int32) for n in (13, 30, 7)]
         outs = {}
-        for c in (0, 16):
+        for cache in (True, False):
             eng = ContinuousEngine(cfg, params, block_size=8, num_blocks=64,
-                                   max_batch=4, max_len=96, prefill_chunk=c)
+                                   max_batch=4, max_len=96, prefill_chunk=16,
+                                   prefix_cache=cache,
+                                   max_admit_per_step=1)
             hs = [eng.submit(p, 5) for p in prompts]
             res = eng.run()
-            outs[c] = [res[h.req_id].tokens for h in hs]
-        assert outs[0] == outs[16]
+            outs[cache] = [res[h.req_id].tokens for h in hs]
+            if cache:
+                assert eng.metrics.cow_copies >= 1     # mid-block resume
+                assert eng.metrics.prefix_hit_tokens > 0
+        assert outs[True] == outs[False]
 
     def test_long_prompt_does_not_stall_decode(self, setup):
         """Interleaving: a short request already decoding keeps producing
@@ -374,6 +396,23 @@ class TestChunkedEngine:
         r3 = cold.run()
         assert r2[h2.req_id].tokens == r3[h3.req_id].tokens
 
+    def test_prefill_chunk_default_and_bounds(self, setup):
+        """The chunk defaults to 256 tokens, cut to ``max_len`` and rounded
+        up to whole blocks; a chunk of no tokens is refused."""
+        cfg, params = setup
+
+        def chunk(**kw):
+            return ContinuousEngine(cfg, params, block_size=8,
+                                    num_blocks=8, **kw).prefill_chunk
+
+        assert PREFILL_CHUNK == 256
+        assert chunk(max_len=512) == 256
+        assert chunk(max_len=100) == 104
+        assert chunk(max_len=512, prefill_chunk=13) == 16
+        for bad in (0, -8):
+            with pytest.raises(ValueError):
+                chunk(max_len=512, prefill_chunk=bad)
+
     def test_warmup_covers_chunked_path(self, setup):
         cfg, params = setup
         eng = ContinuousEngine(cfg, params, block_size=8, num_blocks=32,
@@ -384,21 +423,3 @@ class TestChunkedEngine:
             _rng.integers(1, cfg.vocab_size, (20,)).astype(np.int32), 4)
         res = eng.run()
         assert len(res[h.req_id].tokens) == 4
-
-
-@pytest.mark.slow
-class TestBenchSmoke:
-    def test_prefill_paged_bench_smoke(self):
-        """The benchmark's CI mode: asserts chunked == dense greedy
-        outputs (op-level argmax and engine-level tokens) on a tiny
-        workload; speed is reported, not gated."""
-        import pathlib
-        import sys
-        root = pathlib.Path(__file__).resolve().parent.parent
-        sys.path.insert(0, str(root / "benchmarks"))
-        try:
-            import prefill_paged_bench
-            ratio = prefill_paged_bench.main(["--smoke"])
-        finally:
-            sys.path.pop(0)
-        assert ratio > 0

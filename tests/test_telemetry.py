@@ -258,7 +258,7 @@ class TestTelemetryEngine:
         assert trace["otherData"]["arch"] == cfg.name
         assert trace["otherData"]["dropped_events"] == 0
         phases = {e["name"] for e in evs if e["ph"] == "X"}
-        assert {"step", "prefill", "decode", "drain"} <= phases
+        assert {"step", "prefill-chunk", "decode", "drain"} <= phases
         for e in evs:
             assert e["ph"] in ("X", "i", "M")
             if e["ph"] == "X":
