@@ -12,12 +12,12 @@ physical pool through the same tables):
   group's shared KV block (group× redundant operand movement), one
   ``block_size``-row block per kv grid step, the whole walk serialized on
   one lane per head.
-* **grouped**  — ``flash_decode_paged``: grid
-  ``(B, split_k, W/(T*split_k))`` — one whole-block gather (every KV head)
-  feeds every query head (``(Hkv, group, D)`` batched MXU tiles instead of
-  ``(1, D)`` vector dots), each step streams a ``kv_tile_blocks``-block KV
-  tile, tiles past a row's length neither compute nor copy, and the split
-  partials merge through the associative Softermax combine.
+* **grouped**  — ``flash_decode_paged``: grid ``(B, split_k)``, each
+  program walking only its row's live ``kv_tile_blocks``-block KV tiles —
+  one whole-block copy (every KV head) feeds every query head (``(Hkv,
+  group, D)`` batched MXU tiles instead of ``(1, D)`` vector dots), table
+  columns past a row's length are neither copied nor computed, and the
+  split partials merge through the associative Softermax combine.
 
 Two measurements:
 
@@ -65,8 +65,7 @@ def _time_kernels(args, rng):
     import jax
     import jax.numpy as jnp
     from repro.kernels.flash_decode_paged import (flash_decode_paged,
-                                                  flash_decode_paged_single,
-                                                  split_layout)
+                                                  flash_decode_paged_single)
     from repro.serve.paged_step import table_width_bucket
 
     B, Hq, Hkv = args.requests, args.hq, args.hkv
@@ -120,15 +119,13 @@ def _time_kernels(args, rng):
     # modeled gather traffic per decoded token, per layer (README math)
     # for a row that fills the table: the per-head kernel walks W blocks
     # once per *query* head; the grouped kernel copies each live block
-    # once, every KV head in one copy, and then each slot of the last
-    # tile once more (shared split_layout), its dead steps copying nothing
-    T, _, _, Wp = split_layout(W, args.tile_blocks, args.split_k)
+    # once, every KV head in one copy
     itm = np.dtype(np.float32).itemsize
     bytes_single = 2 * Hq * W * BS * D * itm
-    bytes_grouped = 2 * Hkv * (W - 1 + min(T, Wp - W + 1)) * BS * D * itm
+    bytes_grouped = 2 * Hkv * W * BS * D * itm
     return (float(min(single_s)), float(min(grouped_s)),
             {"mode": "compiled-tpu" if not interpret else "pallas-interpret",
-             "table_width": int(W), "padded_width": int(Wp),
+             "table_width": int(W),
              "gather_bytes_per_token_per_layer": {
                  "single": int(bytes_single), "grouped": int(bytes_grouped),
                  "ratio": round(bytes_single / bytes_grouped, 3)}})

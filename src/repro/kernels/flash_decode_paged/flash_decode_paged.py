@@ -8,43 +8,38 @@ Softermax recurrence is order-free (every rescale is an exact exponent add),
 blocks can be streamed in table order with no pre-pass over the scores, which
 is exactly what makes the paged layout free for this kernel.
 
-The layer index, the block ids each grid column reads (the block table,
-clamped as below) and the per-row lengths are scalar-prefetch operands
-(``PrefetchScalarGridSpec``, SMEM): their entries are available *before*
-the kernel body runs, so the KV BlockSpec index maps perform the gather —
-each grid step DMAs physical blocks of the one layer straight from the
-whole ``(L, N, Hkv, BS, D)`` pool into VMEM — and the body reads its
-row's length as a scalar. The layer scan of ``serve/paged_step.py``
-carries that whole pool and writes each layer's new rows into it in
-place, so no program copies the pool. (A ``(1, 1)`` VMEM block over a
+The layer index, the block table and the per-row lengths are
+scalar-prefetch operands (``PrefetchScalarGridSpec``, SMEM). The K and V
+pools stay in HBM (``memory_space=pl.ANY``) as the whole ``(L, N, Hkv,
+BS, D)`` arrays the layer scan of ``serve/paged_step.py`` carries and
+updates in place, so no program copies the pool; the kernel DMAs the
+blocks it needs of the one layer itself. (A ``(1, 1)`` VMEM block over a
 ``(B, 1)`` lengths array is refused by the TPU compiler for B > 1: a
 block's last two dims must be (8, 128)-divisible or span the array.)
 
-Three grid-level restructurings over the naive per-head walk (all three are
-pure reorganizations of the same recurrence — outputs are unchanged):
+Three restructurings over the naive per-head walk (all pure
+reorganizations of the same recurrence — outputs are unchanged):
 
-* **Whole-block gathers, KV heads folded in.** The grid is ``(B, S,
-  spl)``: one step owns a row's every query head, its query tile is
-  ``(Hkv, G, D)``, and each gather operand takes one whole pool block
-  ``(1, Hkv, BS, D)`` — every KV head of the block in one contiguous DMA
-  (32 KB at 8 heads of 128 in bf16) where a per-head grid moved it in
-  ``Hkv`` copies from ``Hkv`` steps. The QK and AV dots run batched over
-  the head axis. On a TPU v5e each grid step and gather operand costs
-  fixed scalar work, so the grid's size, not the bytes, set the time of
-  the per-head walk.
-* **Multi-block KV tiles.** Each kv grid step gathers ``kv_tile_blocks``
-  (T) whole blocks — T DMAs the pipeline overlaps within one step — and
-  processes them as one ``(Hkv, T*BS, D)`` VMEM tile. Without a tile from
-  the caller, T comes from the block shape (``ref.decode_tile_blocks``:
-  ``T*BS >= 128`` rows, a K tile of at most 512 KiB, so many KV heads or
-  wide heads get a smaller T), clamped to the table width. The block each
-  slot reads is looked up once per launch (``ref.live_block_column``): a
-  slot past its row's live blocks reads the row's last live block, so
-  dead slots and the steps past a row's length repeat a block index, and
-  the pipeline copies nothing for a repeated index; ``@pl.when`` skips
-  their compute.
-* **Split-K.** The KV walk is partitioned across a *parallel* grid axis of
-  ``split_k`` lanes; each lane emits its partial ``(m, d, acc)`` state and
+* **Whole-block copies, KV heads folded in.** One program owns a row's
+  every query head: its query tile is ``(Hkv, G, D)`` and each copy moves
+  one whole pool block ``(Hkv, BS, D)`` — every KV head of the block in one
+  contiguous DMA (32 KB at 8 heads of 128 in bf16). The QK and AV dots run
+  batched over the head axis.
+* **A walk of each row's live tiles.** The grid is ``(B, S)``: one program
+  per row and split lane. A tile is T (``kv_tile_blocks``) whole blocks,
+  processed as one ``(Hkv, T*BS, D)`` VMEM tile; without a tile from the
+  caller T comes from the block shape (``ref.decode_tile_blocks``:
+  ``T*BS >= 128`` rows, a K tile of at most 512 KiB), clamped to the table
+  width. The program reads its row's length from SMEM and loops over the
+  lane's live tiles only (``ref.live_tile_range``), double-buffered: while
+  one half of a ``2 x T``-block VMEM buffer is computed on, the next tile's
+  blocks are copied into the other. Table columns past a row's last live
+  block are neither copied nor attended, however wide the table is padded
+  (``paged_step.table_width_bucket``), so a launch's DMA and compute
+  follow the batch's live tokens, not its padded width.
+* **Split-K.** The tile walk is partitioned across a *parallel* grid axis
+  of ``split_k`` lanes; each lane emits its partial ``(m, d, acc)`` state
+  (the merge identity ``(NEG_INF, 0, 0)`` when it holds no live tile) and
   a small jnp second stage merges them with the associative Softermax
   combine (``core.softermax.softermax_merge`` — exact power-of-two
   rescales under the joint IntMax) before the final normalize. One long
@@ -59,13 +54,16 @@ then ``S *= k_scale`` per column (exact: the scale is a per-row constant of
 K), and ``p *= v_scale`` before ``p·V`` (same identity on the V side). Both
 rescales touch the (Hkv, group, T*BS) score tile, not the value tile, so
 the dequant cost stays O(tile-row) while the accumulate stays fp32 — the
-paper's int-storage / wide-accumulate split applied to the KV side. TPU
-tiling note: int8 VMEM tiles are (32, 128)-granular (vs (16, 128) for
-bf16), so int8 pools waste no sublane padding when ``block_size >= 32``.
+paper's int-storage / wide-accumulate split applied to the KV side. The
+scales ride the same copy loop as the values. TPU tiling note: int8 VMEM
+tiles are (32, 128)-granular (vs (16, 128) for bf16), so int8 pools waste
+no sublane padding when ``block_size >= 32``.
 
 Table entries past a sequence's length may be garbage (the pool's reserved
-block 0): no slot reads them, and the length mask zeroes the columns of the
-last live block past the length.
+block 0): no copy reads them. In a row's last tile the slots past its last
+live block are not copied; their V rows (and v-scales) are zeroed, and the
+length mask gives their columns, and those of the last live block past the
+length, exactly zero weight.
 """
 from __future__ import annotations
 
@@ -80,19 +78,18 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.numerics import NEG_INF
 from repro.core.softermax import softermax_finalize, softermax_merge
 from repro.kernels.flash_decode_paged.ref import (decode_tile_blocks,
-                                                  live_block_column,
+                                                  live_tile_range, scale_rows,
                                                   split_layout)
 
 
 def concat_tiles(refs, axis: int = 0, idx: tuple = (0, 0)):
-    """Assemble one VMEM tile from the T per-slot gather operands (each
-    ref holds one pool block of one layer; ``idx`` strips its leading
-    unit block axes). Shared by the decode and prefill kernel bodies: the
-    decode kernel's whole-block ``(1, 1, Hkv, BS, D)`` operands concat
-    along rows (axis 1) and its ``(1, Hkv, 1, BS)`` scales (``idx=(0,)``)
-    along axis 2; the prefill kernel's ``(1, 1, 1, BS, D)`` blocks
-    (``idx=(0, 0, 0)``) concat along rows (axis 0) and its ``(1, 1, 1,
-    BS)`` scale rows along columns (axis 1)."""
+    """Assemble one VMEM tile from T per-block refs (each holds one pool
+    block of one layer; ``idx`` indexes what to load of it). Shared by the
+    decode and prefill kernel bodies: the decode kernel's ``(Hkv, BS,
+    D)`` buffer slots (``idx=(...,)``) concat along rows (axis 1) and its
+    ``(Hkv, 1, BS)`` scale slots along axis 2; the prefill kernel's ``(1,
+    1, 1, BS, D)`` blocks (``idx=(0, 0, 0)``) concat along rows (axis 0)
+    and its ``(1, 1, 1, BS)`` scale rows along columns (axis 1)."""
     if len(refs) == 1:
         return refs[0][idx]
     return jnp.concatenate([r[idx] for r in refs], axis=axis)
@@ -109,7 +106,7 @@ def layer_operands(k_pool, v_pool, k_scale, v_scale, layer):
     The scales are the one operand read per layer rather than in place:
     on a TPU an f32 ``(..., Hkv, BS)`` array is laid out with its block
     axis minor (the TPU's default for a minor dim under 128 lanes), along
-    which no BlockSpec can gather, so one layer's scales are relaid here
+    which no block copy can gather, so one layer's scales are relaid here
     — a copy of one layer of the scale pool, never of the pool."""
     if (layer is None) != (k_pool.ndim == 4):
         raise ValueError(f"a pool of rank {k_pool.ndim} with layer "
@@ -129,33 +126,73 @@ def layer_operands(k_pool, v_pool, k_scale, v_scale, layer):
 
 def _paged_decode_kernel(layer_ref, ids_ref, len_ref, q_ref, *rest,
                          intmax: bool, block_size: int, tile_blocks: int,
-                         quantized: bool):
-    T = tile_blocks
-    k_refs, v_refs = rest[:T], rest[T:2 * T]
-    n = 2 * T
-    if quantized:
-        ksc_refs, vsc_refs = rest[n:n + T], rest[n + T:n + 2 * T]
-        n += 2 * T
-    acc_ref, m_ref, d_ref, acc_scr, m_scr, d_scr = rest[n:]
-    j = pl.program_id(2)
-    spl = pl.num_programs(2)                  # kv tiles per split lane
+                         tiles_per_lane: int, quantized: bool):
+    T, BS = tile_blocks, block_size
+    n_heads = q_ref.shape[1]
+    n_src = 4 if quantized else 2
+    srcs, (acc_ref, m_ref, d_ref) = rest[:n_src], rest[n_src:n_src + 3]
+    bufs = rest[n_src + 3:2 * n_src + 3]     # (2, T, ...) VMEM, as srcs
+    acc_scr, m_scr, d_scr, sems = rest[2 * n_src + 3:]
+    kbuf, vbuf = bufs[0], bufs[1]
+    row = pl.program_id(0)
+    kv_len = len_ref[row]
+    width = ids_ref.shape[1]
+    lo, hi, n_blocks = live_tile_range(kv_len, pl.program_id(1),
+                                       tiles_per_lane, T, BS, width)
+    layer = layer_ref[0]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        d_scr[...] = jnp.zeros_like(d_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def copies(col, slot, t):
+        # the DMAs of one live block into slot t of buffer half ``slot``:
+        # K and V of the layer (and the int8 pool's scales of that layer)
+        blk = ids_ref[row, col]
+        at = [(layer, blk), (layer, blk), (blk,), (blk,)][:n_src]
+        return [pltpu.make_async_copy(src.at[i], buf.at[slot, t],
+                                      sems.at[slot])
+                for src, buf, i in zip(srcs, bufs, at)]
 
-    kv_len = len_ref[pl.program_id(0)]
-    jj = pl.program_id(1) * spl + j           # global kv tile index
-    k_start = jj * (T * block_size)
+    def fetch(tile, slot):
+        for t in range(T):
+            col = tile * T + t
 
-    @pl.when(k_start < kv_len)
-    def _body():
+            @pl.when(col < n_blocks)
+            def _start():
+                for c in copies(col, slot, t):
+                    c.start()
+
+            @pl.when(col >= n_blocks)
+            def _dead():
+                # a slot past the last live block is not copied: zero its
+                # values (and v-scales) so its masked columns add exactly 0
+                vbuf[slot, t] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+                if quantized:
+                    bufs[3][slot, t] = jnp.zeros(bufs[3].shape[2:],
+                                                 jnp.float32)
+
+    def wait(tile, slot):
+        for t in range(T):
+            col = tile * T + t
+
+            @pl.when(col < n_blocks)
+            def _wait():
+                for c in copies(col, slot, t):
+                    c.wait()
+
+    def scale_tile(buf, slot):
+        # (Hkv, 1, T*BS) scales of a tile from its T lane-dense (1, lanes)
+        # slots, each holding one block's (Hkv, BS) scales head-major
+        rows = [buf[slot, t] for t in range(T)]
+        return jnp.stack([
+            jnp.concatenate([r[:, h * BS:(h + 1) * BS] for r in rows],
+                            axis=1) for h in range(n_heads)])
+
+    def tile_body(tile, slot):
+        k_start = tile * (T * BS)
         q = q_ref[0].astype(jnp.float32)      # (Hkv, G, D)
-        # T whole-block gathers (every KV head) as one (Hkv, T*BS, D) tile
-        k = concat_tiles(k_refs, axis=1)
-        v = concat_tiles(v_refs, axis=1)
+        # T whole blocks (every KV head) as one (Hkv, T*BS, D) tile
+        k = concat_tiles([kbuf.at[slot, t] for t in range(T)], axis=1,
+                         idx=(...,))
+        v = concat_tiles([vbuf.at[slot, t] for t in range(T)], axis=1,
+                         idx=(...,))
         s = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)   # (Hkv, G, T*BS)
@@ -163,7 +200,7 @@ def _paged_decode_kernel(layer_ref, ids_ref, len_ref, q_ref, *rest,
             # dequant fused post-dot: k_scale is constant per K row, so
             # scaling the (G, T*BS) score columns equals scaling the
             # (T*BS, D) tile — for a fraction of the flops
-            s = s * concat_tiles(ksc_refs, 2, (0,))  # (Hkv, 1, T*BS)
+            s = s * scale_tile(bufs[2], slot)     # (Hkv, 1, T*BS)
         kj = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(kj < kv_len, s, NEG_INF)
         m_prev = m_scr[...]
@@ -174,7 +211,7 @@ def _paged_decode_kernel(layer_ref, ids_ref, len_ref, q_ref, *rest,
         alpha = jnp.exp2(m_prev - m_new)      # exact power-of-two
         p = jnp.exp2(s - m_new)
         if quantized:
-            pv = p * concat_tiles(vsc_refs, 2, (0,))  # fold v_scale
+            pv = p * scale_tile(bufs[3], slot)    # fold v_scale
         else:
             pv = p
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
@@ -183,13 +220,31 @@ def _paged_decode_kernel(layer_ref, ids_ref, len_ref, q_ref, *rest,
         d_scr[...] = d_scr[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
         m_scr[...] = m_new
 
-    @pl.when(j == spl - 1)
-    def _fin():
-        # emit the lane's partial state; lanes whose every tile sat past
-        # kv_len emit the merge identity (NEG_INF, 0, 0) from _init
-        acc_ref[0, 0] = acc_scr[...]
-        m_ref[0, 0] = m_scr[...]
-        d_ref[0, 0] = d_scr[...]
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    d_scr[...] = jnp.zeros_like(d_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(lo < hi)
+    def _first():
+        fetch(lo, lo % 2)
+
+    def step(tile, carry):
+        slot = tile % 2
+
+        @pl.when(tile + 1 < hi)
+        def _next():
+            fetch(tile + 1, 1 - slot)
+
+        wait(tile, slot)
+        tile_body(tile, slot)
+        return carry
+
+    jax.lax.fori_loop(lo, hi, step, 0)
+    # the lane's partial state; a lane with no live tile emits the merge
+    # identity (NEG_INF, 0, 0)
+    acc_ref[0, 0] = acc_scr[...]
+    m_ref[0, 0] = m_scr[...]
+    d_ref[0, 0] = d_scr[...]
 
 
 @functools.partial(
@@ -209,9 +264,9 @@ def flash_decode_paged(
     v_scale: jax.Array = None,   # scales, laid out as the pool (no L with
     #                              a one-layer pool)
     intmax: bool = True,
-    kv_tile_blocks: Optional[int] = None,  # pool blocks a kv grid step
-    #                                        gathers (T); None derives it
-    #                                        from the block shape
+    kv_tile_blocks: Optional[int] = None,  # pool blocks a kv tile holds
+    #                                        (T); None derives it from
+    #                                        the block shape
     split_k: int = 1,         # parallel partitions of the KV walk
     interpret: bool = False,
 ) -> jax.Array:
@@ -227,62 +282,50 @@ def flash_decode_paged(
         kv_tile_blocks = decode_tile_blocks(BS, Hkv, D,
                                             k_pool.dtype.itemsize)
     # clamp the tiling to the table (shared geometry — ref.split_layout):
-    # T-block tiles, S split lanes of spl tiles each, covering Wp >= W
-    # table columns. The block each (grid column, row) gathers is looked
-    # up once here, not per grid step: its live table entry, else the
-    # row's last live one (``live_block_column``), so dead slots and dead
-    # steps repeat a block index and the pipeline copies nothing for them
-    T, S, spl, Wp = split_layout(W, kv_tile_blocks, split_k)
-    lens = lengths.astype(jnp.int32)
-    cols = live_block_column(jnp.arange(Wp, dtype=jnp.int32)[None, :],
-                             lens[:, None], BS, W)
-    ids = jnp.take_along_axis(block_tables.astype(jnp.int32), cols, axis=1)
+    # T-block tiles, S split lanes of at most spl tiles each; a lane walks
+    # only its tiles that hold live blocks of its row
+    T, S, spl, _ = split_layout(W, kv_tile_blocks, split_k)
     qf = q.reshape(B, Hkv, G, D)
 
-    def kv_spec(t, layered, block):
-        # one gather per tile slot: (layer, block id, whole block) of the
-        # pools, the layer scalar-prefetched beside the ids; (block id,
-        # whole block) of one layer's scales
-        def _map(b, s, j, layer_ref, ids_ref, len_ref):
-            return ((layer_ref[0],) if layered else ()) + \
-                (ids_ref[b, (s * spl + j) * T + t],) + (0,) * len(block)
-        return pl.BlockSpec((1,) * (1 + layered) + block, _map)
-
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [
-        pl.BlockSpec((1, Hkv, G, D), lambda b, s, j, *_: (b, 0, 0, 0)),
+        pl.BlockSpec((1, Hkv, G, D), lambda b, s, *_: (b, 0, 0, 0)),
+        hbm, hbm,
     ]
-    in_specs += [kv_spec(t, True, (Hkv, BS, D))
-                 for _ in "kv" for t in range(T)]
-    inputs = [qf] + [k_pool] * T + [v_pool] * T
+    inputs = [qf, k_pool, v_pool]
+    bufs = [pltpu.VMEM((2, T, Hkv, BS, D), k_pool.dtype)] * 2
     if quantized:
-        # scales ride the same scalar-prefetch block-table gather as the
-        # values; the unit axis keeps in-kernel reads (Hkv, 1, BS)
-        in_specs += [kv_spec(t, False, (Hkv, 1, BS))
-                     for _ in "kv" for t in range(T)]
-        inputs += [k_scale] * T + [v_scale] * T
+        # the scales ride the same copy loop as the values, one lane-dense
+        # row of every KV head's scales a block (``ref.scale_rows``)
+        k_scale, v_scale = scale_rows(k_scale), scale_rows(v_scale)
+        in_specs += [hbm, hbm]
+        inputs += [k_scale, v_scale]
+        bufs += [pltpu.VMEM((2, T) + k_scale.shape[1:], jnp.float32)] * 2
 
-    def out_map(b, s, j, *_):
+    def out_map(b, s, *_):
         return (b, s, 0, 0, 0)
 
     part = pl.BlockSpec((1, 1, Hkv, G, 1), out_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, S, spl),
+        grid=(B, S),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, Hkv, G, D), out_map),
             part, part,
         ],
-        scratch_shapes=[
+        scratch_shapes=bufs + [
             pltpu.VMEM((Hkv, G, D), jnp.float32),
             pltpu.VMEM((Hkv, G, 1), jnp.float32),
             pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
 
     acc, m, d = pl.pallas_call(
         functools.partial(_paged_decode_kernel, intmax=intmax,
-                          block_size=BS, tile_blocks=T, quantized=quantized),
+                          block_size=BS, tile_blocks=T, tiles_per_lane=spl,
+                          quantized=quantized),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, S, Hkv, G, D), jnp.float32),
@@ -290,10 +333,11 @@ def flash_decode_paged(
             jax.ShapeDtypeStruct((B, S, Hkv, G, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
         ),
-        interpret=interpret,
-    )(lay, ids, lens, *inputs)
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(lay, block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      *inputs)
 
     # second stage: associative Softermax merge of the split partials under
     # the joint (Int)Max, then the one deferred normalize. With split_k=1

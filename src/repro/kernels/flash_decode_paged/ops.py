@@ -3,13 +3,15 @@
 Single dispatcher for every caller (the serving engine's fused decode step
 routes here too):
 
-* TPU backend          — the compiled Pallas kernel: one grid step per
-  (row, split lane, tile of ``kv_tile_blocks`` whole pool blocks, every KV
-  head), ``split_k`` parallel partitions merged by the associative
-  Softermax combine; with no tile given, T comes from the block shape
-  (``ref.decode_tile_blocks``).
-* ``interpret=True``   — the same kernel under the Pallas interpreter (CPU
-  CI exercises the exact grid/tile/split dataflow this way).
+* TPU backend          — the compiled Pallas kernel: one program per
+  (row, split lane), each walking only its row's live tiles of
+  ``kv_tile_blocks`` whole pool blocks (every KV head) with a
+  double-buffered copy loop, ``split_k`` parallel partitions merged by the
+  associative Softermax combine; with no tile given, T comes from the
+  block shape (``ref.decode_tile_blocks``).
+* ``interpret=True``   — the same kernel under the Pallas TPU interpreter
+  (CPU CI exercises the exact walk/tile/split dataflow, its DMAs and
+  semaphores, this way).
 * anywhere else        — pure JAX: the gather oracle ``paged_decode_ref``.
   The tile/split parameters are *layout* knobs, not math knobs — every
   setting computes the identical attention — so the CPU fallback always

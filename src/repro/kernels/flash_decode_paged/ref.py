@@ -68,17 +68,36 @@ def decode_tile_blocks(block_size: int, kv_heads: int, head_dim: int,
     return max(1, min(rows, fit))
 
 
-def live_block_column(col, kv_len, block_size: int, width: int):
-    """The table column a decode gather slot reads for grid column
-    ``col``: the column itself while it holds a live block, else the
-    row's last live column (column 0 for an empty row), never past the
-    table. Dead slots and dead steps so repeat the block they read last,
-    and the pipeline issues no copy for a block index that repeats. The
-    kernel wrapper and the gather oracle both look up their block ids
-    through it."""
-    last = jnp.clip((kv_len + block_size - 1) // block_size - 1, 0,
-                    width - 1)
-    return jnp.minimum(col, last)
+def live_tile_range(kv_len, lane, tiles_per_lane: int, tile_blocks: int,
+                    block_size: int, width: int, xp=jnp):
+    """THE tiles split lane ``lane`` of a row of ``kv_len`` tokens walks:
+    ``(lo, hi, n_blocks)``, tiles ``lo <= tile < hi`` of ``tile_blocks``
+    table columns each, where the row's ``n_blocks = min(ceil(kv_len /
+    block_size), width)`` live blocks lie; ``hi <= lo`` is a lane with no
+    live tile. Columns at or past ``n_blocks`` are never copied. The
+    kernel (on SMEM scalars), the gather oracle and the cost model
+    (``xp=np``, on host arrays) all walk through it."""
+    n_blocks = xp.minimum((kv_len + block_size - 1) // block_size, width)
+    lo = lane * tiles_per_lane
+    hi = xp.minimum(lo + tiles_per_lane,
+                    (n_blocks + tile_blocks - 1) // tile_blocks)
+    return lo, hi, n_blocks
+
+
+SCALE_LANES = 128
+
+
+def scale_rows(scales: jax.Array) -> jax.Array:
+    """An int8 pool's per-row scales of one layer, ``(N, Hkv, BS)`` or
+    ``(N, Hkv, 1, BS)``, as the decode kernel copies them: one lane-dense
+    f32 row per block, ``(N, 1, lanes)``, every KV head's ``BS`` scales in
+    head order, padded to a whole number of ``SCALE_LANES``-lane tiles
+    (a TPU copy's minor dim has to fill whole tiles)."""
+    N = scales.shape[0]
+    row = int(np.prod(scales.shape[1:]))
+    lanes = -(-row // SCALE_LANES) * SCALE_LANES
+    return jnp.pad(scales.astype(jnp.float32).reshape(N, 1, row),
+                   ((0, 0), (0, 0), (0, lanes - row)))
 
 
 def _take_blocks(pool: jax.Array, block_tables: jax.Array, layer):
@@ -137,48 +156,49 @@ def decode_gather_oracle(
     v_scale: jax.Array = None,
 ):
     """MEASURE (not model) one decode launch's gather traffic: walk the
-    kernel's grid ``(B, S, spl)`` with its T gather slots, look up the
-    block each slot reads at each step (``live_block_column`` over the
-    table, as the wrapper does), count a copy whenever a slot's block
-    differs from the one it read the step before (each row's first step
-    copies every slot), take those blocks from the pools and count bytes
-    off the taken arrays' shapes, plus the int32 table the scalar
-    prefetch moves. The analytic model in ``serve/kernel_costs.py`` must
-    reproduce these numbers exactly on tables whose rows hold distinct
-    blocks, as the pool's do.
+    kernel's grid ``(B, S)`` and each lane's live tiles
+    (``live_tile_range``), list the block every copy of the walk moves
+    (each live column of a tile; columns past a row's live blocks are not
+    copied), take those blocks from the pools (and an int8 pool's scales
+    as the kernel lays them out, ``scale_rows``) and count bytes off the
+    taken arrays' shapes, plus the int32 table the scalar prefetch moves.
+    The analytic model in ``serve/kernel_costs.py`` must reproduce these
+    numbers exactly.
 
     Returns ``{"gather_bytes", "useful_bytes", "waste_bytes",
-    "grid_steps", "padded_width"}``; useful bytes are each row's live
-    blocks (``ceil(len/BS)``, at most W) and their table entries, waste
-    the rest: repeat copies into slots past a row's last live block and
-    the table's dead entries, int8 scale siblings included.
+    "grid_steps", "tile_steps", "steps_per_lane"}``; useful bytes are
+    each row's live blocks (``ceil(len/BS)``, at most W), int8 scale
+    siblings included, and the table, waste the rest (0 for a walk that
+    copies only live blocks); ``tile_steps`` counts the tiles every lane
+    walks, ``steps_per_lane`` the most one lane walks.
     """
-    bt = jnp.asarray(block_tables, jnp.int32)
+    bt = np.asarray(block_tables, np.int32)
     B, W = bt.shape
     _, Hkv, BS, D = k_pool.shape
     if kv_tile_blocks is None:
         kv_tile_blocks = decode_tile_blocks(BS, Hkv, D,
                                             k_pool.dtype.itemsize)
-    T, S, spl, Wp = split_layout(W, kv_tile_blocks, split_k)
-    lens = jnp.asarray(lengths, jnp.int32)
-    cols = live_block_column(jnp.arange(Wp, dtype=jnp.int32)[None, :],
-                             lens[:, None], BS, W)
-    ids = np.asarray(jnp.take_along_axis(bt, cols, axis=1)).reshape(
-        B, S * spl, T)                            # (row, step, slot)
-    copied = np.ones_like(ids, bool)
-    copied[:, 1:] = ids[:, 1:] != ids[:, :-1]
-    moved = jnp.asarray(ids[copied])              # block id of every copy
+    T, S, spl, _ = split_layout(W, kv_tile_blocks, split_k)
+    ids, walked = [], []
+    for r, ln in enumerate(np.asarray(lengths, np.int64)):
+        for lane in range(S):
+            lo, hi, nb = (int(x) for x in live_tile_range(
+                int(ln), lane, spl, T, BS, W, xp=np))
+            walked.append(max(hi - lo, 0))
+            ids += [bt[r, c] for tile in range(lo, hi)
+                    for c in range(tile * T, (tile + 1) * T) if c < nb]
+    moved = jnp.asarray(np.asarray(ids, np.int32))  # block of every copy
 
-    taken = [k_pool[moved], v_pool[moved]]        # the real takes — bytes
-    if k_scale is not None:                       # come off their shapes
-        taken += [k_scale[moved], v_scale[moved]]
-    per_block = sum(int(a[0].nbytes) for a in taken)
+    pools = [k_pool, v_pool] + ([scale_rows(k_scale), scale_rows(v_scale)]
+                                if k_scale is not None else [])
+    taken = [p[moved] for p in pools]     # the real takes — bytes come
+    per_block = sum(int(p[0].nbytes) for p in pools)  # off their shapes
     gather = sum(int(a.nbytes) for a in taken) + int(bt.nbytes)
     live = sum(min(-(-int(ln) // BS), W) for ln in list(lengths))
-    useful = live * (per_block + bt.dtype.itemsize)
+    useful = live * per_block + int(bt.nbytes)
     return {"gather_bytes": gather, "useful_bytes": useful,
-            "waste_bytes": gather - useful, "grid_steps": B * S * spl,
-            "padded_width": Wp}
+            "waste_bytes": gather - useful, "grid_steps": B * S,
+            "tile_steps": sum(walked), "steps_per_lane": max(walked)}
 
 
 def paged_decode_ref(

@@ -1100,8 +1100,9 @@ class ContinuousEngine:
         if self.planner is not None and self.autotune == "per-step":
             plan = self.planner.plan_decode(lengths + 1, w)
             tile, split = plan.kv_tile_blocks, plan.split_k
-        # the kernel's grid: B rows x S*spl tiles of T blocks, of which
-        # each row's first ceil((len+1)/(T*BS)) do work
+        # the table's tiles: B rows x S*spl tiles of T blocks, of which
+        # the kernel walks each row's first ceil((len+1)/(T*BS)); the
+        # rest (grid_tiles - live_tiles) are the padded tiles it skips
         T, S, spl, _ = split_layout(w, tile, split)
         live = -(-(lengths + 1) // (T * self.block_size))
         with span("serve.decode", rows=len(occ), width=w, tile=T,

@@ -15,17 +15,12 @@ gathered arrays and count bytes) by ``tests/test_kernel_costs.py``.
 
 What is counted, and why it is exact:
 
-* **Gather-DMA bytes, decode.** Grid ``(B, S, spl)``: each step's T
-  slots gather whole pool blocks, every KV head in one copy
-  (``Hkv * BS * D * itemsize`` bytes of K, as many of V, plus the int8
-  pools' ``Hkv * BS * 4``-byte scale rows each). A slot past a row's live
-  blocks reads the row's last live block (``ref.live_block_column``), and
-  the pipeline issues no copy for a block index that repeats from the
-  step before; so a row costs one copy per live column before its last,
-  then one per slot as it reaches the last (``last + min(T, Wp - last)``
-  for ``Wp = S * spl * T``), however wide the table. The model takes a
-  row's blocks as distinct, as the pool's are, and counts each row's
-  first step as copying all T slots. The ``B * W`` int32 block table,
+* **Gather-DMA bytes, decode.** Grid ``(B, S)``: each program walks its
+  lane's live tiles (``ref.live_tile_range``) and copies each live
+  block of its row once, whole (``Hkv * BS * D * itemsize`` bytes of K,
+  as many of V, plus the int8 pools' two lane-dense scale rows,
+  ``ref.scale_rows``); columns past a row's live blocks are never
+  copied, however wide the table. The ``B * W`` int32 block table,
   copied to SMEM as the scalar-prefetch operand, is counted too.
 * **Gather-DMA bytes, prefill.** One pool block per (KV head, tile slot,
   grid step), unconditionally; ``@pl.when`` skips *compute* on tiles
@@ -34,13 +29,11 @@ What is counted, and why it is exact:
   (``nq`` of them).
 * **Waste bytes.** What a launch moves for table entries at or past a
   row's real block count (``ceil(len / BS)``; the engine's pow2
-  bucketing, tile padding and dead preallocated tail blocks all produce
-  them): decode's repeat copies into slots past the last live block and
-  its dead table entries, prefill's masked block-0 gathers. Waste is 0
-  exactly when every row's blocks fill the padded table, which the
-  property tests pin.
-* **MXU FLOPs.** Per *computed* kv tile (``k_start < kv_len``, resp. the
-  prefill diagonal check) the QK and AV dots each run their full tile
+  bucketing and dead preallocated tail blocks produce them): prefill's
+  masked block-0 gathers. Decode copies only live blocks and the table
+  it needs to find them, so its waste is 0.
+* **MXU FLOPs.** Per *computed* kv tile (a live tile of decode's walk,
+  resp. the prefill diagonal check) the QK and AV dots each run their full tile
   shape regardless of masking: ``2 * rows * D * (T * BS)`` FLOPs apiece.
   Masked columns inside a computed tile still cost FLOPs (that is how the
   kernel runs) — only whole skipped tiles don't.
@@ -59,7 +52,11 @@ import dataclasses
 import math
 from typing import Dict, Optional, Sequence
 
-from repro.kernels.flash_decode_paged.ref import (decode_tile_blocks,
+import numpy as np
+
+from repro.kernels.flash_decode_paged.ref import (SCALE_LANES,
+                                                  decode_tile_blocks,
+                                                  live_tile_range,
                                                   split_layout)
 
 # storage itemsizes by resolved pool dtype name (np.dtype("bfloat16")
@@ -91,7 +88,7 @@ class LaunchCost:
     kind: str                # "decode" | "prefill"
     grid_steps: int          # total grid iterations of the launch
     lanes: int               # parallel grid extent (B*S / B*Hkv*nq)
-    steps_per_lane: int      # sequential kv iterations per lane (spl / nk)
+    steps_per_lane: int      # most sequential kv iterations of a lane
     gather_bytes: int        # KV (+scale) HBM->VMEM bytes the gather
     #                          moves (decode: and its SMEM block table)
     waste_bytes: int         # subset of gather_bytes spent on table
@@ -145,9 +142,10 @@ def decode_launch_cost(
     """Cost of one ``flash_decode_paged`` launch (one layer).
 
     Mirrors the kernel wrapper exactly: the same derived T when none is
-    given, ``split_layout``'s clamp, a grid ``(B, S, spl)`` of whole-block
-    gathers whose dead steps repeat a block and copy nothing, and compute
-    on tiles with ``k_start < kv_len`` only.
+    given, ``split_layout``'s clamp, a grid ``(B, S)`` whose programs walk
+    only their lane's live tiles (``ref.live_tile_range``), copying each
+    live block once, and computing every tile they walk. ``grid_steps``
+    is ``B * S``, ``steps_per_lane`` the most tiles one lane walks.
     """
     B = len(lengths)
     W, BS, D = table_width, block_size, head_dim
@@ -155,30 +153,29 @@ def decode_launch_cost(
     G = Hq // Hkv
     if kv_tile_blocks is None:
         kv_tile_blocks = decode_tile_blocks(BS, Hkv, D, _itemsize(kv_dtype))
-    T, S, spl, Wp = split_layout(W, kv_tile_blocks, split_k)
-    bb = Hkv * _block_bytes(BS, D, kv_dtype)          # one whole block
+    T, S, spl, _ = split_layout(W, kv_tile_blocks, split_k)
+    bb = Hkv * 2 * BS * D * _itemsize(kv_dtype)       # one whole block
+    if kv_dtype == "int8":                            # + its scale rows
+        bb += 2 * -(-Hkv * BS // SCALE_LANES) * SCALE_LANES * SCALE_BYTES
 
-    live = [min(-(-int(ln) // BS), W) for ln in lengths]
-    lasts = [max(n - 1, 0) for n in live]
-    copies = sum(c + min(T, Wp - c) for c in lasts)
-    gather = copies * bb + B * W * TABLE_ENTRY_BYTES
-    waste = ((copies - sum(live)) * bb
-             + (B * W - sum(live)) * TABLE_ENTRY_BYTES)
-
-    # computed kv tiles per row: tile jj runs iff jj*T*BS < len
-    tiles = sum(min(-(-int(ln) // (T * BS)), S * spl) for ln in lengths)
+    lens = np.asarray(lengths, np.int64)
+    lo, hi, n_blocks = live_tile_range(lens[:, None], np.arange(S)[None, :],
+                                       spl, T, BS, W, xp=np)
+    walked = np.maximum(hi - lo, 0)                   # (B, S) tiles
+    gather = int(n_blocks[:, 0].sum()) * bb + B * W * TABLE_ENTRY_BYTES
+    tiles = int(walked.sum())
     flops = tiles * Hkv * 4 * G * D * T * BS          # QK + AV full tiles
     merge = B * Hq * D * 8 * S if S > 1 else 0        # jnp merge stage
 
     q_in = B * Hq * D * ACC_BYTES
     part_out = B * S * Hkv * (G * D + 2 * G) * ACC_BYTES
     vmem = (Hkv * G * D * ACC_BYTES                   # q tile (f32 in-kernel)
-            + T * bb                                  # K+V (+scale) tiles
+            + 2 * T * bb                              # 2 x T-block buffers
             + Hkv * (G * D + 2 * G) * ACC_BYTES       # acc/m/d scratch
             + Hkv * (G * D + 2 * G) * ACC_BYTES)      # partial outputs
-    return LaunchCost(kind="decode", grid_steps=B * S * spl,
-                      lanes=B * S, steps_per_lane=spl,
-                      gather_bytes=gather, waste_bytes=waste,
+    return LaunchCost(kind="decode", grid_steps=B * S,
+                      lanes=B * S, steps_per_lane=int(walked.max(initial=0)),
+                      gather_bytes=gather, waste_bytes=0,
                       io_bytes=q_in + part_out, flops=flops,
                       merge_flops=merge, tile_bytes=T * bb,
                       vmem_bytes=vmem)

@@ -16,7 +16,7 @@ from repro.kernels.flash_decode_paged import (flash_decode_paged,
                                               paged_decode_split_ref,
                                               split_layout)
 from repro.kernels.flash_decode_paged.ref import (decode_tile_blocks,
-                                                  live_block_column)
+                                                  live_tile_range)
 from repro.models.attention import quantize_kv
 from repro.models.registry import get_config, model_fns, reduce_config
 from repro.serve import ContinuousEngine
@@ -307,6 +307,50 @@ class TestGroupedSplitDecodeKernel:
         np.testing.assert_allclose(np.asarray(sref), np.asarray(want),
                                    atol=1e-5)
 
+    @pytest.mark.parametrize("layered", [False, True],
+                             ids=["one-layer", "layer2of3"])
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    @pytest.mark.parametrize("T,S", [
+        pytest.param(T, S, id=f"T{T or 'derived'}-S{S}")
+        for T in (1, 2, None) for S in (1, 3)])
+    def test_live_walk_matches_refs(self, T, S, quantized, layered):
+        """The live-tile walk against both references at the lengths
+        where it turns: an empty row (attends 1), one block, a row ending
+        on a tile edge (128 is one for T 1, 2 and the derived 4), one
+        token past it, the table's full width, and a zombie row; split 3
+        leaves lanes with no live tile. 32-token blocks make the derived
+        T 4, so the full-width row's last tile (columns 8-11 of a 10-wide
+        table) holds two dead slots."""
+        B, Hq, Hkv, D, BS, W = 6, 4, 2, 16, 32, 10
+        lens = jnp.asarray([1, BS, 128, 129, W * BS, 0], jnp.int32)
+        layers = [_random_paged_kv(B, Hkv, D, BS, W, quantized)
+                  for _ in range(3 if layered else 1)]
+        bt = layers[0][2]
+
+        def pool(i):
+            a = jnp.stack([layer[i] for layer in layers])
+            a = a if layered else a[0]
+            return a if quantized or i > 1 else a.astype(jnp.bfloat16)
+
+        kw = {"k_scale": pool(3), "v_scale": pool(4)} if quantized else {}
+        if layered:
+            kw["layer"] = 2
+        q = jnp.asarray(_rng.normal(size=(B, Hq, D)),
+                        jnp.float32) / np.sqrt(D)
+        got = np.asarray(flash_decode_paged(
+            q, pool(0), pool(1), bt, lens, kv_tile_blocks=T, split_k=S,
+            interpret=True, **kw))
+        want = np.asarray(paged_decode_ref(q, pool(0), pool(1), bt, lens,
+                                           **kw))
+        Tk = T or decode_tile_blocks(BS, Hkv, D, pool(0).dtype.itemsize)
+        sref = np.asarray(paged_decode_split_ref(
+            q, pool(0), pool(1), bt, lens, kv_tile_blocks=Tk, split_k=S,
+            **kw))
+        np.testing.assert_allclose(got[:5], want[:5], atol=1e-5)
+        np.testing.assert_allclose(got, sref, atol=1e-5)
+        assert np.all(got[5] == 0)
+
     def test_grouped_equals_legacy_per_head_kernel(self):
         """The restructure is layout-only: the grouped/tiled/split kernel
         and the retired per-head single-block kernel agree."""
@@ -350,17 +394,26 @@ class TestGroupedSplitDecodeKernel:
         assert decode_tile_blocks(16, 128, 256, 2) == 1
         assert split_layout(3, decode_tile_blocks(16, 8, 128, 2), 1)[0] == 3
 
-    def test_dead_slots_repeat_the_last_live_block(self):
-        """Slots past a row's live blocks read its last live column (0
-        for an empty row), never past the table, so dead steps repeat a
-        block index and the pipeline copies nothing for them."""
-        cols = np.arange(8)
-        assert list(np.asarray(live_block_column(cols, 40, 16, 6))) == \
-            [0, 1, 2, 2, 2, 2, 2, 2]
-        assert list(np.asarray(live_block_column(cols, 0, 16, 6))) == \
-            [0] * 8
-        assert list(np.asarray(live_block_column(cols, 96, 16, 6))) == \
-            [0, 1, 2, 3, 4, 5, 5, 5]
+    def test_live_tile_range(self):
+        """A lane walks only the tiles that hold its row's live blocks:
+        ``[lane*spl, min((lane+1)*spl, ceil(n_blocks/T)))``, with
+        ``n_blocks = min(ceil(len/BS), W)``; a lane past them walks
+        nothing, and columns at or past ``n_blocks`` are never copied."""
+        def rng_of(n, lane, spl=2, T=2, BS=16, W=6):
+            return tuple(int(x) for x in live_tile_range(n, lane, spl, T,
+                                                         BS, W))
+        assert rng_of(40, 0) == (0, 2, 3)        # 3 blocks: 2 tiles
+        assert rng_of(40, 1) == (2, 2, 3)        # second lane: empty
+        assert rng_of(1, 0) == (0, 1, 1)         # one block, one tile
+        assert rng_of(0, 0) == (0, 0, 0)         # nothing to walk
+        assert rng_of(64, 0) == (0, 2, 4)        # ends on a tile edge
+        assert rng_of(65, 1) == (2, 3, 5)        # one token past it
+        assert rng_of(96, 1) == (2, 3, 6)        # the table's full width
+        assert rng_of(500, 1) == (2, 3, 6)       # clamped to the table
+        # traced lengths give the same range (the kernel reads SMEM)
+        lo, hi, nb = jax.jit(lambda n: live_tile_range(n, 1, 2, 2, 16, 6))(
+            jnp.int32(65))
+        assert (int(lo), int(hi), int(nb)) == (2, 3, 5)
 
     @pytest.mark.parametrize("geom,T", [
         pytest.param(SMALL, 2, id="T2"),

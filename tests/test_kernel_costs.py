@@ -61,12 +61,16 @@ class TestDecodeModelMatchesOracle:
         model = decode_launch_cost(lengths, W, kv_tile_blocks=tile,
                                    split_k=split, kv_dtype=dtype, **SHAPE)
         assert model.gather_bytes == oracle["gather_bytes"]
-        assert model.waste_bytes == oracle["waste_bytes"]
+        assert model.waste_bytes == oracle["waste_bytes"] == 0
         assert model.useful_bytes == oracle["useful_bytes"]
         assert model.grid_steps == oracle["grid_steps"]
+        assert model.steps_per_lane == oracle["steps_per_lane"]
         T = tile or decode_tile_blocks(BS, Hkv, D, k.dtype.itemsize)
-        _, _, _, Wp = split_layout(W, T, split)
-        assert Wp == oracle["padded_width"]
+        _, S, _, _ = split_layout(W, T, split)
+        assert model.grid_steps == model.lanes == B * S
+        # the walk visits each row's live tiles once, over all its lanes
+        assert oracle["tile_steps"] == sum(
+            -(-min(-(-int(n) // BS), W) // min(T, W)) for n in lengths)
 
     def test_random_geometry_sweep(self):
         for _ in range(25):
@@ -83,37 +87,41 @@ class TestDecodeModelMatchesOracle:
             model = decode_launch_cost(lengths, W, kv_tile_blocks=tile,
                                        split_k=split, **SHAPE)
             assert model.gather_bytes == oracle["gather_bytes"]
-            assert model.waste_bytes == oracle["waste_bytes"]
+            assert model.waste_bytes == oracle["waste_bytes"] == 0
             assert model.grid_steps == oracle["grid_steps"]
+            assert model.steps_per_lane == oracle["steps_per_lane"]
 
     def test_waste_zero_iff_no_padding(self):
-        # every row exactly fills the unpadded, un-bucketed table and the
-        # grid needs no tile/split padding -> zero waste
+        """The live walk copies no padding: waste is 0 whether or not
+        the rows fill the table and the tiles, and a shortfall (a freed
+        block, or a padded tile) only shrinks what is copied."""
         B, W = 3, 8
         lengths = np.full((B,), W * BS, np.int64)
         model = decode_launch_cost(lengths, W, kv_tile_blocks=2, split_k=2,
                                    **SHAPE)
-        _, _, _, Wp = split_layout(W, 2, 2)
-        assert Wp == W and model.waste_bytes == 0
-        # any shortfall (a freed block, or a padded grid) -> strictly
-        # positive; waste is block-granular, so drop a full block
+        assert model.waste_bytes == 0
         short = lengths.copy()
         short[0] -= BS
-        assert decode_launch_cost(short, W, kv_tile_blocks=2, split_k=2,
-                                  **SHAPE).waste_bytes > 0
+        shorter = decode_launch_cost(short, W, kv_tile_blocks=2, split_k=2,
+                                     **SHAPE)
+        assert shorter.waste_bytes == 0
+        assert model.gather_bytes - shorter.gather_bytes == \
+            Hkv * 2 * BS * D * 4
         assert decode_launch_cost(lengths, W, kv_tile_blocks=3, split_k=1,
-                                  **SHAPE).waste_bytes > 0
+                                  **SHAPE).waste_bytes == 0
 
     def test_dead_steps_copy_nothing(self):
-        """Whole-block gathers on a (B, S, spl) grid: widening the table
-        past every row's last live tile adds grid steps and the table's
-        int32 entries, and no block copy."""
+        """The (B, S) grid walks live tiles only: widening the table past
+        every row's last live tile adds the table's int32 entries, and no
+        grid step, tile step or block copy."""
         lengths = np.array([1, 17, 64, 190, 7], np.int64)
         narrow = decode_launch_cost(lengths, 16, kv_tile_blocks=4, **SHAPE)
         wide = decode_launch_cost(lengths, 64, kv_tile_blocks=4, **SHAPE)
         assert wide.gather_bytes - narrow.gather_bytes == 5 * (64 - 16) * 4
-        assert (narrow.grid_steps, wide.grid_steps) == (5 * 4, 5 * 16)
-        assert narrow.lanes == 5
+        assert narrow.grid_steps == wide.grid_steps == narrow.lanes == 5
+        # the 190-token row's 12 live blocks are 3 tiles of 4
+        assert narrow.steps_per_lane == wide.steps_per_lane == 3
+        assert narrow.flops == wide.flops
         # one copy moves a block's every KV head, K and V
         assert narrow.tile_bytes == 4 * Hkv * 2 * BS * D * 4
 
@@ -123,13 +131,14 @@ class TestDecodeModelMatchesOracle:
                                  kv_dtype="float32", **SHAPE)
         i8 = decode_launch_cost(lengths, 4, kv_tile_blocks=1,
                                 kv_dtype="int8", **SHAPE)
-        # int8 blocks: quarter the values + the f32 scale rows; at T = 1
-        # each row copies its live blocks once (3 + 1 whole blocks of Hkv
-        # heads), and both move the same 2 x 4 int32 table
+        # int8 blocks: quarter the values + the f32 scale rows; each row
+        # copies its live blocks once (3 + 1 whole blocks of Hkv heads),
+        # each with a K and a V scale row of every head's BS scales padded
+        # to 128 lanes, and both move the same 2 x 4 int32 table
         table = 2 * 4 * 4
-        heads = Hkv * (3 + 1)
+        rows = -(-Hkv * BS // 128) * 128
         assert i8.gather_bytes - table == \
-            (f32.gather_bytes - table) // 4 + heads * 2 * BS * 4
+            (f32.gather_bytes - table) // 4 + (3 + 1) * 2 * rows * 4
 
     def test_scaled_multiplies_extensive_fields_only(self):
         c = decode_launch_cost(np.array([33]), 4, **SHAPE)
@@ -186,8 +195,8 @@ class TestPrefillModelMatchesOracle:
 
 class TestEstimateSeconds:
     def test_monotone_in_length(self):
-        # compute-bound machine point: the gather DMA is unconditional over
-        # the padded width, so only the @pl.when-gated FLOPs see the length
+        # compute-bound machine point: the walk's FLOPs grow with the
+        # live tiles (its gather bytes do too)
         p = CostParams(flops_per_s=5e10)
         costs = [decode_launch_cost(np.array([ln]), 16, **SHAPE)
                  for ln in (8, 64, 200)]
